@@ -38,15 +38,8 @@ try:  # package-style and script-style execution both work
 except ImportError:  # pragma: no cover
     from common import emit
 
-if hasattr(np, "bitwise_count"):
-    def _popcount(words):
-        return int(np.bitwise_count(words).sum(dtype=np.int64))
-else:  # pragma: no cover
-    from repro.kernels.popcount import POPCOUNT8
-
-    def _popcount(words):
-        return int(POPCOUNT8[np.ascontiguousarray(words).view(np.uint8)]
-                   .sum(dtype=np.int64))
+def _popcount(words):
+    return int(np.bitwise_count(words).sum(dtype=np.int64))
 
 
 def _make_table(n: int, rng: np.random.Generator) -> np.ndarray:

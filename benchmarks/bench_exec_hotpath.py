@@ -12,7 +12,9 @@ contracts rather than eyeballing them:
    compile serves all of them) vs per-shape padding (one compile *each*).
    Asserts warm latency is flat within the bucket and correctness vs NumPy.
 3. **Shard-parallel execution** — sequential vs ``ShardProcessPool`` (and a
-   thread pool for reference) on >= 4 shards.  Asserts bit-identical results
+   thread pool for reference) on >= 4 shards, all ``backend="ewah"``: the
+   forked workers are host-only, and they fork before this process imports
+   jax.  Asserts bit-identical results
    always; asserts parallel < sequential when the machine demonstrably has
    multi-core headroom (a 2-process CPU-scaling pre-check — on a 1-core or
    quota-throttled box *nothing* can run below sequential, and pretending
@@ -31,6 +33,7 @@ import argparse
 import itertools
 import json
 import multiprocessing
+import sys
 import time
 
 import numpy as np
@@ -237,6 +240,9 @@ def bench_shards(table: np.ndarray, results: dict, tiny: bool) -> None:
 
     rounds = itertools.count()
     caches = [{} for _ in sharded.shards]
+    # host-only forks (here and in the scaling probe): never from a process
+    # that may already hold a device
+    assert "jax" not in sys.modules, "fork shard workers before jax loads"
     proc_pool = ShardProcessPool(sharded, workers=2)
     from concurrent.futures import ThreadPoolExecutor
     thread_pool = ThreadPoolExecutor(max_workers=4)
@@ -326,7 +332,8 @@ def run(n_rows: int, tiny: bool, out_path: str) -> dict:
     table = _make_table(n_rows, rng)
     results: dict = {"n_rows": n_rows, "tiny": tiny}
     bench_ewah_nary(table, results)
-    # shard forks must happen before anything imports jax (fork safety)
+    # shard forks must happen before anything imports jax (one process on
+    # the device; bench_shards asserts it)
     bench_shards(table, results, tiny)
     bench_kernel_buckets(results, tiny)
     bench_cost_model(results, tiny)

@@ -4,7 +4,7 @@ The executor picks a physical path per n-ary node: the compressed EWAH
 run-list path (cost ~ O(compressed words), Lemma 2) or the dense Pallas
 ``logical_reduce`` path (cost ~ O(uncompressed words / lanes), flat in
 density).  The crossover density between the two is a property of the
-*machine* — VMEM bandwidth, interpret vs compiled Pallas, NumPy build — not
+*machine* — VMEM bandwidth, interpreted vs compiled Pallas, NumPy build — not
 of the data, so a guessed constant (the old ``DENSE_THRESHOLD = 0.5``) is
 wrong on any box it was not tuned on.
 
@@ -54,7 +54,8 @@ class CostModel:
 
     dense_threshold: float = DEFAULT_DENSE_THRESHOLD
     calibrated: bool = False
-    source: str = "default"           # "default" | "calibrated" | file path
+    # "default" | "calibrated" | "calibrated-interpret" | file path
+    source: str = "default"
     machine: str = ""
     n_words: int = 0                  # calibration operand size
     n_operands: int = 0
@@ -176,8 +177,7 @@ def _best_of(fn, repeats: int) -> float:
 def calibrate(n_words: int = 1 << 14, n_operands: int = 8,
               densities: Sequence[float] = (0.02, 0.05, 0.1, 0.2, 0.35,
                                             0.5, 0.7, 0.9),
-              repeats: int = 3, interpret: bool = True,
-              seed: int = 0) -> CostModel:
+              repeats: int = 3, seed: int = 0) -> CostModel:
     """Measure the EWAH-vs-kernel crossover on *this* machine.
 
     For each density, times the vectorized EWAH ``and_many`` against the
@@ -186,31 +186,17 @@ def calibrate(n_words: int = 1 << 14, n_operands: int = 8,
     Returns an uninstalled ``CostModel``; call ``.save()`` + ``set_default``
     (or ``get_default(refresh=True)`` after saving) to put it into effect.
 
-    ``interpret=False`` compiles the Pallas kernel for the real accelerator
-    — the measurement that matters in production.  On a host without one,
-    jax raises at compile/dispatch time; calibration then falls back to
-    ``interpret=True`` and records ``source="calibrated-interpret"`` so
-    ``/stats`` can tell a hardware-measured crossover from an interpreted
-    one.
+    The kernel runs as ``repro.kernels.ops.interpret_mode()`` decides:
+    compiled on a TPU, where a kernel that fails to compile raises, and
+    interpreted on the CPU, recorded as ``source="calibrated-interpret"``
+    so ``/stats`` can tell a hardware-measured crossover from an
+    interpreted one.
     """
     from .ewah import and_many
     from repro.kernels import ops as kops
 
     rng = np.random.default_rng(seed)
-    source = "calibrated"
-    if not interpret:
-        # probe compiled dispatch once, tiny: an accelerator-less host
-        # raises here (not per density sweep), and we degrade gracefully
-        probe = np.zeros((2, 8), dtype=np.uint32)
-        try:
-            np.asarray(kops.logical_reduce(probe, op="and", interpret=False))
-        except Exception as exc:  # noqa: BLE001 - jax error types vary by backend
-            log.warning(
-                "calibrate(interpret=False): compiled Pallas dispatch "
-                "unavailable (%s: %s) — falling back to interpret mode",
-                type(exc).__name__, exc)
-            interpret = True
-            source = "calibrated-interpret"
+    source = "calibrated-interpret" if kops.interpret_mode() else "calibrated"
     samples: List[dict] = []
     crossover: Optional[float] = None
     prev_density: Optional[float] = None
@@ -220,7 +206,7 @@ def calibrate(n_words: int = 1 << 14, n_operands: int = 8,
         for bm in bms:
             bm.runlist()  # decode outside the timed region, like the executor cache
         kernel = lambda: np.asarray(  # noqa: E731
-            kops.logical_reduce(mat, op="and", interpret=interpret))
+            kops.logical_reduce(mat, op="and"))
         kernel()  # warm: compile the bucket
         ewah_s = _best_of(lambda: and_many(bms), repeats)
         kern_s = _best_of(kernel, repeats)

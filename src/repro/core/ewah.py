@@ -323,9 +323,8 @@ class EWAH:
 
         Computed in the compressed domain from the run-list: clean-one runs
         contribute ``32 * length`` without materializing words, literal words
-        are popcounted in one vectorized pass (``np.bitwise_count`` when
-        available, the byte lookup table from ``repro.kernels.popcount``
-        otherwise).  Memoized — selectivity estimation hits this repeatedly.
+        are popcounted in one vectorized pass (``np.bitwise_count``).
+        Memoized — selectivity estimation hits this repeatedly.
         """
         if self.n_bits == 0:
             return 0
@@ -803,18 +802,11 @@ KIND_CLEAN0 = 0
 KIND_CLEAN1 = 1
 KIND_LIT = 2
 
-_HAS_BITWISE_COUNT = hasattr(np, "bitwise_count")
-
-
 def _popcount_words(words: np.ndarray) -> int:
     """Popcount a uint32 array in one vectorized pass."""
     if len(words) == 0:
         return 0
-    if _HAS_BITWISE_COUNT:
-        return int(np.bitwise_count(words).sum(dtype=np.int64))
-    from repro.kernels.popcount import POPCOUNT8  # byte-LUT fallback
-    return int(POPCOUNT8[np.ascontiguousarray(words).view(np.uint8)]
-               .sum(dtype=np.int64))
+    return int(np.bitwise_count(words).sum(dtype=np.int64))
 
 
 @dataclass(frozen=True, eq=False)

@@ -75,6 +75,10 @@ def _const_bitmap(index: BitmapIndex, value: bool,
 
 
 class Executor:
+    # process-wide count of plan nodes dispatched to the Pallas kernels
+    # (a smoke run asserts the device path was taken at all)
+    kernel_dispatches = 0
+
     def __init__(self, index: BitmapIndex, backend: Backend = "auto",
                  cache: Optional[Dict] = None,
                  dense_threshold: Optional[float] = None):
@@ -389,6 +393,7 @@ class Executor:
         neg = [(ch, self._run(ch)) for ch in node.neg]
         if self._use_kernel([bm for _, bm in pos + neg]):
             from repro.kernels import ops as kops
+            Executor.kernel_dispatches += 1
             pw, pf = zip(*[self._dense_operand(n, bm) for n, bm in pos])
             nw, nf = zip(*[self._dense_operand(n, bm) for n, bm in neg])
             a = kops.logical_reduce(np.stack(pw), op="and",
@@ -419,6 +424,7 @@ class Executor:
 
     def _reduce_kernel(self, children, op: str) -> EWAH:
         from repro.kernels import ops as kops  # lazy: jax only on this path
+        Executor.kernel_dispatches += 1
         ws, fs = zip(*[self._dense_operand(node, bm) for node, bm in children])
         out = np.asarray(kops.logical_reduce(np.stack(ws), op=op,
                                              row_flags=np.stack(fs)))

@@ -646,7 +646,7 @@ class ShardedIndex:
 # ---------------------------------------------------------------------------
 
 class ForkSafetyError(Exception):
-    """An explicit jax-backend request reached a forked shard worker.
+    """A backend that can reach jax was requested in a forked shard worker.
 
     Deliberately *not* a ``RuntimeError``: ``ShardProcessPool.run_shards``
     retries ``RuntimeError`` once (racing generation bumps shut executors
@@ -660,8 +660,9 @@ class ForkSafetyError(Exception):
 # — including an already-imported jax — so fork safety cannot be "jax is not
 # imported here"; it is "this process never *calls* into the jax runtime":
 # XLA client threads and locks do not survive fork, and a first-use
-# initialization in a worker would boot one runtime per worker.  The guard
-# therefore pins forked workers to the pure-NumPy EWAH backend.
+# initialization in a worker would boot one runtime per worker (and on a
+# TPU host, one more process reaching for the chip).  The guard therefore
+# admits only the pure-NumPy EWAH backend in forked workers.
 _IN_FORK_WORKER = False
 
 
@@ -671,20 +672,18 @@ def _fork_worker_init() -> None:
 
 
 def _guard_backend(backend: str) -> str:
-    """Resolve ``backend`` under the fork-safety rule (worker side).
+    """Check ``backend`` against the fork-safety rule (worker side).
 
-    ``auto`` quietly degrades to ``ewah`` (the executor's kernel path is
-    an optimization, never a semantic change); an *explicit* ``kernel``
-    request is a caller error and raises ``ForkSafetyError``.
+    Forked workers run ``ewah`` only.  ``auto`` and ``kernel`` can both
+    reach the jax kernels, so either is a caller error and raises
+    ``ForkSafetyError`` instead of being quietly rewritten.
     """
-    if not _IN_FORK_WORKER:
-        return backend
-    if backend == "kernel":
+    if _IN_FORK_WORKER and backend != "ewah":
         raise ForkSafetyError(
-            "backend='kernel' inside a forked shard worker: the jax "
-            "runtime is not fork-safe; use backend='auto'/'ewah' with "
-            "ShardProcessPool, or a thread pool for kernel execution")
-    return "ewah" if backend == "auto" else backend
+            f"backend={backend!r} inside a forked shard worker: the jax "
+            "runtime is not fork-safe; use backend='ewah' with "
+            "ShardProcessPool, or a thread pool for the kernel path")
+    return backend
 
 
 # indexes visible to forked workers, keyed per pool.  Entries are written in
@@ -814,10 +813,10 @@ class ShardProcessPool:
     index ``generation`` changes (``replace_shard``), so a worker never
     serves a stale shard.  Per-worker operand caches persist across queries.
     Fork safety is *enforced*: every worker runs ``_fork_worker_init`` and
-    ``_guard_backend`` pins it to the pure-NumPy EWAH path — ``auto``
-    degrades to ``ewah``, an explicit ``kernel`` raises ``ForkSafetyError``
-    — so a worker never initializes (or re-enters) a jax runtime inherited
-    from the parent.  ``run_shards(("probe",), shard_ids)`` returns each
+    ``_guard_backend`` admits only the pure-NumPy EWAH path — ``auto`` and
+    ``kernel`` raise ``ForkSafetyError`` — so a worker never initializes
+    (or re-enters) a jax runtime inherited from the parent.  Callers pass
+    ``backend="ewah"``.  ``run_shards(("probe",), shard_ids)`` returns each
     worker's pid / fork flag / effective backend for verification.
 
     With ``index_dir`` (a saved ``ShardedIndex`` store directory), workers

@@ -15,7 +15,7 @@ mask algebra and convergence parity on a toy problem.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, NamedTuple, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -57,7 +57,7 @@ def _unflatten(tree, flat):
 
 
 def sparsify(grads, error: Any, keep_ratio: float, values_per_block: int = 256,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """(grads, error-feedback) -> (masked grads, new error, keep-mask, flat)."""
     flat, _ = _flatten(grads)
     if error is not None:
@@ -76,7 +76,8 @@ def sparsify(grads, error: Any, keep_ratio: float, values_per_block: int = 256,
 
 def compressed_allreduce(grads, error, keep_ratio: float,
                          values_per_block: int = 256,
-                         interpret: bool = True) -> Tuple[Any, Any, CompressionStats]:
+                         interpret: Optional[bool] = None
+                         ) -> Tuple[Any, Any, CompressionStats]:
     """Returns (sparsified grads pytree, new error pytree, wire stats).
 
     The actual cross-replica mean happens in the caller's pjit (the masked

@@ -6,7 +6,7 @@ bitpack       — Algorithm 3's row->word packing
 grad_compress — blockwise norms for EWAH sparse-gradient all-reduce
 
 `ops` holds the jit'd wrappers, `ref` the pure-jnp oracles.
-Kernels target TPU ((8,128)-aligned tiles, VMEM BlockSpecs) and are
-validated on CPU with interpret=True.
+Kernels target TPU ((8,128)-aligned tiles, VMEM BlockSpecs, SMEM
+scalars); ``ops.interpret_mode()`` runs them interpreted on the CPU.
 """
 from . import ops, ref

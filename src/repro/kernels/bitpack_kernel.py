@@ -2,8 +2,10 @@
 
 Inner loop of the index builder (Algorithm 3): 32 consecutive rows of a
 bitmap column become one 32-bit word.  In-kernel the pack is a weighted sum
-over the 32-row axis with weights 2^i (uint32), vectorized over 128 bitmap
-lanes — MXU-free, pure VPU work.
+over the 32-row axis with weights 2^i, vectorized over 128 bitmap lanes —
+MXU-free, pure VPU work.  The sum runs in int32 (the TPU has no unsigned
+reductions; bit 31's weight wraps to -2^31) and is bitcast to uint32: the
+weighted bits are disjoint, so the two's-complement sum is exactly their OR.
 
 Layout: bits (N_ROWS, L) -> words (N_ROWS // 32, L); bit i of word w is row
 32*w + i (the codec's little-endian convention).
@@ -22,17 +24,18 @@ WORD_BITS = 32
 
 
 def _kernel(bits_ref, words_ref):
-    bits = bits_ref[...].astype(jnp.uint32)           # (ROW_BLOCK, COL_BLOCK)
+    bits = bits_ref[...].astype(jnp.int32)            # (ROW_BLOCK, COL_BLOCK)
     r, c = bits.shape
     w = r // WORD_BITS
     bits = bits.reshape(w, WORD_BITS, c)
-    weights = (jnp.uint32(1) << jnp.arange(WORD_BITS, dtype=jnp.uint32))
-    words_ref[...] = jnp.sum(bits * weights[None, :, None], axis=1, dtype=jnp.uint32)
+    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, WORD_BITS, c), 1)
+    words = jnp.sum(bits << shifts, axis=1)
+    words_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("row_block", "col_block", "interpret"))
 def bitpack(bits: jax.Array, row_block: int = ROW_BLOCK, col_block: int = COL_BLOCK,
-            interpret: bool = True) -> jax.Array:
+            *, interpret: bool) -> jax.Array:
     """(N, L) bools -> (N//32, L) uint32 words."""
     N, L = bits.shape
     assert N % WORD_BITS == 0, "pad rows to a word multiple"

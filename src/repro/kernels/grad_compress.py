@@ -25,7 +25,7 @@ def _kernel(g_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("values_per_block", "tile_blocks", "interpret"))
 def block_sqnorms(grad_flat: jax.Array, values_per_block: int = VALUES_PER_BLOCK,
-                  tile_blocks: int = TILE_BLOCKS, interpret: bool = True) -> jax.Array:
+                  tile_blocks: int = TILE_BLOCKS, *, interpret: bool) -> jax.Array:
     """(n_blocks * values_per_block,) f32 -> (n_blocks,) squared block norms."""
     n = grad_flat.shape[0]
     n_blocks = n // values_per_block
@@ -47,7 +47,7 @@ def block_sqnorms(grad_flat: jax.Array, values_per_block: int = VALUES_PER_BLOCK
 
 def topk_block_mask(grad_flat: jax.Array, keep_ratio: float,
                     values_per_block: int = VALUES_PER_BLOCK,
-                    interpret: bool = True) -> jax.Array:
+                    *, interpret: bool) -> jax.Array:
     """Boolean keep-mask over compression blocks (True = block survives)."""
     norms = block_sqnorms(grad_flat, values_per_block, interpret=interpret)
     n_blocks = norms.shape[0]

@@ -1,8 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels (+ padding glue).
 
-`interpret=True` by default: this container is CPU-only; on TPU pass
-``interpret=False`` (the kernels are written against TPU tiling rules:
-multiples of (8, 128) for 32-bit types).
+Every wrapper takes ``interpret=None``, resolved by ``interpret_mode()``
+from the JAX backend: compiled on a TPU, interpreted on the CPU, refused
+anywhere else.  The kernels are written against TPU tiling rules
+(multiples of (8, 128) for 32-bit types); ``tests/test_tpu_compile.py``
+compiles them for a described v5e chip.
 
 Shape bucketing (the JIT cold-start fix): ``jax.jit`` compiles one program
 per operand shape, so a query stream whose bitmaps span many distinct word
@@ -17,6 +19,8 @@ is not recomputed per query — the executor caches them next to the words.
 """
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Optional, Tuple
 
 import jax
@@ -29,6 +33,41 @@ from . import bitpack_kernel as _bp
 from . import grad_compress as _gc
 
 _ALL_ONES = np.uint32(0xFFFFFFFF)
+
+# the checkout root (src/repro/kernels/ops.py -> three levels up)
+CHECKOUT_ROOT = Path(__file__).resolve().parents[3]
+
+
+def interpret_mode() -> bool:
+    """Whether Pallas kernels run interpreted on this process's backend:
+    ``False`` on a TPU, ``True`` on the CPU; any other backend raises."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels target TPU (or the CPU "
+                       f"interpreter), not backend {backend!r}")
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return interpret_mode() if interpret is None else interpret
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compile cache for this process.
+
+    ``$JAX_COMPILATION_CACHE_DIR``, when set, is where JAX already keeps
+    it; otherwise the cache goes to ``.jax_cache/`` in the checkout (a
+    fixed path, since the path is part of what a later run must find).
+    Kernels compile in well under JAX's default one-second floor for
+    caching, so the floor is dropped.  Returns the cache directory."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(CHECKOUT_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return path
 
 
 def next_pow2(x: int) -> int:
@@ -141,7 +180,7 @@ def _pad_rows_np(rf: Optional[np.ndarray], rows: int, br: int) -> Optional[np.nd
     return np.pad(rf, ((0, pad), (0, 0)), constant_values=_wl.CLEAN0)
 
 
-def word_logical(a, b, op: str = "and", interpret: bool = True,
+def word_logical(a, b, op: str = "and", interpret: Optional[bool] = None,
                  block_rows: int = 8, block_cols: int = 1024,
                  bucket: bool = True,
                  row_flags_a: Optional[np.ndarray] = None,
@@ -171,11 +210,12 @@ def word_logical(a, b, op: str = "and", interpret: bool = True,
         fb = jnp.asarray(_combine_row_flags(
             _pad_rows_np(row_flags_b, orig[0], block_rows), block_rows))
     out = _wl.word_logical(ap, bp_, fa, fb, op=op, block_rows=block_rows,
-                           block_cols=block_cols, interpret=interpret)
+                           block_cols=block_cols,
+                           interpret=_interpret(interpret))
     return out[: orig[0], : orig[1]]
 
 
-def logical_reduce(mat, op: str = "and", interpret: bool = True,
+def logical_reduce(mat, op: str = "and", interpret: Optional[bool] = None,
                    block_rows: int = 8, block_cols: int = 1024,
                    bucket: bool = True,
                    row_flags: Optional[np.ndarray] = None) -> jax.Array:
@@ -196,6 +236,7 @@ def logical_reduce(mat, op: str = "and", interpret: bool = True,
     later rounds recompute flags on device for their intermediate results.
     """
     assert op in ("and", "or", "xor"), op  # associative ops only
+    interpret = _interpret(interpret)
     mat = jnp.asarray(mat, jnp.uint32)
     assert mat.ndim == 2 and mat.shape[0] >= 1, mat.shape
     L, C = mat.shape
@@ -231,37 +272,40 @@ def logical_reduce(mat, op: str = "and", interpret: bool = True,
     return mat[0][:C]
 
 
-def popcount_total(a, interpret: bool = True) -> jax.Array:
+def popcount_total(a, interpret: Optional[bool] = None) -> jax.Array:
     a = jnp.asarray(a, jnp.uint32)
     ap, _ = _pad2(a, 8, 1024)
-    return _pc.popcount_total(ap, interpret=interpret)
+    return _pc.popcount_total(ap, interpret=_interpret(interpret))
 
 
-def popcount_rows(a, interpret: bool = True) -> jax.Array:
+def popcount_rows(a, interpret: Optional[bool] = None) -> jax.Array:
     a = jnp.asarray(a, jnp.uint32)
     ap, (R, _) = _pad2(a, 8, 1024)
-    return _pc.popcount_rows(ap, interpret=interpret)[:R]
+    return _pc.popcount_rows(ap, interpret=_interpret(interpret))[:R]
 
 
-def bitpack(bits, interpret: bool = True) -> jax.Array:
+def bitpack(bits, interpret: Optional[bool] = None) -> jax.Array:
     """(N, L) bools -> (ceil(N/32), L) uint32 words."""
     bits = jnp.asarray(bits, jnp.bool_)
     N, L = bits.shape
     bp2, (_, _) = _pad2(bits, 1024, 128, fill=False)
-    out = _bp.bitpack(bp2, interpret=interpret)
+    out = _bp.bitpack(bp2, interpret=_interpret(interpret))
     return out[: -(-N // 32), :L]
 
 
-def block_sqnorms(grad_flat, values_per_block: int = 256, interpret: bool = True) -> jax.Array:
+def block_sqnorms(grad_flat, values_per_block: int = 256,
+                  interpret: Optional[bool] = None) -> jax.Array:
     grad_flat = jnp.asarray(grad_flat, jnp.float32)
     n = grad_flat.shape[0]
     npad = -(-n // values_per_block) * values_per_block
     if npad != n:
         grad_flat = jnp.pad(grad_flat, (0, npad - n))
-    return _gc.block_sqnorms(grad_flat, values_per_block, interpret=interpret)
+    return _gc.block_sqnorms(grad_flat, values_per_block,
+                             interpret=_interpret(interpret))
 
 
 def topk_block_mask(grad_flat, keep_ratio: float, values_per_block: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     return _gc.topk_block_mask(jnp.asarray(grad_flat, jnp.float32), keep_ratio,
-                               values_per_block, interpret=interpret)
+                               values_per_block,
+                               interpret=_interpret(interpret))

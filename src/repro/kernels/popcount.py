@@ -10,15 +10,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 8
 BLOCK_COLS = 1024
-
-# byte-wise popcount lookup: the host-side fallback used by
-# ``repro.core.ewah`` when NumPy lacks ``bitwise_count`` (numpy < 2.0)
-POPCOUNT8 = np.array([bin(i).count("1") for i in range(256)], dtype=np.uint8)
 
 
 def _popcount_u32(v):
@@ -30,25 +26,33 @@ def _popcount_u32(v):
 
 def _kernel(a_ref, o_ref):
     counts = _popcount_u32(a_ref[...]).astype(jnp.int32)
-    o_ref[0, 0] = jnp.sum(counts)
+
+    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0))
+    def _():
+        o_ref[0, 0] = jnp.int32(0)
+
+    o_ref[0, 0] += jnp.sum(counts)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_cols", "interpret"))
 def popcount_total(a: jax.Array, block_rows: int = BLOCK_ROWS,
-                   block_cols: int = BLOCK_COLS, interpret: bool = True) -> jax.Array:
-    """Total number of set bits in an (R, C) uint32 array."""
+                   block_cols: int = BLOCK_COLS, *, interpret: bool) -> jax.Array:
+    """Total number of set bits in an (R, C) uint32 array.
+
+    One SMEM scalar accumulates across the (sequential) grid: every tile
+    adds its count, the first one zeroes it first."""
     R, C = a.shape
     gr, gc = R // block_rows, C // block_cols
     assert gr * block_rows == R and gc * block_cols == C
-    partials = pl.pallas_call(
+    total = pl.pallas_call(
         _kernel,
-        out_shape=jax.ShapeDtypeStruct((gr, gc), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((1, 1), jnp.int32),
         grid=(gr, gc),
         in_specs=[pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((1, 1), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
         interpret=interpret,
     )(a)
-    return jnp.sum(partials)
+    return total[0, 0]
 
 
 def _kernel_rows(a_ref, o_ref, *, first_col):
@@ -64,7 +68,7 @@ def _kernel_rows(a_ref, o_ref, *, first_col):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "block_cols", "interpret"))
 def popcount_rows(a: jax.Array, block_rows: int = BLOCK_ROWS,
-                  block_cols: int = BLOCK_COLS, interpret: bool = True) -> jax.Array:
+                  block_cols: int = BLOCK_COLS, *, interpret: bool) -> jax.Array:
     """Per-row set-bit counts of an (R, C) uint32 array -> (R,) int32.
 
     Grid iterates columns innermost; the output row-block accumulates across

@@ -9,7 +9,9 @@ dirty×dirty tiles — recovering "only touch non-zero words" at VMEM-tile
 granularity, which is the granularity a TPU can actually skip at.
 
 Tiling: (SUBLANES=8, LANES=128) words per VREG op for 32-bit types; default
-block (8, 1024) = 32 KiB/operand in VMEM.
+block (8, 1024) = 32 KiB/operand in VMEM.  The flags are scalar-prefetched
+into SMEM (one int32 per tile): a (1, 1) VMEM block per tile would break
+the (8, 128) block rule.
 
 Compilation contract: ``word_logical`` is jit-compiled once per *input
 shape* (plus static block/op params).  Callers must therefore keep the
@@ -29,6 +31,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 # flag values for a tile
 DIRTY = 0
@@ -51,9 +54,11 @@ def _apply(op: str, a, b):
     return a & ~b  # andnot
 
 
-def _kernel(op: str, fa_ref, fb_ref, a_ref, b_ref, o_ref):
-    fa = fa_ref[0, 0]
-    fb = fb_ref[0, 0]
+def _kernel(op: str, gc: int, fa_ref, fb_ref, a_ref, b_ref, o_ref):
+    # flags are scalar-prefetched into SMEM, flattened row-major by tile
+    t = pl.program_id(0) * gc + pl.program_id(1)
+    fa = fa_ref[t]
+    fb = fb_ref[t]
     both_dirty = (fa == DIRTY) & (fb == DIRTY)
 
     @pl.when(both_dirty)
@@ -79,7 +84,8 @@ def word_logical(
     op: str = "and",
     block_rows: int = BLOCK_ROWS,
     block_cols: int = BLOCK_COLS,
-    interpret: bool = True,
+    *,
+    interpret: bool,
 ) -> jax.Array:
     """op(a, b) over (R, C) uint32 word arrays with (R/br, C/bc) tile flags."""
     assert op in OPS
@@ -89,19 +95,16 @@ def word_logical(
     assert gr * block_rows == R and gc * block_cols == C, (a.shape, block_rows, block_cols)
     assert flags_a.shape == (gr, gc) == flags_b.shape
 
+    # index maps see the two prefetched flag refs after the grid indices
+    tile = pl.BlockSpec((block_rows, block_cols), lambda i, j, fa, fb: (i, j))
     return pl.pallas_call(
-        functools.partial(_kernel, op),
+        functools.partial(_kernel, op, gc),
         out_shape=jax.ShapeDtypeStruct((R, C), jnp.uint32),
-        grid=(gr, gc),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, j)),
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(gr, gc),
+            in_specs=[tile, tile], out_specs=tile),
         interpret=interpret,
-    )(flags_a, flags_b, a, b)
+    )(flags_a.reshape(-1), flags_b.reshape(-1), a, b)
 
 
 def tile_flags(words: jax.Array, block_rows: int = BLOCK_ROWS,

@@ -9,6 +9,12 @@ the fleet to answer health probes, and hands back a started
 method: ``kill_worker`` (hard crash), ``restart_worker`` (recovery),
 ``set_fault`` (seeded drop/delay/corrupt/disconnect on a live worker).
 
+The workers are host-only: several of them share one host, and a device
+belongs to one process, so each runs ``--backend ewah`` with
+``JAX_PLATFORMS=cpu`` in its environment and never opens an accelerator.
+Asking for any other backend fails at start; the kernel path is served by
+one ``QueryService`` process (``repro.serve.query_api``).
+
 Typical test / benchmark shape::
 
     with LocalCluster(index_dir, n_workers=3, replication=2) as cluster:
@@ -54,12 +60,18 @@ class LocalCluster:
 
     def __init__(self, index_dir: str, n_workers: int = 3,
                  replication: int = 2, policy: Optional[Policy] = None,
-                 backend: str = "auto", host: str = "127.0.0.1",
+                 backend: str = "ewah", host: str = "127.0.0.1",
                  hot_shards: Sequence[int] = (),
                  log_dir: Optional[str] = None,
                  fault: Optional[Dict] = None,
                  start_monitor: bool = True,
                  startup_timeout_s: float = 20.0):
+        if backend != "ewah":
+            raise ValueError(
+                f"LocalCluster workers are host-only (backend='ewah'), not "
+                f"{backend!r}: {n_workers} worker processes on one host "
+                "cannot share its accelerator; serve the kernel path from "
+                "one QueryService process instead")
         self.index_dir = index_dir
         self.host = host
         self.backend = backend
@@ -101,7 +113,7 @@ class LocalCluster:
                               ("delay_s", "--fault-delay-s")):
                 if key in self._fault:
                     cmd += [flag, str(self._fault[key])]
-        env = dict(os.environ)
+        env = dict(os.environ, JAX_PLATFORMS="cpu")  # host-only worker
         src = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         extra = env.get("PYTHONPATH")
@@ -194,8 +206,8 @@ class LocalCluster:
 def build_demo_store(out_dir: str, n_rows: int = 100_000,
                      n_shards: int = 8) -> str:
     """Build the demo census-like sharded index and save it to ``out_dir``."""
-    from repro.serve.query_api import _demo_index
-    idx = _demo_index(n_rows, shards=max(n_shards, 2))
+    from repro.serve.query_api import demo_index, demo_table
+    idx = demo_index(demo_table(n_rows), shards=max(n_shards, 2))
     idx.save(out_dir)
     return out_dir
 
@@ -210,7 +222,9 @@ def main(argv=None):
     ap.add_argument("--shards", type=int, default=8)
     ap.add_argument("--n-workers", type=int, default=3)
     ap.add_argument("--replication", type=int, default=2)
-    ap.add_argument("--backend", default="auto")
+    ap.add_argument("--backend", default="ewah",
+                    help="worker backend; workers are host-only, so only "
+                         "'ewah' is accepted")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8321)
     ap.add_argument("--max-body-bytes", type=int, default=None)

@@ -26,11 +26,13 @@ Production-shaped serving on a dependency-free stack (stdlib ``http.server``
   file from an out-of-band reindex), keeping the *other* shards' caches
   warm; ``--watch-interval N`` runs the same manifest/shard-fingerprint
   check on a background poller so replaced files are picked up with no
-  admin call.  On a store directory, shard fan-out defaults to a
-  fork-based ``ShardProcessPool`` (workers mmap-open the shard files and
-  are pinned to the fork-safe EWAH backend); ``--shard-procs 0`` forces
-  the thread pool.  Result-cache entries can also expire after ``--cache-ttl`` seconds
-  (lazily, on lookup), with hit/miss/expired counters in ``/stats``.
+  admin call.  Shard fan-out runs on a thread pool whenever the backend
+  can reach the Pallas kernels (``auto``, ``kernel``): one process holds
+  the device.  With ``--backend ewah`` on a store directory it defaults to
+  a fork-based ``ShardProcessPool`` (workers mmap-open the shard files);
+  ``--shard-procs 0`` forces the thread pool there.  Result-cache entries
+  can also expire after ``--cache-ttl`` seconds (lazily, on lookup), with
+  hit/miss/expired counters in ``/stats``.
 * **Aggregation statements** — count / group-by / top-k evaluate *in the
   compressed domain* (memoized popcounts + interval intersection; sharded
   indexes merge per-shard partial counts at the coordinator, never a global
@@ -459,15 +461,22 @@ class QueryService:
         # shard fan-out pool: query workers wait on shard tasks, shard tasks
         # submit nothing, so the wait graph is acyclic (no pool deadlock).
         # ``shard_processes`` > 0 swaps in a fork-based ShardProcessPool so
-        # CPU-bound EWAH shard work runs beyond the GIL (the pool's worker
-        # initializer pins workers to the fork-safe EWAH backend); ``None``
-        # (the default) picks the process pool automatically for sharded
-        # indexes opened from a store directory — there the workers
-        # mmap-open the shard files themselves, so no fork-COW of the
-        # parent heap is involved — and a thread pool everywhere else.
+        # CPU-bound EWAH shard work runs beyond the GIL; forked workers run
+        # ``backend="ewah"`` only, so a service whose backend can reach the
+        # kernels never forks and keeps the device in one process.  With
+        # backend ``ewah``, ``None`` (the default) picks the process pool
+        # for sharded indexes opened from a store directory — there the
+        # workers mmap-open the shard files themselves, so no fork-COW of
+        # the parent heap is involved — and a thread pool everywhere else.
         # ``0`` forces the thread pool.
         self.shard_processes = shard_processes if shard_processes is None \
             else int(shard_processes)
+        if self.shard_processes and backend != "ewah":
+            raise ValueError(
+                f"shard_processes={self.shard_processes} with backend="
+                f"{backend!r}: forked shard workers run backend='ewah' "
+                "only, and a service that can reach the kernels keeps them "
+                "in one process; pass backend='ewah' or shard_processes=0")
         self._shard_pool = self._make_shard_pool()
         # manifest fingerprint for the change watcher (None when not
         # store-backed); shard-file prints live in ``_fingerprints``
@@ -511,7 +520,7 @@ class QueryService:
         if self.shard_processes is not None:
             return self.shard_processes
         import multiprocessing
-        if (self.index_dir is not None
+        if (self.backend == "ewah" and self.index_dir is not None
                 and isinstance(self.index, ShardedIndex)
                 and "fork" in multiprocessing.get_all_start_methods()):
             return os.cpu_count() or 2
@@ -1263,18 +1272,26 @@ def serve_in_thread(service: QueryService, host: str = "127.0.0.1",
     return srv, srv.server_address[1]
 
 
-def _demo_index(n_rows: int, shards: int = 0,
-                rng: Optional[np.random.Generator] = None):
+DEMO_COLUMNS = ["region", "day", "user"]
+
+
+def demo_table(n_rows: int,
+               rng: Optional[np.random.Generator] = None) -> np.ndarray:
+    """The demo fact table: census-shaped, factorized to ranks and
+    lexicographically sorted (columns ``DEMO_COLUMNS``)."""
     rng = rng or np.random.default_rng(0)
-    table = synth.census_like_table(n_rows, rng)
-    ranked, _ = synth.factorize(table)
-    ranked = ranked[lex_sort(ranked)]
-    names = ["region", "day", "user"]
+    ranked, _ = synth.factorize(synth.census_like_table(n_rows, rng))
+    return ranked[lex_sort(ranked)]
+
+
+def demo_index(table: np.ndarray, shards: int = 0):
+    """k=2 index over ``demo_table``'s rows, cut into (at most) ``shards``
+    row shards of whole words when ``shards > 1``."""
     if shards > 1:
-        shard_rows = max(-(-n_rows // shards) // 32 * 32, 32)
-        return ShardedIndex.build(ranked, shard_rows=shard_rows, k=2,
-                                  column_names=names)
-    return BitmapIndex.build(ranked, k=2, column_names=names)
+        shard_rows = max(-(-len(table) // (32 * shards)) * 32, 32)
+        return ShardedIndex.build(table, shard_rows=shard_rows, k=2,
+                                  column_names=DEMO_COLUMNS)
+    return BitmapIndex.build(table, k=2, column_names=DEMO_COLUMNS)
 
 
 def main(argv=None):
@@ -1295,9 +1312,10 @@ def main(argv=None):
     ap.add_argument("--cache-ttl", type=float, default=0,
                     help="result-cache entry TTL in seconds (0 = no expiry)")
     ap.add_argument("--shard-procs", type=int, default=None,
-                    help="shard-parallel worker *processes* (0 = thread "
-                         "pool; default: processes when serving a store "
-                         "directory, threads otherwise)")
+                    help="shard-parallel worker *processes*, backend ewah "
+                         "only (0 = thread pool; default: processes for "
+                         "--backend ewah on a store directory, threads "
+                         "otherwise)")
     ap.add_argument("--watch-interval", type=float, default=0,
                     help="poll the store directory every N seconds and "
                          "auto-reload changed shard files (0 = off; "
@@ -1320,6 +1338,9 @@ def main(argv=None):
                          "(413 + code 'too_large' beyond it; default "
                          "unlimited)")
     args = ap.parse_args(argv)
+    if args.backend != "ewah":  # only the kernel path compiles anything
+        from repro.kernels.ops import use_compile_cache
+        use_compile_cache()
     kw = dict(backend=args.backend, pool_workers=args.workers,
               cache_entries=args.cache,
               cache_bytes=int(args.cache_mb * 2**20),
@@ -1331,7 +1352,7 @@ def main(argv=None):
         origin = (f"warm start {args.index_dir} "
                   f"({time.perf_counter() - t0:.3f}s open)")
     else:
-        index = _demo_index(args.rows, args.shards)
+        index = demo_index(demo_table(args.rows), args.shards)
         if args.save_index:
             if not isinstance(index, ShardedIndex):
                 index = ShardedIndex([index])

@@ -439,6 +439,9 @@ def main(argv=None):
     ap.add_argument("--fault-disconnect", type=float, default=0.0)
     ap.add_argument("--fault-delay-s", type=float, default=0.25)
     args = ap.parse_args(argv)
+    if args.backend != "ewah":  # only the kernel path compiles anything
+        from repro.kernels.ops import use_compile_cache
+        use_compile_cache()
     if args.shards == "all":
         ids = list(range(len(index_store.manifest_shards(args.index_dir))))
     else:

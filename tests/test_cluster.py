@@ -479,3 +479,13 @@ def test_cluster_http_front_end(store, cluster):
         assert err.value.code == 400
     finally:
         srv.shutdown()
+
+
+@pytest.mark.parametrize("backend", ["auto", "kernel"])
+def test_local_cluster_workers_are_host_only(tmp_path, backend):
+    # several worker processes on one host cannot share its accelerator:
+    # the request fails before any store is read or any worker spawned
+    from repro.launch.cluster import LocalCluster
+    with pytest.raises(ValueError, match="host-only"):
+        LocalCluster(str(tmp_path / "no-store"), n_workers=2,
+                     backend=backend)

@@ -382,16 +382,18 @@ def test_fork_workers_never_touch_jax():
     idx = ShardedIndex.build(table, shard_rows=512)
     pool = ShardProcessPool(idx, workers=2)
     try:
-        probes = pool.run_shards(("probe",), range(idx.n_shards))
+        probes = pool.run_shards(("probe",), range(idx.n_shards),
+                                 backend="ewah")
         assert all(p["fork_worker"] for p in probes)
         assert all(p["pid"] != os.getpid() for p in probes)
-        # auto degrades to the fork-safe EWAH path in every worker
         assert all(p["backend"] == "ewah" for p in probes)
-        # an explicit kernel request is a loud error, not a retry loop
-        with pytest.raises(ForkSafetyError):
-            pool.run_shards(("probe",), [0], backend="kernel")
+        # any backend that can reach jax is a loud error in a worker —
+        # never rewritten to ewah, never retried
+        for backend in ("auto", "kernel"):
+            with pytest.raises(ForkSafetyError):
+                pool.run_shards(("probe",), [0], backend=backend)
         assert not issubclass(ForkSafetyError, RuntimeError)
         e = (col(0) == 3) & (col(1) != 2)
-        assert idx.execute(e, pool=pool) == idx.execute(e)
+        assert idx.execute(e, backend="ewah", pool=pool) == idx.execute(e)
     finally:
         pool.shutdown()

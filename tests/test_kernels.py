@@ -79,3 +79,45 @@ def test_topk_block_mask():
     g[256 * 3: 256 * 4] = 100.0  # one hot block
     mask = np.asarray(ops.topk_block_mask(g, 0.1))
     assert mask[3] and mask.sum() == 1
+
+
+def test_interpret_mode_follows_backend(monkeypatch):
+    assert ops.interpret_mode() is True  # the tests run on the CPU backend
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(ops.jax, "default_backend", lambda b=backend: b)
+        assert ops.interpret_mode() is want
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops.interpret_mode()
+    with pytest.raises(RuntimeError):
+        ops.popcount_total(np.ones((8, 1024), np.uint32))
+
+
+@pytest.fixture
+def restore_cache_config():
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs")
+    prev = {k: getattr(ops.jax.config, k) for k in keys}
+    yield
+    for k, v in prev.items():
+        ops.jax.config.update(k, v)
+
+
+def test_compile_cache_defaults_to_checkout(monkeypatch,
+                                            restore_cache_config):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = ops.use_compile_cache()
+    checkout = __import__("pathlib").Path(__file__).resolve().parents[1]
+    assert path == str(checkout / ".jax_cache")
+    assert ops.jax.config.jax_compilation_cache_dir == path
+    # kernels compile in well under a second: nothing may gate them out
+    assert ops.jax.config.jax_persistent_cache_min_compile_time_secs == 0
+
+
+def test_compile_cache_env_wins(tmp_path, monkeypatch, restore_cache_config):
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    before = ops.jax.config.jax_compilation_cache_dir
+    assert ops.use_compile_cache() == outside
+    # JAX reads the variable itself; the code sets no other directory
+    assert ops.jax.config.jax_compilation_cache_dir == before

@@ -479,6 +479,40 @@ def test_service_warm_start_and_reload(sharded_dir):
         svc.close()
 
 
+def test_service_kernel_backend_serves_store_on_threads(sharded_dir):
+    """A store-backed service whose backend reaches the kernels fans out
+    on threads (one process holds the device) instead of forking workers
+    that would raise ForkSafetyError, and answers as the EWAH path does."""
+    from concurrent.futures import ThreadPoolExecutor
+    from repro.core.executor import Executor
+    _table, _cards, _sh, d = sharded_dir
+    kern = QueryService.from_dir(d, backend="kernel")
+    host = QueryService.from_dir(d, backend="ewah", shard_processes=0)
+    try:
+        assert isinstance(kern._shard_pool, ThreadPoolExecutor)
+        before = Executor.kernel_dispatches
+        for e in queries():
+            got, want = kern.query(expr_to_json(e)), host.query(
+                expr_to_json(e))
+            assert got["count"] == want["count"]
+            assert got["rows"] == want["rows"]
+        assert Executor.kernel_dispatches > before
+        q = expr_to_json(col("day") <= 3)
+        assert kern.group_count("region", q)["counts"] == \
+            host.group_count("region", q)["counts"]
+    finally:
+        kern.close()
+        host.close()
+
+
+@pytest.mark.parametrize("backend", ["auto", "kernel"])
+def test_service_refuses_shard_processes_with_kernel_backend(sharded_dir,
+                                                             backend):
+    _table, _cards, _sh, d = sharded_dir
+    with pytest.raises(ValueError, match="backend='ewah'"):
+        QueryService.from_dir(d, backend=backend, shard_processes=2)
+
+
 def test_service_watcher_picks_up_shard_swap(sharded_dir):
     """The --watch-interval poller: an out-of-band shard-file replacement is
     swapped in with no /admin/reload call, and the *sibling* shards'

@@ -1,7 +1,7 @@
 """Lemmas 1/2: compressed logical ops scale with non-zero words, not n_bits.
 
 Also times the Pallas word_logical kernel (interpret mode — correctness
-path; the TPU performance story lives in the roofline) vs the jnp oracle,
+path; its TPU timings come from the chip benchmark) vs the jnp oracle,
 and compares EWAH vs WAH compressed sizes across densities.
 """
 from __future__ import annotations

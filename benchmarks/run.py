@@ -1,12 +1,9 @@
 """Benchmark orchestrator — one function per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV rows (see benchmarks/common.py).
-The roofline table (from the dry-run artifacts) is appended when results
-exist; run ``python -m repro.launch.sweep`` first to (re)generate them.
 """
 from __future__ import annotations
 
-import sys
 import time
 
 
@@ -20,18 +17,6 @@ def main() -> None:
     bench_bitmap_profile.run()
     bench_block_sort.run()
     bench_logical_ops.run()
-
-    # roofline table from dry-run artifacts (skipped if sweep not yet run)
-    try:
-        from . import roofline
-        if list(roofline.RESULTS.glob("*.json")):
-            print("\n== roofline (from multi-pod dry-run artifacts) ==")
-            roofline.run()
-            print("\n== §Perf hillclimb variants (3 cells) ==")
-            from . import perf_variants
-            perf_variants.run()
-    except Exception as e:  # noqa: BLE001
-        print(f"roofline skipped: {e}", file=sys.stderr)
     print(f"\n[benchmarks] total {time.time()-t0:.0f}s")
 
 

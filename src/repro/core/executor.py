@@ -33,6 +33,7 @@ import numpy as np
 
 from . import cost_model as _cm
 from . import measures as _ms
+from . import trace as _trace
 from .ewah import EWAH, and_many, or_many
 from .expr import Expr
 from .index import BitmapIndex
@@ -103,7 +104,8 @@ class Executor:
         key = ("bm", node.col, node.bitmap_id)
         bm = self.cache.get(key)
         if bm is None:
-            bm = self.index.bitmap(node.col, node.bitmap_id)
+            with _trace.span("executor.load"):
+                bm = self.index.bitmap(node.col, node.bitmap_id)
             self.cache[key] = bm
         return bm
 
@@ -128,14 +130,16 @@ class Executor:
     @staticmethod
     def _pad_and_flags(bm: EWAH, cp: int):
         from repro.kernels import ops as kops
-        w = bm.to_words()
-        if len(w) < cp:
-            w = np.pad(w, (0, cp - len(w)))
-        if bm._cont is not None and bm._words is None:
-            # container-backed: flags come off the chunk directory (EMPTY/
-            # FULL/ARRAY chunks never scan words), bit-identical to below
-            return w, kops.container_row_flags(bm._cont, len(w))
-        return w, kops.np_row_flags(w)
+        with _trace.span("executor.densify"):
+            w = bm.to_words()
+            if len(w) < cp:
+                w = np.pad(w, (0, cp - len(w)))
+            if bm._cont is not None and bm._words is None:
+                # container-backed: flags come off the chunk directory
+                # (EMPTY/FULL/ARRAY chunks never scan words), bit-identical
+                # to below
+                return w, kops.container_row_flags(bm._cont, len(w))
+            return w, kops.np_row_flags(w)
 
     # -- evaluation --------------------------------------------------------
     def run(self, node: PlanNode) -> EWAH:
@@ -192,7 +196,9 @@ class Executor:
 
     def _run_composite(self, node: PlanNode) -> EWAH:
         if isinstance(node, PNot):
-            return ~self._run(node.child)
+            child = self._run(node.child)
+            with _trace.span("executor.ewah_logical"):
+                return ~child
         if isinstance(node, PDiff):
             return self._run_diff(node)
         assert isinstance(node, (PAnd, POr))
@@ -201,7 +207,8 @@ class Executor:
         if self._use_kernel([bm for _, bm in children]):
             return self._reduce_kernel(children, op)
         bms = [bm for _, bm in children]
-        return and_many(bms) if op == "and" else or_many(bms)
+        with _trace.span("executor.ewah_logical"):
+            return and_many(bms) if op == "and" else or_many(bms)
 
     # -- aggregation (compressed domain) -----------------------------------
     def run_count(self, node: PCount) -> int:
@@ -212,7 +219,9 @@ class Executor:
             return self.index.n_rows if child.value else 0
         # the filter is a *subexpression* of the count statement: cached,
         # so a row query or group-by over the same filter reuses it
-        return self._run(child).count()
+        bm = self._run(child)
+        with _trace.span("executor.aggregate"):
+            return bm.count()
 
     # a group bitmap whose literal pool would expand to far more intervals
     # than the filter exposes is cheaper to intersect pairwise: past this
@@ -245,13 +254,20 @@ class Executor:
                 return out
             filt = None
         if filt is None:
-            for g, gn in enumerate(node.groups):
-                if isinstance(gn, PConst):
-                    out[g] = self.index.n_rows if gn.value else 0
-                else:
-                    out[g] = self._run(gn).count()
+            with _trace.span("executor.aggregate"):
+                for g, gn in enumerate(node.groups):
+                    if isinstance(gn, PConst):
+                        out[g] = self.index.n_rows if gn.value else 0
+                    else:
+                        out[g] = self._run(gn).count()
             return out
         fbm = self._run(filt)
+        with _trace.span("executor.aggregate"):
+            return self._group_count(node, fbm, out)
+
+    def _group_count(self, node: PGroupCount, fbm: EWAH,
+                     out: np.ndarray) -> np.ndarray:
+        """``run_group_count`` once its filter is evaluated to ``fbm``."""
         # the filter always takes the interval view, even when
         # literal-heavy: its expansion is paid once (memoized on the EWAH,
         # which the subexpression cache keeps alive) and the per-query
@@ -286,22 +302,29 @@ class Executor:
                            minlength=len(node.groups)).astype(np.int64)
         return out
 
-    def _filter_intervals(self, filt: Optional[PlanNode]):
-        """A filter node's set-bit intervals, ``None`` filters covering all
-        rows; returns empty arrays for an all-false filter."""
-        if isinstance(filt, PConst):
-            if not filt.value:
-                return (np.empty(0, dtype=np.int64),
-                        np.empty(0, dtype=np.int64))
-            filt = None
+    def _filter(self, filt: Optional[PlanNode]):
+        """Evaluate a filter node: its EWAH, or ``True``/``False`` for all
+        or no rows (a ``None`` filter covers all rows)."""
         if filt is None:
+            return True
+        if isinstance(filt, PConst):
+            return filt.value
+        return self._run(filt)
+
+    def _filter_intervals(self, f):
+        """Set-bit intervals of an evaluated filter (``_filter``); empty
+        arrays for an all-false filter."""
+        if f is True:
             n = self.index.n_rows
             if not n:
                 return (np.empty(0, dtype=np.int64),
                         np.empty(0, dtype=np.int64))
             return (np.asarray([0], dtype=np.int64),
                     np.asarray([n], dtype=np.int64))
-        return self._run(filt).set_intervals()
+        if f is False:
+            return (np.empty(0, dtype=np.int64),
+                    np.empty(0, dtype=np.int64))
+        return f.set_intervals()
 
     def run_agg(self, node: PAgg):
         """Scalar ``(sum, count, min, max)`` of a measure under the node's
@@ -309,8 +332,10 @@ class Executor:
         directly (one gather, three reductions) — no row ids, no result
         bitmap, no row reconstruction."""
         values = self.index.measure(node.measure)
-        fs, fe = self._filter_intervals(node.filter)
-        return _ms.reduce_intervals(values, fs, fe)
+        f = self._filter(node.filter)
+        with _trace.span("executor.aggregate"):
+            fs, fe = self._filter_intervals(f)
+            return _ms.reduce_intervals(values, fs, fe)
 
     def run_group_agg(self, node: PGroupAgg) -> Dict:
         """Grouped aggregates over one or two columns in the filtered
@@ -327,12 +352,18 @@ class Executor:
         each into its ``(rank_a, rank_b)`` cell — cost O(selected rows +
         intervals), never O(card_a * card_b * rows).
         """
+        f = self._filter(node.filter)
+        with _trace.span("executor.aggregate"):
+            return self._group_agg(node, f)
+
+    def _group_agg(self, node: PGroupAgg, f) -> Dict:
+        """``run_group_agg`` once its filter is evaluated to ``f``."""
         cards = tuple(len(g) for g in node.groups)
         name = node.measure
         values = self.index.measure(name) if name is not None else None
         dt = _ms.measure_dtype_str(values) if values is not None else None
         out = _ms.empty_group_agg(node.cols, cards, name, dt)
-        fs, fe = self._filter_intervals(node.filter)
+        fs, fe = self._filter_intervals(f)
         if not len(fs):
             return out
         F = int((fe - fs).sum())
@@ -393,21 +424,22 @@ class Executor:
         neg = [(ch, self._run(ch)) for ch in node.neg]
         if self._use_kernel([bm for _, bm in pos + neg]):
             from repro.kernels import ops as kops
-            Executor.kernel_dispatches += 1
+            self._count_dispatch()
             pw, pf = zip(*[self._dense_operand(n, bm) for n, bm in pos])
             nw, nf = zip(*[self._dense_operand(n, bm) for n, bm in neg])
-            a = kops.logical_reduce(np.stack(pw), op="and",
-                                    row_flags=np.stack(pf))
-            b = kops.logical_reduce(np.stack(nw), op="or",
-                                    row_flags=np.stack(nf))
-            out = np.asarray(kops.word_logical(a[None, :], b[None, :],
-                                               "andnot"))[0]
-            n_words = pos[0][1].n_words_uncompressed
-            return EWAH.from_words(out[:n_words], pos[0][1].n_bits)
-        acc = and_many([bm for _, bm in pos])
-        for _, bm in neg:
-            acc = acc.andnot(bm)
-        return acc
+            with _trace.span("executor.kernel"):
+                a = kops.logical_reduce(np.stack(pw), op="and",
+                                        row_flags=np.stack(pf))
+                b = kops.logical_reduce(np.stack(nw), op="or",
+                                        row_flags=np.stack(nf))
+                out = self._fetch(kops.word_logical(a[None, :], b[None, :],
+                                                    "andnot"))[0]
+            return self._reencode(out, pos[0][1])
+        with _trace.span("executor.ewah_logical"):
+            acc = and_many([bm for _, bm in pos])
+            for _, bm in neg:
+                acc = acc.andnot(bm)
+            return acc
 
     def _use_kernel(self, bms: Sequence[EWAH]) -> bool:
         if self.backend == "ewah":
@@ -424,13 +456,34 @@ class Executor:
 
     def _reduce_kernel(self, children, op: str) -> EWAH:
         from repro.kernels import ops as kops  # lazy: jax only on this path
-        Executor.kernel_dispatches += 1
+        self._count_dispatch()
         ws, fs = zip(*[self._dense_operand(node, bm) for node, bm in children])
-        out = np.asarray(kops.logical_reduce(np.stack(ws), op=op,
-                                             row_flags=np.stack(fs)))
-        n_bits = children[0][1].n_bits
-        n_words = children[0][1].n_words_uncompressed
-        return EWAH.from_words(out[:n_words], n_bits)
+        with _trace.span("executor.kernel"):
+            out = self._fetch(kops.logical_reduce(np.stack(ws), op=op,
+                                                  row_flags=np.stack(fs)))
+        return self._reencode(out, children[0][1])
+
+    @staticmethod
+    def _count_dispatch() -> None:
+        # shard threads dispatch concurrently, and ``+=`` on the class
+        # attribute is a read-modify-write: it takes the counters' lock
+        with _trace.counter_lock:
+            Executor.kernel_dispatches += 1
+
+    @staticmethod
+    def _fetch(out) -> np.ndarray:
+        """A kernel result copied to the host (waits for the device)."""
+        with _trace.span("executor.fetch"):
+            host = np.asarray(out)
+        _trace.add("kops.d2h_bytes", host.nbytes)
+        return host
+
+    @staticmethod
+    def _reencode(words: np.ndarray, like: EWAH) -> EWAH:
+        """Dense result words back to an EWAH of ``like``'s length."""
+        with _trace.span("executor.reencode"):
+            return EWAH.from_words(words[:like.n_words_uncompressed],
+                                   like.n_bits)
 
 
 def _shard_caches(index, cache: Optional[Dict]) -> Optional[List[Dict]]:

@@ -36,11 +36,13 @@ results — not just leaf bitmaps — across the statements of a batch.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import List, Optional, Tuple
 
 import numpy as np
 
+from . import trace as _trace
 from .expr import And, Const, Eq, Expr, In, Not, Or, Range
 from .index import BitmapIndex
 
@@ -261,6 +263,15 @@ def _nary_key(tag: str, children) -> Optional[tuple]:
     return (tag,) + tuple(sorted(keys, key=repr))
 
 
+def _spanned(method):
+    """A planner entry point, timed as one ``planner.plan`` span."""
+    @functools.wraps(method)
+    def wrapper(self, *args, **kwargs):
+        with _trace.span("planner.plan"):
+            return method(self, *args, **kwargs)
+    return wrapper
+
+
 class Planner:
     def __init__(self, index: BitmapIndex, optimize: bool = True,
                  cost_model=None, use_counts: bool = True):
@@ -295,20 +306,26 @@ class Planner:
         return (node.est_words,)
 
     # -- lowering ---------------------------------------------------------
+    @_spanned
     def plan(self, e: Expr) -> PlanNode:
+        return self._plan(e)
+
+    def _plan(self, e: Expr) -> PlanNode:
         if self.optimize:
             e = flatten(push_not(e))
         return self._lower(e)
 
+    @_spanned
     def plan_count(self, e: Optional[Expr] = None) -> PCount:
         """Lower a COUNT statement: ``e is None`` counts every row."""
-        child = self.plan(e) if e is not None else self._const(True)
+        child = self._plan(e) if e is not None else self._const(True)
         node = PCount(child)
         node.est_words = 0
         node.est_rows = child.est_rows
         node.ckey = ("count", child.ckey)
         return node
 
+    @_spanned
     def plan_group_count(self, col, e: Optional[Expr] = None) -> PGroupCount:
         """Lower a GROUP BY ``col`` COUNT(*) statement.
 
@@ -320,7 +337,7 @@ class Planner:
         enc = self.index.columns[c].encoder
         codes = enc.codes(np.arange(card, dtype=np.int64))
         groups = [self._value_node(c, code) for code in codes]
-        filt = self.plan(e) if e is not None else None
+        filt = self._plan(e) if e is not None else None
         node = PGroupCount(c, groups, filt)
         node.est_words = 0
         node.est_rows = filt.est_rows if filt is not None else \
@@ -336,11 +353,12 @@ class Planner:
                 f"unknown measure {name!r}; this index declares "
                 f"{sorted(measures)}")
 
+    @_spanned
     def plan_agg(self, measure: str, e: Optional[Expr] = None) -> PAgg:
         """Lower a scalar measure aggregate (sum/avg/min/max/count of a
         measure) under an optional filter."""
         self._measure_check(measure)
-        filt = self.plan(e) if e is not None else None
+        filt = self._plan(e) if e is not None else None
         node = PAgg(measure, filt)
         node.est_words = 0
         node.est_rows = filt.est_rows if filt is not None else \
@@ -352,6 +370,7 @@ class Planner:
                          None if filt is None else filt.ckey)
         return node
 
+    @_spanned
     def plan_group_agg(self, measure: Optional[str], cols,
                        e: Optional[Expr] = None) -> PGroupAgg:
         """Lower a grouped aggregate over one or two grouping columns.
@@ -376,7 +395,7 @@ class Planner:
             enc = self.index.columns[c].encoder
             codes = enc.codes(np.arange(self.index.card(c), dtype=np.int64))
             groups.append([self._value_node(c, code) for code in codes])
-        filt = self.plan(e) if e is not None else None
+        filt = self._plan(e) if e is not None else None
         node = PGroupAgg(measure, tuple(resolved), tuple(groups), filt)
         node.est_words = 0
         node.est_rows = filt.est_rows if filt is not None else \

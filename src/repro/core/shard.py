@@ -36,6 +36,7 @@ from typing import Dict, List, Optional, Sequence, Union
 
 import numpy as np
 
+from . import trace as _trace
 from .ewah import EWAH
 from .expr import Expr, canonical_key
 from .index import (BitmapIndex, ColumnIndex, IndexBuilder, WORD_ROWS,
@@ -394,9 +395,15 @@ class ShardedIndex:
                                     optimize=optimize)
         elif pool is not None and not isinstance(pool, ShardProcessPool) \
                 and len(missing) > 1:
-            fresh = list(pool.map(lambda i: run_shard(i, shards[i]), missing))
+            runs = [_trace.bind(run_shard, wait="shard.queue_wait",
+                                run="shard.run") for _ in missing]
+            fresh = list(pool.map(lambda run, i: run(i, shards[i]), runs,
+                                  missing))
         else:
-            fresh = [run_shard(i, shards[i]) for i in missing]
+            fresh = []
+            for i in missing:
+                with _trace.span("shard.run"):
+                    fresh.append(run_shard(i, shards[i]))
         for i, res in zip(missing, fresh):
             parts[i] = res
             if key is not None:
@@ -669,6 +676,7 @@ _IN_FORK_WORKER = False
 def _fork_worker_init() -> None:
     global _IN_FORK_WORKER
     _IN_FORK_WORKER = True
+    _trace.disable()  # spans recorded here would never reach the parent
 
 
 def _guard_backend(backend: str) -> str:

@@ -31,6 +31,7 @@ from . import word_logical as _wl
 from . import popcount as _pc
 from . import bitpack_kernel as _bp
 from . import grad_compress as _gc
+from repro.core import trace as _trace
 
 _ALL_ONES = np.uint32(0xFFFFFFFF)
 
@@ -180,6 +181,40 @@ def _pad_rows_np(rf: Optional[np.ndarray], rows: int, br: int) -> Optional[np.nd
     return np.pad(rf, ((0, pad), (0, 0)), constant_values=_wl.CLEAN0)
 
 
+def _to_device(x) -> jax.Array:
+    """``x`` as a device uint32 array; a host array's bytes count as
+    ``kops.h2d_bytes``."""
+    if isinstance(x, np.ndarray):
+        _trace.add("kops.h2d_bytes", 4 * x.size)
+    return jnp.asarray(x, jnp.uint32)
+
+
+def _tile_flags_to_device(row_flags: np.ndarray, rows: int,
+                          block_rows: int) -> jax.Array:
+    """Host per-row flags of ``rows`` operand rows as device tile flags."""
+    tf = _combine_row_flags(_pad_rows_np(row_flags, rows, block_rows),
+                            block_rows)
+    _trace.add("kops.h2d_bytes", tf.nbytes)
+    return jnp.asarray(tf)
+
+
+def _word_logical_dev(a: jax.Array, b: jax.Array, fa, fb, op: str,
+                      interpret: bool, block_rows: int, block_cols: int,
+                      bucket: bool) -> jax.Array:
+    """``word_logical`` on device operands; ``fa``/``fb`` are their device
+    tile flags, or None to compute them on the device."""
+    bc_pad = bucket_cols(a.shape[1], block_cols) if bucket else block_cols
+    ap, orig = _pad2(a, block_rows, bc_pad)
+    bp_, _ = _pad2(b, block_rows, bc_pad)
+    if fa is None:
+        fa = _wl.tile_flags(ap, block_rows, block_cols)
+    if fb is None:
+        fb = _wl.tile_flags(bp_, block_rows, block_cols)
+    out = _wl.word_logical(ap, bp_, fa, fb, op=op, block_rows=block_rows,
+                           block_cols=block_cols, interpret=interpret)
+    return out[: orig[0], : orig[1]]
+
+
 def word_logical(a, b, op: str = "and", interpret: Optional[bool] = None,
                  block_rows: int = 8, block_cols: int = 1024,
                  bucket: bool = True,
@@ -194,25 +229,16 @@ def word_logical(a, b, op: str = "and", interpret: Optional[bool] = None,
     sidebands for the (bucketed) inputs; absent, flags are computed on
     device.
     """
-    a = jnp.asarray(a, jnp.uint32)
-    b = jnp.asarray(b, jnp.uint32)
-    bc_pad = bucket_cols(a.shape[1], block_cols) if bucket else block_cols
-    ap, orig = _pad2(a, block_rows, bc_pad)
-    bp_, _ = _pad2(b, block_rows, bc_pad)
-    if row_flags_a is None:
-        fa = _wl.tile_flags(ap, block_rows, block_cols)
-    else:
-        fa = jnp.asarray(_combine_row_flags(
-            _pad_rows_np(row_flags_a, orig[0], block_rows), block_rows))
-    if row_flags_b is None:
-        fb = _wl.tile_flags(bp_, block_rows, block_cols)
-    else:
-        fb = jnp.asarray(_combine_row_flags(
-            _pad_rows_np(row_flags_b, orig[0], block_rows), block_rows))
-    out = _wl.word_logical(ap, bp_, fa, fb, op=op, block_rows=block_rows,
-                           block_cols=block_cols,
-                           interpret=_interpret(interpret))
-    return out[: orig[0], : orig[1]]
+    with _trace.span("kops.transfer_in"):
+        a, b = _to_device(a), _to_device(b)
+        fa = fb = None
+        if row_flags_a is not None:
+            fa = _tile_flags_to_device(row_flags_a, a.shape[0], block_rows)
+        if row_flags_b is not None:
+            fb = _tile_flags_to_device(row_flags_b, a.shape[0], block_rows)
+    with _trace.span("kops.launch"):
+        return _word_logical_dev(a, b, fa, fb, op, _interpret(interpret),
+                                 block_rows, block_cols, bucket)
 
 
 def logical_reduce(mat, op: str = "and", interpret: Optional[bool] = None,
@@ -234,42 +260,50 @@ def logical_reduce(mat, op: str = "and", interpret: Optional[bool] = None,
     ``row_flags`` is the optional (L, cols/block_cols) precomputed clean
     sideband of the input rows; it accelerates the first (widest) round,
     later rounds recompute flags on device for their intermediate results.
+    Its DIRTY flags count as ``kops.dirty_tile_bytes``.
     """
     assert op in ("and", "or", "xor"), op  # associative ops only
     interpret = _interpret(interpret)
-    mat = jnp.asarray(mat, jnp.uint32)
-    assert mat.ndim == 2 and mat.shape[0] >= 1, mat.shape
-    L, C = mat.shape
-    if bucket:
-        Cp = bucket_cols(C, block_cols)
-        Lp = next_pow2(L)
-        identity = _ALL_ONES if op == "and" else np.uint32(0)
-        if Cp != C:
-            mat = jnp.pad(mat, ((0, 0), (0, Cp - C)))
-        if Lp != L:
-            mat = jnp.concatenate(
-                [mat, jnp.full((Lp - L, Cp), identity, jnp.uint32)], axis=0)
+    with _trace.span("kops.transfer_in"):
+        mat = _to_device(mat)
+        assert mat.ndim == 2 and mat.shape[0] >= 1, mat.shape
+        L, C = mat.shape
+        Cp = bucket_cols(C, block_cols) if bucket else C
+        Lp = next_pow2(L) if bucket else L
+        fa = fb = None
         if row_flags is not None:
-            pad_flag = _wl.CLEAN1 if op == "and" else _wl.CLEAN0
-            row_flags = np.pad(row_flags, ((0, Lp - L), (0, 0)),
-                               constant_values=pad_flag)
-    first = True
-    while mat.shape[0] > 1:
-        half = mat.shape[0] // 2
-        rfa = rfb = None
-        if first and row_flags is not None:
-            # word_logical row-pads flags itself (CLEAN0, matching _pad2's
-            # zero rows), so any half size works
-            rfa, rfb = row_flags[:half], row_flags[half:2 * half]
-        red = word_logical(mat[:half], mat[half:2 * half], op,
-                           interpret=interpret, block_rows=block_rows,
-                           block_cols=block_cols, bucket=bucket,
-                           row_flags_a=rfa, row_flags_b=rfb)
-        if mat.shape[0] % 2:  # odd row carries to the next round
-            red = jnp.concatenate([red, mat[2 * half:]], axis=0)
-        mat = red
-        first = False
-    return mat[0][:C]
+            _trace.add("kops.dirty_tile_bytes", 4 * block_cols * int(
+                np.count_nonzero(np.asarray(row_flags) == _wl.DIRTY)))
+            if bucket:
+                pad_flag = _wl.CLEAN1 if op == "and" else _wl.CLEAN0
+                row_flags = np.pad(row_flags, ((0, Lp - L), (0, 0)),
+                                   constant_values=pad_flag)
+            half = Lp // 2
+            if half:
+                # the flags are row-padded with CLEAN0, matching _pad2's
+                # zero rows, so any half size works
+                fa = _tile_flags_to_device(row_flags[:half], half, block_rows)
+                fb = _tile_flags_to_device(row_flags[half:2 * half], half,
+                                           block_rows)
+    with _trace.span("kops.launch"):
+        if bucket:
+            identity = _ALL_ONES if op == "and" else np.uint32(0)
+            if Cp != C:
+                mat = jnp.pad(mat, ((0, 0), (0, Cp - C)))
+            if Lp != L:
+                mat = jnp.concatenate(
+                    [mat, jnp.full((Lp - L, Cp), identity, jnp.uint32)],
+                    axis=0)
+        while mat.shape[0] > 1:
+            half = mat.shape[0] // 2
+            red = _word_logical_dev(mat[:half], mat[half:2 * half], fa, fb,
+                                    op, interpret, block_rows, block_cols,
+                                    bucket)
+            if mat.shape[0] % 2:  # odd row carries to the next round
+                red = jnp.concatenate([red, mat[2 * half:]], axis=0)
+            mat = red
+            fa = fb = None  # later rounds flag their inputs on the device
+        return mat[0][:C]
 
 
 def popcount_total(a, interpret: Optional[bool] = None) -> jax.Array:

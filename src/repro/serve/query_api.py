@@ -61,7 +61,8 @@ Production-shaped serving on a dependency-free stack (stdlib ``http.server``
                                                           layout, rolling swap
     GET  /healthz                                      -> liveness
     GET  /stats                                        -> index + cache stats
-                                                          (+ live/compaction)
+                                                          (+ live/compaction,
+                                                          + trace summary)
 
 Wire format for expressions (mirrors the AST):
     {"op": "eq", "col": 0, "value": 3}
@@ -93,6 +94,9 @@ Run standalone against a synthetic sorted table:
 Build once, then warm-start serve:
     PYTHONPATH=src python -m repro.serve.query_api --shards 4 --save-index /tmp/idx
     PYTHONPATH=src python -m repro.serve.query_api --index-dir /tmp/idx
+``--trace`` records spans of every statement's phases (``repro.core.trace``);
+``/stats`` reports their count, total, self and CPU seconds under
+``trace``.
 """
 from __future__ import annotations
 
@@ -111,6 +115,7 @@ from repro.core import BitmapIndex, ShardedIndex, lex_sort, synth
 from repro.core import cost_model
 from repro.core import measures as measures_mod
 from repro.core import store as index_store
+from repro.core import trace
 from repro.core.dataset import top_k_from_counts, top_k_from_values
 from repro.core.expr import Expr, canonical_key, from_wire, to_wire
 from repro.core.executor import (execute, execute_agg, execute_count,
@@ -878,23 +883,41 @@ class QueryService:
             return head + explain(plan(idx.shards[0], e))
         return explain(plan(idx, e))
 
+    def _submit(self, fn, *args):
+        """``fn(*args)`` on the query pool: ``service.queue_wait`` until a
+        worker takes it, then ``service.execute``."""
+        return self._pool.submit(
+            trace.bind(fn, wait="service.queue_wait", run="service.execute"),
+            *args)
+
+    def _call(self, fn, *args):
+        """One statement: ``fn(*args)`` on the query pool, awaited."""
+        with trace.statement():
+            return self._submit(fn, *args).result()
+
     def query(self, expr, explain_plan: bool = False) -> Dict:
-        e = parse_expr(expr) if isinstance(expr, dict) else expr
-        return self._pool.submit(self._query_one, e, explain_plan).result()
+        with trace.statement():
+            with trace.span("service.parse"):
+                e = parse_expr(expr) if isinstance(expr, dict) else expr
+            return self._call(self._query_one, e, explain_plan)
 
     def query_batch(self, exprs: Sequence) -> List[Dict]:
-        es = [parse_expr(e) if isinstance(e, dict) else e for e in exprs]
-        # the whole batch executes against one (generation, index) snapshot,
-        # so a mid-batch set_index can't mix bitmaps of two indexes through
-        # the shared operand cache; uncached queries share loaded operands
-        # via the Executor's dict (benign races — worst case a bitmap loads
-        # twice), with per-shard sub-caches on the sharded path
-        snapshot = self._snapshot()
-        op_cache: Dict = {}
-        futs = [self._pool.submit(self._query_one, e, False, op_cache,
-                                  snapshot)
-                for e in es]
-        return [f.result() for f in futs]
+        with trace.statement():
+            with trace.span("service.parse"):
+                es = [parse_expr(e) if isinstance(e, dict) else e
+                      for e in exprs]
+            # the whole batch executes against one (generation, index)
+            # snapshot, so a mid-batch set_index can't mix bitmaps of two
+            # indexes through the shared operand cache; uncached queries
+            # share loaded operands via the Executor's dict (benign races —
+            # worst case a bitmap loads twice), with per-shard sub-caches on
+            # the sharded path
+            snapshot = self._snapshot()
+            op_cache: Dict = {}
+            futs = [self._submit(self._query_one, e, False, op_cache,
+                                 snapshot)
+                    for e in es]
+            return [f.result() for f in futs]
 
     # -- aggregation statements (compressed domain) -------------------------
     def _agg_cached(self, kind: str, col, e: Optional[Expr], compute):
@@ -1003,28 +1026,26 @@ class QueryService:
 
     def count(self, where=None) -> Dict:
         e = parse_expr(where) if isinstance(where, dict) else where
-        return self._pool.submit(self._count_one, e).result()
+        return self._call(self._count_one, e)
 
     def group_count(self, col, where=None) -> Dict:
         e = parse_expr(where) if isinstance(where, dict) else where
-        return self._pool.submit(self._group_count_one, col, e).result()
+        return self._call(self._group_count_one, col, e)
 
     def top_k(self, col, k: int, where=None, measure=None) -> Dict:
         e = parse_expr(where) if isinstance(where, dict) else where
-        return self._pool.submit(self._top_k_one, col, k, e,
-                                 measure).result()
+        return self._call(self._top_k_one, col, k, e, measure)
 
     def agg(self, op: str, measure: str, where=None) -> Dict:
         """Scalar sum/avg/min/max of a measure under an optional filter."""
         e = parse_expr(where) if isinstance(where, dict) else where
-        return self._pool.submit(self._agg_one, op, measure, e).result()
+        return self._call(self._agg_one, op, measure, e)
 
     def group_agg(self, op: str, measure: Optional[str], by,
                   where=None) -> Dict:
         """Grouped sum/avg/min/max/count over 1-2 columns."""
         e = parse_expr(where) if isinstance(where, dict) else where
-        return self._pool.submit(self._group_agg_one, op, measure,
-                                 list(by), e).result()
+        return self._call(self._group_agg_one, op, measure, list(by), e)
 
     def sql(self, text: str) -> Dict:
         """Execute one SQL-ish statement (see ``parse_sql``)."""
@@ -1032,21 +1053,21 @@ class QueryService:
 
     def statement(self, obj: Dict) -> Dict:
         """Execute one ``{"select": ..., "where": ...}`` wire statement."""
-        st = parse_statement(obj)
-        kind, e = st["kind"], st["where"]
-        if kind == "count":
-            return self._pool.submit(self._count_one, e).result()
-        if kind == "group_count":
-            return self._pool.submit(self._group_count_one,
-                                     st["col"], e).result()
-        if kind == "agg":
-            return self._pool.submit(self._agg_one, st["op"],
-                                     st["measure"], e).result()
-        if kind == "group_agg":
-            return self._pool.submit(self._group_agg_one, st["op"],
-                                     st["measure"], st["by"], e).result()
-        return self._pool.submit(self._top_k_one, st["col"], st["k"], e,
-                                 st["measure"]).result()
+        with trace.statement():
+            with trace.span("service.parse"):
+                st = parse_statement(obj)
+            kind, e = st["kind"], st["where"]
+            if kind == "count":
+                return self._call(self._count_one, e)
+            if kind == "group_count":
+                return self._call(self._group_count_one, st["col"], e)
+            if kind == "agg":
+                return self._call(self._agg_one, st["op"], st["measure"], e)
+            if kind == "group_agg":
+                return self._call(self._group_agg_one, st["op"],
+                                  st["measure"], st["by"], e)
+            return self._call(self._top_k_one, st["col"], st["k"], e,
+                              st["measure"])
 
     def stats(self) -> Dict:
         from repro.core.ingest import LiveIndex
@@ -1086,6 +1107,8 @@ class QueryService:
                 self.index_dir).get("layout")
         else:
             out["layout"] = None
+        # spans by name (count, total, self and CPU seconds), the counters
+        out["trace"] = trace.summary()
         m = cost_model.get_default()
         th = m.dense_threshold
         out["cost_model"] = {
@@ -1134,12 +1157,13 @@ class _Handler(BaseHTTPRequestHandler):
     max_body_bytes: Optional[int] = None  # set by make_server
 
     def _send(self, code: int, payload: Dict):
-        body = json.dumps(payload).encode()
-        self.send_response(code)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        with trace.span("service.respond"):
+            body = json.dumps(payload).encode()
+            self.send_response(code)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
 
     def _fail(self, exc: _HTTPError):
         self._send(exc.status, {"error": str(exc), "code": exc.code})
@@ -1227,9 +1251,15 @@ class _Handler(BaseHTTPRequestHandler):
             return
         if self.path != "/query":
             raise _HTTPError(404, "not_found", f"unknown path {self.path}")
-        req = self._body()
-        if "sql" in req:
-            self._send(200, self.service.statement(parse_sql(req["sql"])))
+        with trace.statement():
+            self._query()
+
+    def _query(self):
+        with trace.span("service.parse"):
+            req = self._body()
+            sql = parse_sql(req["sql"]) if "sql" in req else None
+        if sql is not None:
+            self._send(200, self.service.statement(sql))
         elif "select" in req:
             self._send(200, self.service.statement(req))
         elif "queries" in req:
@@ -1337,7 +1367,12 @@ def main(argv=None):
                     help="largest accepted HTTP request body in bytes "
                          "(413 + code 'too_large' beyond it; default "
                          "unlimited)")
+    ap.add_argument("--trace", action="store_true",
+                    help="record spans of every statement's phases; "
+                         "/stats reports them under 'trace'")
     args = ap.parse_args(argv)
+    if args.trace:
+        trace.enable()
     if args.backend != "ewah":  # only the kernel path compiles anything
         from repro.kernels.ops import use_compile_cache
         use_compile_cache()

@@ -30,6 +30,9 @@ Hot path (this module's two execution strategies):
   merge + marker emission) entirely with vectorized NumPy.  Output words are
   bit-identical to ``binary_op``'s; n-ary reductions fold at the run-list
   level so intermediate results never round-trip through the word codec.
+
+The dense codec between this form and the Pallas kernels' operands
+(``to_words``, ``from_words``) is whole-array too, on the same helpers.
 """
 from __future__ import annotations
 
@@ -66,19 +69,24 @@ Run = Tuple[str, int, int]          # ('run', bit, count)
 Lit = Tuple[str, np.ndarray]        # ('lit', words)
 
 
+def _word_groups(words: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """Per-word kinds (``KIND_CLEAN0`` / ``KIND_CLEAN1`` / ``KIND_LIT``) and
+    the bounds of the maximal same-kind stretches: ``[0, ..., len]``."""
+    key = np.full(len(words), KIND_LIT, np.int8)
+    key[words == 0] = KIND_CLEAN0
+    key[words == ALL_ONES] = KIND_CLEAN1
+    bounds = np.concatenate(
+        ([0], np.flatnonzero(key[1:] != key[:-1]) + 1, [len(words)]))
+    return key, bounds
+
+
 def _split_literal(words: np.ndarray) -> Iterator:
     """Split a word array into maximal clean runs / literal stretches."""
-    n = len(words)
-    if n == 0:
+    if len(words) == 0:
         return
-    is_clean = (words == 0) | (words == ALL_ONES)
-    # group key: -1 literal, 0 clean-zero, 1 clean-one
-    key = np.where(is_clean, (words == ALL_ONES).astype(np.int8), np.int8(-1))
-    bounds = np.flatnonzero(key[1:] != key[:-1]) + 1
-    starts = np.concatenate(([0], bounds))
-    ends = np.concatenate((bounds, [n]))
-    for s, e in zip(starts, ends):
-        if key[s] < 0:
+    key, bounds = _word_groups(words)
+    for s, e in zip(bounds[:-1], bounds[1:]):
+        if key[s] == KIND_LIT:
             yield ("lit", words[s:e])
         else:
             yield ("run", int(key[s]), int(e - s))
@@ -148,6 +156,16 @@ class EWAH:
         return self.size_words * 4
 
     @property
+    def memo_nbytes(self) -> int:
+        """Bytes of the memoized run-list (0 when none is held): bounds,
+        kinds, literal offsets and the copy of the literal pool."""
+        rl = self._rl
+        if rl is None:
+            return 0
+        return (rl.bounds.nbytes + rl.kinds.nbytes + rl.lit_starts.nbytes
+                + rl.lits.nbytes)
+
+    @property
     def n_words_uncompressed(self) -> int:
         return -(-self.n_bits // WORD_BITS)
 
@@ -159,9 +177,21 @@ class EWAH:
     # -- construction -----------------------------------------------------
     @classmethod
     def from_words(cls, words: np.ndarray, n_bits: int) -> "EWAH":
-        """Compress a dense uint32 word array."""
+        """Compress a dense uint32 word array.
+
+        Whole-array: the maximal clean-0 / clean-1 / literal stretches are
+        the canonical RunList, which ``_rl_emit`` encodes, so the words
+        equal ``_emit(_split_literal(words))``'s and the run-list memo is
+        warm for the aggregate that reads the bitmap next."""
         words = np.asarray(words, dtype=WORD_DTYPE)
-        return cls(_emit(_split_literal(words)), n_bits)
+        if len(words) == 0:
+            return _rl_wrap(_EMPTY_RUNLIST, n_bits)
+        key, bounds = _word_groups(words)
+        kinds = key[bounds[:-1]]
+        lit_len = np.where(kinds == KIND_LIT, np.diff(bounds), 0)
+        rl = RunList(bounds, kinds, np.cumsum(lit_len) - lit_len,
+                     words[key == KIND_LIT])
+        return _rl_wrap(rl, n_bits)
 
     @classmethod
     def from_bool(cls, bits: np.ndarray) -> "EWAH":
@@ -252,18 +282,12 @@ class EWAH:
             # without a marker-stream decode
             from .containers import containers_to_dense
             return containers_to_dense(self._cont)
-        out = np.empty(self.n_words_uncompressed, dtype=WORD_DTYPE)
-        pos = 0
-        for seg in self.segments():
-            if seg[0] == "run":
-                _, bit, cnt = seg
-                out[pos : pos + cnt] = ALL_ONES if bit else 0
-                pos += cnt
-            else:
-                lit = seg[1]
-                out[pos : pos + len(lit)] = lit
-                pos += len(lit)
-        assert pos == self.n_words_uncompressed, (pos, self.n_words_uncompressed)
+        if self._rl is not None:
+            out = _rl_to_words(self._rl)
+        else:
+            out = _decode_words(self._words)
+        assert len(out) == self.n_words_uncompressed, \
+            (len(out), self.n_words_uncompressed)
         return out
 
     def to_bool(self) -> np.ndarray:
@@ -882,16 +906,19 @@ def _marker_positions(words: np.ndarray) -> np.ndarray:
     nlit = (words >> np.uint32(_LIT_SHIFT)).astype(np.int64)
     jump = np.minimum(np.arange(n, dtype=np.int64) + 1 + nlit, n)
     jump = np.append(jump, n)  # J[n] = n: past-the-end is a fixed point
+    step = jump
     mpos = np.zeros(1, dtype=np.int64)
-    while True:
+    # the chain is complete once its last known marker's successor lies
+    # past the end: checked before each squaring, so a stream of a few
+    # markers pays for no squaring it does not use
+    while step[mpos[-1]] < n:
         nxt = jump[mpos]
-        nxt = nxt[nxt < n]
-        if nxt.size == 0:
-            return mpos
         # chain entries are strictly increasing, so the newly reached
         # markers extend the known prefix in order with no duplicates
-        mpos = np.concatenate((mpos, nxt))
-        jump = jump[jump]
+        mpos = np.concatenate((mpos, nxt[nxt < n]))
+        if step[mpos[-1]] < n:
+            jump = jump[jump]
+    return mpos
 
 
 def _decode_runlist(words: np.ndarray) -> RunList:
@@ -939,6 +966,35 @@ def _decode_runlist(words: np.ndarray) -> RunList:
     item_word = np.zeros(len(item_kind), WORD_DTYPE)
     item_word[item_kind == KIND_LIT] = lits
     return _groups_to_runlist(item_kind, item_count, item_word)
+
+
+def _decode_words(words: np.ndarray) -> np.ndarray:
+    """Marker stream -> dense words, with no per-marker loop.
+
+    Each marker word stands for its clean run: replaced by the run's fill
+    word and repeated ``n_clean`` times, while every literal word is kept
+    once, so one ``np.repeat`` over the compressed stream is the output."""
+    if len(words) == 0:
+        return np.empty(0, WORD_DTYPE)
+    mpos = _marker_positions(words)
+    mk = np.asarray(words[mpos], dtype=WORD_DTYPE)
+    vals = np.array(words, dtype=WORD_DTYPE)
+    vals[mpos] = np.where(mk & np.uint32(1), ALL_ONES, np.uint32(0))
+    reps = np.ones(len(words), np.int64)
+    reps[mpos] = (mk >> np.uint32(_CLEAN_SHIFT)) & np.uint32(MAX_CLEAN)
+    return np.repeat(vals, reps)
+
+
+def _rl_to_words(rl: RunList) -> np.ndarray:
+    """RunList -> dense words: one scatter of the clean-one runs and one of
+    the literal pool into zeros."""
+    out = np.zeros(rl.n_words, WORD_DTYPE)
+    lens = np.diff(rl.bounds)
+    c1 = rl.kinds == KIND_CLEAN1
+    out[_ranges(rl.bounds[:-1][c1], lens[c1])] = ALL_ONES
+    lm = rl.kinds == KIND_LIT
+    out[_ranges(rl.bounds[:-1][lm], lens[lm])] = rl.lits
+    return out
 
 
 def _ranges(starts: np.ndarray, lens: np.ndarray) -> np.ndarray:

@@ -24,7 +24,9 @@ def payload_nbytes(v) -> int:
     ``size_bytes`` on a container-backed bitmap is its exact serialized
     container size (chunk directory + payloads), *not* the cost of the
     EWAH words it would lazily emit — so the byte budget tracks what the
-    cache actually holds in memory.
+    cache actually holds in memory.  A bitmap that carries a memoized
+    run-list (every kernel result, via ``EWAH.from_words``) adds its
+    ``memo_nbytes``: the run-list can outweigh the words several times.
 
     Aggregate results are *composite*: a scalar aggregate is a ``(sum,
     count, min, max)`` tuple, a grouped aggregate a dict of count/sum/
@@ -33,13 +35,13 @@ def payload_nbytes(v) -> int:
     dict branches below, every such entry would size as 0 and a result
     cache full of group-by matrices would evade its byte budget entirely."""
     size = getattr(v, "size_bytes", None)
-    if size is None:
-        if isinstance(v, (tuple, list)):
-            return sum(payload_nbytes(x) for x in v)
-        if isinstance(v, dict):
-            return sum(payload_nbytes(x) for x in v.values())
-        size = getattr(v, "nbytes", 0)
-    return int(size)
+    if size is not None:
+        return int(size) + int(getattr(v, "memo_nbytes", 0))
+    if isinstance(v, (tuple, list)):
+        return sum(payload_nbytes(x) for x in v)
+    if isinstance(v, dict):
+        return sum(payload_nbytes(x) for x in v.values())
+    return int(getattr(v, "nbytes", 0))
 
 
 def payload_kind(v) -> str:

@@ -9,8 +9,10 @@ import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
-from repro.core.ewah import (EWAH, RunList, and_many, binary_op, or_many,
-                             vec_binary_op)
+from repro.core import ewah as ewah_mod
+from repro.core.ewah import (ALL_ONES, EWAH, MAX_CLEAN, MAX_LIT, WORD_DTYPE,
+                             RunList, _decode_runlist, _emit, _split_literal,
+                             and_many, binary_op, or_many, vec_binary_op)
 
 OPS = ("and", "or", "xor", "andnot")
 
@@ -269,3 +271,133 @@ def test_vectorized_decode_matches_segments(seed, n, style):
     want_lits = (np.concatenate(lits) if lits
                  else np.empty(0, e.words.dtype))
     assert np.array_equal(rl.lits, want_lits)
+
+
+# -- the dense-word codec: to_words / from_words ----------------------------
+# The per-marker and per-segment loops these functions once ran, kept here
+# as the oracles the whole-array codec must match word for word.
+
+def loop_to_words(bm: EWAH) -> np.ndarray:
+    out = np.empty(bm.n_words_uncompressed, dtype=WORD_DTYPE)
+    pos = 0
+    for seg in bm.segments():
+        if seg[0] == "run":
+            _, bit, cnt = seg
+            out[pos:pos + cnt] = ALL_ONES if bit else 0
+            pos += cnt
+        else:
+            out[pos:pos + len(seg[1])] = seg[1]
+            pos += len(seg[1])
+    assert pos == bm.n_words_uncompressed
+    return out
+
+
+def loop_from_words(words: np.ndarray) -> np.ndarray:
+    return _emit(_split_literal(np.asarray(words, dtype=WORD_DTYPE)))
+
+
+def assert_codec_matches_loops(words: np.ndarray, n_bits: int) -> None:
+    """``from_words`` against ``_emit(_split_literal(.))``, and ``to_words``
+    against the segment loop, both from the marker stream alone (cold) and
+    from the memoized run-list."""
+    got = EWAH.from_words(words, n_bits)
+    want = loop_from_words(words)
+    assert got.words.dtype == WORD_DTYPE
+    assert np.array_equal(got.words, want)
+    cold = EWAH(want, n_bits)
+    assert cold._rl is None
+    dense = cold.to_words()
+    assert np.array_equal(dense, loop_to_words(cold))
+    assert np.array_equal(dense, words)
+    assert got._rl is not None
+    assert np.array_equal(got.to_words(), words)
+
+
+@settings(max_examples=150, deadline=None)
+@given(st.integers(0, 2**31), st.integers(0, 4096), st.integers(0, 3))
+def test_to_words_word_identical_to_segment_loop(seed, n, style):
+    bits = structured_bits(seed, n, style)
+    e = EWAH(EWAH.from_bool(bits).words, n)  # no run-list memo
+    assert np.array_equal(e.to_words(), loop_to_words(e))
+    assert np.array_equal(e.to_bool(), bits)
+    e.runlist()  # memoized: the run-list branch gives the same words
+    assert np.array_equal(e.to_words(), loop_to_words(e))
+
+
+@settings(max_examples=150, deadline=None)
+@given(st.integers(0, 2**31), st.integers(0, 4096), st.integers(0, 3))
+def test_from_words_word_identical_to_emit(seed, n, style):
+    from repro.core.bitpack import pack_bits
+    words = pack_bits(structured_bits(seed, n, style))
+    assert_codec_matches_loops(words, n)
+
+
+def _long_clean_run():
+    # a clean-one run past MAX_CLEAN words (> 2.1M bits) between literals
+    w = np.full(2 * MAX_CLEAN + 7, ALL_ONES, WORD_DTYPE)
+    w[0], w[-1] = 0x5, 0xA0000000
+    return w, len(w) * 32
+
+
+def _long_literal_stretch():
+    # more than MAX_LIT literal words in a row: continuation markers
+    rng = np.random.default_rng(5)
+    w = rng.integers(1, 0xFFFFFFFF, 2 * MAX_LIT + 11, dtype=np.uint64)
+    return w.astype(WORD_DTYPE), len(w) * 32
+
+
+def _shard_quantity():
+    # the 1.5M-row shard shape at density 1/51: a marker every few words
+    rng = np.random.default_rng(51)
+    from repro.core.bitpack import pack_bits
+    return pack_bits(rng.integers(0, 51, 1_500_000) == 0), 1_500_000
+
+
+def _unaligned_tail():
+    from repro.core.bitpack import pack_bits
+    rng = np.random.default_rng(9)
+    n = 32 * 1000 + 13
+    return pack_bits(rng.random(n) < 0.3), n
+
+
+@pytest.mark.parametrize("make", [
+    _long_clean_run,
+    _long_literal_stretch,
+    _shard_quantity,
+    lambda: (np.zeros(0, WORD_DTYPE), 0),                      # zero rows
+    _unaligned_tail,
+    lambda: (np.full(3 * MAX_CLEAN, ALL_ONES, WORD_DTYPE),     # all ones
+             3 * MAX_CLEAN * 32),
+    lambda: (np.zeros(3 * MAX_CLEAN + 1, WORD_DTYPE),          # all zeros
+             (3 * MAX_CLEAN + 1) * 32),
+], ids=["clean-run-over-max-clean", "literals-over-max-lit",
+        "shard-1.5M-density-1-51", "zero-rows", "unaligned-tail",
+        "all-ones", "all-zeros"])
+def test_codec_regimes_match_loops(make):
+    words, n_bits = make()
+    assert_codec_matches_loops(words, n_bits)
+
+
+def test_from_words_memoizes_its_runlist(monkeypatch):
+    """``from_words`` hands back the run-list it encoded from, equal to a
+    decode of its own words, so ``set_intervals`` and ``count`` never decode
+    the re-encoded result again."""
+    from repro.core.bitpack import pack_bits
+    rng = np.random.default_rng(13)
+    bits = rng.random(40_000) < 0.02
+    e = EWAH.from_words(pack_bits(bits), len(bits))
+    rl, ref = e._rl, _decode_runlist(e.words)
+    assert rl is not None
+    assert np.array_equal(rl.bounds, ref.bounds)
+    assert np.array_equal(rl.kinds, ref.kinds)
+    assert np.array_equal(rl.lit_starts, ref.lit_starts)
+    assert np.array_equal(rl.lits, ref.lits)
+
+    def no_decode(_words):
+        raise AssertionError("the re-encoded result was decoded again")
+
+    monkeypatch.setattr(ewah_mod, "_decode_runlist", no_decode)
+    monkeypatch.setattr(ewah_mod, "_decode_words", no_decode)
+    s, t = e.set_intervals()
+    assert int((t - s).sum()) == e.count() == int(bits.sum())
+    assert np.array_equal(e.to_words(), pack_bits(bits))

@@ -155,6 +155,54 @@ def test_dense_operand_cache_holds_bucketed_words_and_flags(sorted_table):
     assert out == ref
 
 
+def test_kernel_result_densified_again_from_its_runlist(monkeypatch):
+    """An unsorted table's fragmented bitmaps through the kernel backend:
+    the same words as the EWAH backend, and an OR re-encoded from the
+    kernel's words reaches its parent's kernel from its memoized run-list,
+    with no second decode of its marker stream."""
+    from repro.core import ewah as ewah_mod
+    rng = np.random.default_rng(17)
+    n = 40_000
+    table = np.stack([rng.integers(0, 51, n), rng.integers(0, 11, n),
+                      rng.integers(0, 4, n)], axis=1)
+    idx = BitmapIndex.build(table, k=1)
+    assert idx.bitmap(0, 3).size_words > (n // 32) // 2  # fragmented
+    e = col(0).isin((3, 9, 17, 30)) & (col(1) <= 5) & (col(2) == 1)
+
+    reencoded, densified, cold = [], [], []
+    reencode, pad, decode = (Executor._reencode, Executor._pad_and_flags,
+                             ewah_mod._decode_words)
+
+    def spy_reencode(words, like):
+        out = reencode(words, like)
+        reencoded.append(out)
+        return out
+
+    def spy_pad(bm, cp):
+        densified.append(bm)
+        return pad(bm, cp)
+
+    def spy_decode(words):
+        cold.append(words)
+        return decode(words)
+
+    monkeypatch.setattr(Executor, "_reencode", staticmethod(spy_reencode))
+    monkeypatch.setattr(Executor, "_pad_and_flags", staticmethod(spy_pad))
+    monkeypatch.setattr(ewah_mod, "_decode_words", spy_decode)
+    got = Executor(idx, backend="kernel").run(plan(idx, e))
+    ref = execute(idx, e, backend="ewah")
+    assert np.array_equal(got.words, ref.words)
+    want = (np.isin(table[:, 0], (3, 9, 17, 30)) & (table[:, 1] <= 5)
+            & (table[:, 2] == 1))
+    assert got.count() == int(want.sum())
+
+    fed = [bm for bm in densified if any(bm is r for r in reencoded)]
+    assert fed, "no kernel result was an operand of a parent kernel"
+    for bm in fed:
+        assert bm._rl is not None
+        assert not any(w is bm.words for w in cold)
+
+
 # -- shard-parallel execution ----------------------------------------------
 
 @pytest.fixture(scope="module")
@@ -281,6 +329,28 @@ def test_lru_oversized_entry_and_replacement():
     c.put("k", b"x" * 10)      # replacement updates accounting
     assert c.stats()["bytes"] == 10
     assert len(c) == 1
+
+
+def test_payload_nbytes_counts_a_memoized_runlist():
+    """A kernel result (``from_words``) carries its run-list: the result
+    caches' byte budget counts it beside the words."""
+    from repro.core.ewah import EWAH
+    from repro.core.lru import payload_nbytes
+    rng = np.random.default_rng(3)
+    words = np.where(rng.random(4096) < 0.5,
+                     rng.integers(1, 1 << 31, 4096), 0).astype(np.uint32)
+    memo = EWAH.from_words(words, 4096 * 32)
+    bare = EWAH(memo.words, memo.n_bits)
+    rl = memo._rl
+    held = (rl.bounds.nbytes + rl.kinds.nbytes + rl.lit_starts.nbytes
+            + rl.lits.nbytes)
+    assert bare.memo_nbytes == 0 and payload_nbytes(bare) == bare.size_bytes
+    assert memo.memo_nbytes == held > memo.size_bytes
+    assert payload_nbytes(memo) == memo.size_bytes + held
+    c = LRUCache(capacity=4, max_bytes=memo.size_bytes + held - 1,
+                 sizeof=payload_nbytes)
+    c.put("k", memo)  # over the budget once the run-list counts
+    assert c.get("k") is None
 
 
 def test_lru_disabled_and_unbounded():

@@ -15,7 +15,7 @@ The lifecycle this walks through:
     .query().top_k(c, k)              -> heavy hitters, no rows decompressed
     Dataset.from_rows(..., measures={"sales": arr})  -> v4 measure sidecar
     .query().where(e).sum("sales")    -> interval-sliced scalar aggregates
-    .group_by(a, b).sum("sales")      -> two-column measure matrices
+    .group_by(a, b).sum("sales")      -> multi-column measure cubes
     .top_k(c, k, measure="sales")     -> shard-pruned sum-ranked top-k
     .serve().sql("SELECT sum(sales) FROM t WHERE ... GROUP BY day")
     .serve()                          -> pooled caching HTTP service

@@ -806,9 +806,9 @@ class Query:
                              backend=self._backend, pool=self._pool)
 
     def group_by(self, col, *more) -> "GroupedQuery":
-        """GROUP BY one or two columns; two-column grouping aggregates
-        into a ``(card_a, card_b)`` matrix, still entirely in the
-        compressed domain (pairwise interval intersection)."""
+        """GROUP BY one or more columns; grouping by n columns aggregates
+        into the dense row-major cube ``[card(c0), ..., card(cn-1)]``,
+        still entirely in the compressed domain (one interval sweep)."""
         return GroupedQuery(self, col, *more)
 
     # -- measure aggregates --------------------------------------------------
@@ -906,23 +906,22 @@ class Query:
 
 
 class GroupedQuery:
-    """``query().group_by(a[, b])`` — aggregate terminals over one or two
-    grouping columns.
+    """``query().group_by(c0, c1, ...)`` — aggregate terminals over one or
+    more grouping columns.
 
     One column keeps the historical shapes (``count()`` is the
-    ``np.bincount``-shaped vector); two columns return ``(card_a,
-    card_b)`` matrices.  All terminals stay in the compressed domain: the
-    shared filter evaluates once, each grouping column's value bitmaps
-    intersect it by run-interval arithmetic, and measure statistics come
-    from slicing the measure sidecar over the filtered coordinates.
+    ``np.bincount``-shaped vector); n columns return the dense row-major
+    cube ``[card(c0), ..., card(cn-1)]``.  All terminals stay in the
+    compressed domain: the shared filter evaluates once, each grouping
+    column's value bitmaps intersect it by run-interval arithmetic, and
+    measure statistics come from slicing the measure sidecar over the
+    filtered coordinates.  Empty cells count 0 and sum 0; their avg, min
+    and max are NaN.
     """
 
     __slots__ = ("_query", "_cols")
 
     def __init__(self, query: Query, col, *more):
-        if len(more) > 1:
-            raise ValueError(
-                f"group_by supports at most two columns, got {1 + len(more)}")
         self._query = query
         self._cols = (col,) + more
 
@@ -936,8 +935,8 @@ class GroupedQuery:
     def count(self) -> np.ndarray:
         """Per-group row counts under the query's filter: an int64 vector
         of length ``card(col)`` (one column, bit-identical to
-        ``np.bincount`` over the matching rows) or a ``(card_a, card_b)``
-        matrix (two columns) — computed from the bitmaps alone, with
+        ``np.bincount`` over the matching rows) or the ``[card(c0), ...]``
+        cube (several columns) — computed from the bitmaps alone, with
         per-shard partial vectors summed at the coordinator."""
         q = self._query
         if len(self._cols) == 1:

@@ -338,19 +338,20 @@ class Executor:
             return _ms.reduce_intervals(values, fs, fe)
 
     def run_group_agg(self, node: PGroupAgg) -> Dict:
-        """Grouped aggregates over one or two columns in the filtered
+        """Grouped aggregates over one or more columns in the filtered
         domain.
 
         The filter's intervals define a dense coordinate space of
         ``count(filter)`` positions; the measure is gathered into it once
-        and prefix-summed, so every group's sum is two subtractions and its
-        min/max one segmented ``reduceat``.  Each grouping column's rank
-        bitmaps *partition* the rows (every row holds exactly one value),
-        so their interval images partition the filtered domain: one column
-        accumulates per-rank segments directly; two columns sweep the
-        *elementary segments* induced by both columns' boundaries, binning
-        each into its ``(rank_a, rank_b)`` cell — cost O(selected rows +
-        intervals), never O(card_a * card_b * rows).
+        and prefix-summed, so every segment's sum is one subtraction and
+        its min/max one segmented ``reduceat``.  Each grouping column's
+        rank bitmaps *partition* the rows (every row holds exactly one
+        value), so their interval images partition the filtered domain.
+        One sweep serves any number of columns: the *elementary segments*
+        start wherever any column changes rank, each is homogeneous in
+        every column, and each is binned into its row-major cell of the
+        dense ``[card(c0), ..., card(cn-1)]`` cube — cost O(selected rows
+        + intervals), never O(prod(cards) * rows).
         """
         f = self._filter(node.filter)
         with _trace.span("executor.aggregate"):
@@ -358,6 +359,7 @@ class Executor:
 
     def _group_agg(self, node: PGroupAgg, f) -> Dict:
         """``run_group_agg`` once its filter is evaluated to ``f``."""
+        _trace.add("executor.group_aggs", 1)
         cards = tuple(len(g) for g in node.groups)
         name = node.measure
         values = self.index.measure(name) if name is not None else None
@@ -366,56 +368,61 @@ class Executor:
         fs, fe = self._filter_intervals(f)
         if not len(fs):
             return out
-        F = int((fe - fs).sum())
         fvals = _ms.gather(values, fs, fe) if values is not None else None
-        pref = _ms.prefix_sums(fvals) if fvals is not None else None
-        # per-column segment catalogs in filtered coordinates, sorted by
-        # start (segments of one column are disjoint and cover [0, F))
+        with _trace.span("executor.group_sweep"):
+            self._group_sweep(node.groups, cards, fs, fe, fvals, out)
+        return out
+
+    def _group_sweep(self, groups, cards, fs, fe, fvals, out: Dict) -> None:
+        """Bin the elementary segments of the grouping columns' partitions
+        of the filtered domain ``[fs, fe)`` into ``out``'s cells."""
+        F = int((fe - fs).sum())
+        # per-column catalogs in filtered coordinates: the segment starts,
+        # ascending, and each segment's rank (a column's segments are
+        # disjoint and cover [0, F), so a segment ends where the next starts)
         catalogs = []
-        for groups in node.groups:
-            ss, es, rs = [], [], []
-            for g, gn in enumerate(groups):
+        mapped = 0
+        for col_groups in groups:
+            ss, rs = [], []
+            for g, gn in enumerate(col_groups):
                 s, e = self._run(gn).set_intervals()
                 if not len(s):
                     continue
+                mapped += len(s)
                 cs = _ms.interval_coverage(fs, fe, s)
                 ce = _ms.interval_coverage(fs, fe, e)
                 keep = ce > cs
                 if not keep.any():
                     continue
                 ss.append(cs[keep])
-                es.append(ce[keep])
                 rs.append(np.full(int(keep.sum()), g, dtype=np.int64))
             if not ss:
-                return out  # a partition with no coverage means F == 0
+                break
             S = np.concatenate(ss)
-            E = np.concatenate(es)
             R = np.concatenate(rs)
             order = np.argsort(S, kind="stable")
-            catalogs.append((S[order], E[order], R[order]))
-        if len(catalogs) == 1:
-            S, E, R = catalogs[0]
-            cell = R
-            size = cards[0]
-        else:
-            # elementary segments: boundaries wherever either column
-            # changes rank; each segment is homogeneous in both columns
-            (sa, _, ra), (sb, _, rb) = catalogs
-            S = np.unique(np.concatenate([sa, sb]))
-            E = np.concatenate([S[1:], [F]]).astype(np.int64)
-            ia = np.searchsorted(sa, S, side="right") - 1
-            ib = np.searchsorted(sb, S, side="right") - 1
-            cell = ra[ia] * cards[1] + rb[ib]
-            size = cards[0] * cards[1]
+            catalogs.append((S[order], R[order]))
+        _trace.add("executor.group_intervals", mapped)
+        if len(catalogs) < len(groups):
+            return  # a partition with no coverage means F == 0
+        # elementary segments: a boundary wherever any column changes rank
+        S = np.unique(np.concatenate([starts for starts, _ in catalogs]))
+        E = np.append(S[1:], F)
+        _trace.add("executor.group_segments", len(S))
+        cell = np.zeros(len(S), dtype=np.int64)
+        for (starts, ranks), card in zip(catalogs, cards):
+            cell = cell * card + ranks[np.searchsorted(starts, S,
+                                                       side="right") - 1]
+        size = int(np.prod(cards))
         out["counts"] += np.bincount(cell, weights=(E - S),
                                      minlength=size).astype(np.int64)
-        if values is not None:
+        if fvals is not None:
+            pref = _ms.prefix_sums(fvals)
             # np.add.at (not bincount) keeps int64 sums exact past 2^53
             np.add.at(out["sums"], cell, pref[E] - pref[S])
             mins, maxs = _ms.segmented_min_max(fvals, S, E)
             np.minimum.at(out["mins"], cell, mins)
             np.maximum.at(out["maxs"], cell, maxs)
-        return out
 
     def _run_diff(self, node: PDiff) -> EWAH:
         """AND(pos) \\ OR(neg) via EWAH's native andnot — negated operands
@@ -596,7 +603,7 @@ def execute_group_agg(index, measure: Optional[str], cols,
                       e: Optional[Expr] = None,
                       backend: Backend = "auto", optimize: bool = True,
                       cache: Optional[Dict] = None, pool=None) -> Dict:
-    """GROUP BY one or two columns, aggregating ``measure`` (or counting
+    """GROUP BY one or more columns, aggregating ``measure`` (or counting
     rows when ``measure`` is ``None``) under filter ``e``.  Returns the
     partial-aggregate dict of ``Executor.run_group_agg``; project it onto
     one op with ``repro.core.measures.finalize_group``.  Sharded indexes

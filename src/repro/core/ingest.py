@@ -629,7 +629,7 @@ class LiveIndex:
 
     def group_agg(self, measure, cols, e: Optional[Expr] = None,
                   backend: str = "auto", optimize: bool = True, pool=None):
-        """Grouped aggregates over one or two columns across the base+delta
+        """Grouped aggregates over one or more columns across the base+delta
         merge (``measure=None`` computes counts only) — same per-layer
         partial shape as ``Executor.run_group_agg``, merged elementwise,
         tombstones pinned exactly as in ``group_count``."""

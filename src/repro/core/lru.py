@@ -30,7 +30,7 @@ def payload_nbytes(v) -> int:
 
     Aggregate results are *composite*: a scalar aggregate is a ``(sum,
     count, min, max)`` tuple, a grouped aggregate a dict of count/sum/
-    min/max arrays (possibly card_a x card_b cells), and shard-pruned
+    min/max arrays (one entry per cube cell), and shard-pruned
     top-k reports nest arrays inside dicts.  Without the recursive tuple/
     dict branches below, every such entry would size as 0 and a result
     cache full of group-by matrices would evade its byte budget entirely."""

@@ -192,15 +192,14 @@ class PAgg(PlanNode):
 
 @dataclass
 class PGroupAgg(PlanNode):
-    """Grouped aggregates over one or two grouping columns.
+    """Grouped aggregates over one or more grouping columns.
 
     ``groups[j][v]`` is the lowered value node of rank ``v`` of grouping
-    column ``cols[j]``.  With one column the executor maps each rank's
-    intervals into the filter's dense coordinate space and reads sums off a
-    prefix array; with two it intersects the *pairwise* segment catalogs of
-    both columns (an elementary-segment sweep over their combined interval
-    boundaries) so the (card_a x card_b) matrix costs one pass, not
-    card_a*card_b bitmap ANDs.  ``measure=None`` computes counts only."""
+    column ``cols[j]``.  The executor maps every rank's intervals into the
+    filter's dense coordinate space and sweeps the elementary segments of
+    all the columns' boundaries at once, so the dense row-major cube
+    ``[card(c0), ..., card(cn-1)]`` costs one pass, not one bitmap AND per
+    cell.  ``measure=None`` computes counts only."""
     measure: Optional[str]
     cols: Tuple[int, ...]
     groups: Tuple[List[PlanNode], ...]
@@ -373,17 +372,16 @@ class Planner:
     @_spanned
     def plan_group_agg(self, measure: Optional[str], cols,
                        e: Optional[Expr] = None) -> PGroupAgg:
-        """Lower a grouped aggregate over one or two grouping columns.
+        """Lower a grouped aggregate over one or more grouping columns.
 
         ``measure=None`` lowers a multi-column COUNT(*) group-by (the
-        two-column analogue of ``plan_group_count``)."""
+        multi-column analogue of ``plan_group_count``)."""
         if measure is not None:
             self._measure_check(measure)
         cols = [cols] if isinstance(cols, (int, np.integer, str)) else \
             list(cols)
-        if not (1 <= len(cols) <= 2):
-            raise ValueError(
-                f"group_agg takes 1 or 2 grouping columns, got {len(cols)}")
+        if not cols:
+            raise ValueError("group_agg needs a grouping column")
         resolved = []
         groups = []
         for col in cols:

@@ -542,10 +542,11 @@ class ShardedIndex:
     def group_agg(self, measure, cols, e=None, backend: str = "auto",
                   optimize: bool = True,
                   caches: Optional[List[Dict]] = None, pool=None) -> Dict:
-        """GROUP BY one or two columns aggregating ``measure`` (or
-        counting rows when ``None``); per-shard partial dicts merge
-        elementwise (sums/counts add, mins/maxs combine against their
-        identities)."""
+        """GROUP BY one or more columns aggregating ``measure`` (or
+        counting rows when ``None``) into flat partials of the dense
+        row-major cube ``[card(c0), ..., card(cn-1)]`` (``shape``);
+        per-shard partial dicts merge elementwise (sums/counts add,
+        mins/maxs combine against their identities)."""
         from .executor import Executor
         from .planner import Planner
         from .measures import merge_group_aggs

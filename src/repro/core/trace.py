@@ -23,11 +23,15 @@ the hand-off to the start of the run.  Workers forked by a
 turned off in them.
 
 Counters (``add``) are always on: integer sums under one lock, read with
-``counters()``.  The served path keeps three: ``kops.h2d_bytes`` (operand
-words and tile flags copied to the device), ``kops.d2h_bytes`` (kernel
-results fetched back) and ``kops.dirty_tile_bytes`` (4 bytes x
-``block_cols`` per DIRTY operand tile flag handed to ``logical_reduce``,
-the operand bytes any implementation must read).  ``summary()`` adds, per
+``counters()``.  The served path keeps ``kops.h2d_bytes`` (operand words
+and tile flags copied to the device), ``kops.d2h_bytes`` (kernel results
+fetched back), ``kops.dirty_tile_bytes`` (4 bytes x ``block_cols`` per
+DIRTY operand tile flag handed to ``logical_reduce``, the operand bytes any
+implementation must read), and for grouped aggregates
+``executor.group_aggs`` (one per shard-level aggregate run),
+``executor.group_intervals`` (group-bitmap run intervals mapped into the
+filter's coordinates) and ``executor.group_segments`` (elementary segments
+binned into cube cells).  ``summary()`` adds, per
 span name, the count, total seconds, self seconds (a span's duration less
 the part of its interval that its child spans cover) and CPU seconds.
 """

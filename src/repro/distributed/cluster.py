@@ -697,7 +697,8 @@ class ClusterService:
     def group_agg(self, op: str, measure: Optional[str], by,
                   where=None) -> Dict:
         """Grouped sum/avg/min/max (or multi-column count when ``measure``
-        is None) over 1-2 columns."""
+        is None) over one or more columns: the dense row-major cube
+        ``[card(c0), ..., card(cn-1)]`` as nested lists."""
         from repro.core import measures as measures_mod
         if measure is not None:
             self._check_measure(measure)

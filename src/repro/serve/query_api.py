@@ -80,7 +80,7 @@ Measure statements (OLAP over the columnar measure sidecar, evaluated in
 the compressed domain by slicing mmap'd measure arrays with the filter's
 ``set_intervals()`` — no row reconstruction):
     {"select": {"sum": "sales"}, "where": <expr>}            # also avg/min/max
-    {"select": {"sum": "sales", "by": ["day", "region"]}}    # 1-2 group cols
+    {"select": {"sum": "sales", "by": ["day", "region"]}}    # 1+ group cols
     {"select": {"count": true, "by": ["day", "region"]}}     # multi-col counts
     {"select": {"top_k": {"col": "region", "k": 5,
                           "measure": "sales"}}}              # rank by SUM
@@ -173,11 +173,11 @@ def parse_statement(obj: Dict) -> Dict:
     if by is not None:
         if isinstance(by, (str, int)) and not isinstance(by, bool):
             by = [by]
-        if (not isinstance(by, list) or not (1 <= len(by) <= 2)
+        if (not isinstance(by, list) or not by
                 or any(isinstance(c, bool) or not isinstance(c, (str, int))
                        for c in by)):
             raise ValueError(
-                f"'by' must list 1 or 2 grouping columns, got {by!r}")
+                f"'by' must list one or more grouping columns, got {by!r}")
     out = {"kind": None, "op": None, "measure": None, "col": None,
            "by": None, "k": None, "where": e}
     if kind == "count":
@@ -232,7 +232,7 @@ def _apply_limit(st: Dict, limit) -> Dict:
         return {**st, "k": min(st["k"], int(limit))}
     raise ValueError(
         "'limit' ranks a single-column count or sum group-by (top-k); it "
-        "cannot truncate a scalar, a two-column matrix, or an avg/min/max "
+        "cannot truncate a scalar, a multi-column cube, or an avg/min/max "
         "ranking")
 
 
@@ -291,7 +291,7 @@ class _SqlParser:
         FROM <table>                      -- single-table engine: name ignored
         [WHERE <pred>]                    -- =, IN (...), BETWEEN a AND b,
                                           --   AND / OR / NOT, parentheses
-        [GROUP BY a[, b]]
+        [GROUP BY a[, b, ...]]
         [LIMIT k]
 
     Values are integer *ranks* (the dictionary-encoded domain the bitmap
@@ -404,8 +404,6 @@ class _SqlParser:
             while self.peek()[0] == ",":
                 self.next()
                 by.append(self.expect("ident"))
-            if len(by) > 2:
-                raise ValueError("SQL: GROUP BY takes at most two columns")
             sel["by"] = by
         if self.at_kw("LIMIT"):
             self.next()
@@ -972,10 +970,11 @@ class QueryService:
 
     def _group_agg_one(self, op: str, measure: Optional[str], by,
                        e: Optional[Expr]) -> Dict:
-        """Grouped aggregate over 1-2 columns; ``measure=None`` is the
-        multi-column count.  The value matrix is row-major nested lists
-        (shape ``[card(a)]`` or ``[card(a), card(b)]``); empty avg/min/max
-        cells serialize as null."""
+        """Grouped aggregate over one or more columns; ``measure=None`` is
+        the multi-column count.  Counts and values are the dense row-major
+        cube as nested lists, of shape ``[card(c0), ..., card(cn-1)]``;
+        empty cells count 0 and sum 0, and their avg/min/max serialize as
+        null."""
         agg, cached = self._agg_cached(
             f"gagg:{op}:{measure}", list(by), e,
             lambda idx, pool, cs: execute_group_agg(
@@ -1043,7 +1042,7 @@ class QueryService:
 
     def group_agg(self, op: str, measure: Optional[str], by,
                   where=None) -> Dict:
-        """Grouped sum/avg/min/max/count over 1-2 columns."""
+        """Grouped sum/avg/min/max/count over one or more columns."""
         e = parse_expr(where) if isinstance(where, dict) else where
         return self._call(self._group_agg_one, op, measure, list(by), e)
 

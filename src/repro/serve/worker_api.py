@@ -276,10 +276,10 @@ class ShardWorker:
                 raise ValueError(f"gagg 'measure' must be a name or null, "
                                  f"got {name!r}")
             cols = obj.get("cols")
-            if (not isinstance(cols, list) or not (1 <= len(cols) <= 2)
+            if (not isinstance(cols, list) or not cols
                     or not all(isinstance(c, int) for c in cols)):
-                raise ValueError(f"gagg needs 'cols' as a list of 1-2 "
-                                 f"integer columns, got {cols!r}")
+                raise ValueError(f"gagg needs 'cols' as a list of integer "
+                                 f"columns, got {cols!r}")
             cols = tuple(cols)
             shapes = {}
             dtype = None

@@ -228,3 +228,61 @@ def test_h2d_bytes_are_the_bytes_put_on_the_device():
     out, d = delta(lambda: kops.word_logical(a, b, "and"))
     np.testing.assert_array_equal(out, a & b)
     assert d["kops.h2d_bytes"] == a.nbytes + b.nbytes
+
+
+def _runs(x):
+    """Run id of each row: maximal runs of one value."""
+    return np.concatenate(([0], np.cumsum(x[1:] != x[:-1])))
+
+
+def test_group_sweep_span_and_counters(table):
+    idx = ShardedIndex.build(table, shard_rows=2048, k=1,
+                             column_names=["a", "b", "c"])
+    svc = QueryService(idx, backend="ewah", pool_workers=4, cache_entries=0)
+    for cache in idx._result_caches:
+        cache.capacity = 0
+    cube = {"select": {"count": True, "by": ["a", "b", "c"]},
+            "where": {"op": "range", "col": "c", "lo": 0, "hi": 1}}
+    empty = {"select": {"count": True, "by": ["a", "b", "c"]},
+             "where": {"op": "and", "args": [
+                 {"op": "eq", "col": "a", "value": 0},
+                 {"op": "eq", "col": "a", "value": 1}]}}
+    names = ("executor.group_aggs", "executor.group_intervals",
+             "executor.group_segments")
+    # what the sweep must count, from the stored rows: every run of every
+    # value of every grouping column is mapped, and an elementary segment
+    # starts at each selected row whose predecessor among the selected rows
+    # lies in another run of some column
+    intervals = segments = 0
+    for sh in idx.shards:
+        rows = sh.reconstruct_rows()
+        runs = np.stack([_runs(rows[:, j]) for j in range(3)], axis=1)
+        intervals += sum(len(np.unique(runs[:, j])) for j in range(3))
+        sel = runs[rows[:, 2] <= 1]
+        if len(sel):
+            segments += 1 + int((sel[1:] != sel[:-1]).any(axis=1).sum())
+    try:
+        c0 = trace.counters()
+        trace.enable()
+        out = svc.statement(cube)
+        trace.disable()
+        c1 = trace.counters()
+        svc.statement(empty)
+        c2 = trace.counters()
+    finally:
+        svc.close()
+    assert np.asarray(out["counts"]).sum() == int((table[:, 2] <= 1).sum())
+    assert [c1.get(k, 0) - c0.get(k, 0) for k in names] == [
+        4, intervals, segments]
+    # an all-false filter runs the aggregate and sweeps nothing
+    assert [c2.get(k, 0) - c1.get(k, 0) for k in names] == [4, 0, 0]
+    spans = trace.spans()
+    ids = _by_id(spans)
+    sweeps = [s for s in spans if s.name == "executor.group_sweep"]
+    assert len(sweeps) == 4
+    for s in sweeps:
+        assert ids[s.parent].name == "executor.aggregate"
+        assert ids[ids[s.parent].parent].name == "shard.run"
+    loads = [s for s in spans if s.name == "executor.load"
+             and ids[s.parent].name == "executor.group_sweep"]
+    assert loads  # the group bitmaps are read inside the sweep

@@ -230,6 +230,16 @@ class Executor:
     # batched coverage pass — per query, cold or warm
     LIT_INTERVAL_CUTOFF = 4
 
+    # a grouping column is ranked at the filter's rows (``_rank_catalog``)
+    # when ``card x (n_words + F)`` is below this share of its bitmaps'
+    # compressed words times ``log2(2 + the filter's intervals)``, the
+    # interval path's cost (``_interval_catalog``).  Measured on one CPU
+    # over 1.5M-row SSB shards (PERF.md): unsorted Q3.1 and Q4.2 columns
+    # read 0.10-0.28 and rank 3-8x faster; a lex-sorted table's most
+    # fragmented column under Q4.2 reads 0.88 and keeps intervals, 6%
+    # faster there, as every unfiltered column does (22 and up)
+    RANK_CUTOFF = 0.5
+
     def run_group_count(self, node: PGroupCount) -> np.ndarray:
         """Per-value counts of one column under the node's filter.
 
@@ -381,48 +391,40 @@ class Executor:
         # ascending, and each segment's rank (a column's segments are
         # disjoint and cover [0, F), so a segment ends where the next starts)
         catalogs = []
-        mapped = 0
+        mapped = ranked = 0
+        pos = None
         for col_groups in groups:
-            ss, rs = [], []
-            for g, gn in enumerate(col_groups):
-                s, e = self._run(gn).set_intervals()
-                if not len(s):
-                    continue
-                mapped += len(s)
-                cs = _ms.interval_coverage(fs, fe, s)
-                ce = _ms.interval_coverage(fs, fe, e)
-                keep = ce > cs
-                if not keep.any():
-                    continue
-                ss.append(cs[keep])
-                rs.append(np.full(int(keep.sum()), g, dtype=np.int64))
-            if not ss:
+            bms = [self._run(gn) for gn in col_groups]
+            if self._rank_at_rows(bms, F, len(fs)):
+                if pos is None:
+                    pos = _ms.interval_positions(fs, fe)
+                starts, ranks = _rank_catalog(bms, pos)
+                mapped += len(starts)
+                ranked += F
+            else:
+                starts, ranks, n = _interval_catalog(bms, fs, fe)
+                mapped += n
+            if not len(starts):
                 break
-            S = np.concatenate(ss)
-            R = np.concatenate(rs)
-            order = np.argsort(S, kind="stable")
-            catalogs.append((S[order], R[order]))
+            catalogs.append((starts, ranks))
         _trace.add("executor.group_intervals", mapped)
+        _trace.add("executor.group_rows_ranked", ranked)
         if len(catalogs) < len(groups):
             return  # a partition with no coverage means F == 0
-        # elementary segments: a boundary wherever any column changes rank
-        S = np.unique(np.concatenate([starts for starts, _ in catalogs]))
-        E = np.append(S[1:], F)
-        _trace.add("executor.group_segments", len(S))
-        cell = np.zeros(len(S), dtype=np.int64)
-        for (starts, ranks), card in zip(catalogs, cards):
-            cell = cell * card + ranks[np.searchsorted(starts, S,
-                                                       side="right") - 1]
-        size = int(np.prod(cards))
-        out["counts"] += np.bincount(cell, weights=(E - S),
-                                     minlength=size).astype(np.int64)
-        if fvals is not None:
-            pref = _ms.prefix_sums(fvals)
-            # np.add.at (not bincount) keeps int64 sums exact past 2^53
-            np.add.at(out["sums"], cell, pref[E] - pref[S])
-            mins, maxs = _ms.segmented_min_max(fvals, S, E)
-            np.minimum.at(out["mins"], cell, mins)
-            np.maximum.at(out["maxs"], cell, maxs)
+        _bin_segments(catalogs, cards, F, fvals, out)
+
+    def _rank_at_rows(self, bms: Sequence[EWAH], F: int, n_fs: int) -> bool:
+        """Whether to rank a column at the filter's ``F`` rows
+        (``_rank_catalog``) rather than map its bitmaps' intervals into the
+        filter's ``n_fs`` intervals (``_interval_catalog``).  Ranking reads
+        every bitmap's words and one word per filtered row, ``card x
+        (n_words + F)``, whatever the compression; the intervals grow with
+        the bitmaps' compressed words, and each is searched among the
+        filter's intervals."""
+        n_words = bms[0].n_words_uncompressed
+        size = sum(bm.size_words for bm in bms)
+        return (len(bms) * (n_words + F)
+                < self.RANK_CUTOFF * size * np.log2(2 + n_fs))
 
     def _run_diff(self, node: PDiff) -> EWAH:
         """AND(pos) \\ OR(neg) via EWAH's native andnot — negated operands
@@ -541,6 +543,74 @@ def _interval_coverage(fs: np.ndarray, fe: np.ndarray,
     i0 = np.maximum(i, 0)
     inside = np.clip(xs - fs[i0], 0, fe[i0] - fs[i0])
     return np.where(i >= 0, pref[i0] + inside, 0)
+
+
+def _interval_catalog(bms: Sequence[EWAH], fs: np.ndarray,
+                      fe: np.ndarray):
+    """A grouping column's catalog from its bitmaps' set-bit intervals:
+    ``(starts, ranks, mapped)``, every interval mapped into the filter's
+    coordinates ``[fs, fe)`` and those that meet it kept.  Cheap where a
+    bitmap is a few long runs (a sorted table)."""
+    ss, rs = [], []
+    mapped = 0
+    for g, bm in enumerate(bms):
+        s, e = bm.set_intervals()
+        if not len(s):
+            continue
+        mapped += len(s)
+        cs = _ms.interval_coverage(fs, fe, s)
+        ce = _ms.interval_coverage(fs, fe, e)
+        keep = ce > cs
+        if keep.any():
+            ss.append(cs[keep])
+            rs.append(np.full(int(keep.sum()), g, dtype=np.int64))
+    if not ss:
+        return np.empty(0, np.int64), np.empty(0, np.int64), mapped
+    S = np.concatenate(ss)
+    R = np.concatenate(rs)
+    order = np.argsort(S, kind="stable")
+    return S[order], R[order], mapped
+
+
+def _rank_catalog(bms: Sequence[EWAH], pos: np.ndarray):
+    """A grouping column's catalog from its bitmaps' bits at the filter's
+    row positions ``pos``: ``(starts, ranks)``, a start wherever the rank
+    changes along the filtered rows.  The bitmaps partition the rows, so a
+    row's rank is the sum of ``g`` over the bitmaps ``g`` that hold it.
+    Costs ``card x (n_words + len(pos))`` whatever the compression: cheap
+    where literal words would expand into about one interval per set bit
+    (an unsorted table)."""
+    widx = pos >> 5
+    shift = (pos & 31).astype(np.uint32)
+    rank = np.zeros(len(pos), dtype=np.uint32)
+    for g, bm in enumerate(bms):
+        if g:
+            rank += ((bm.to_words()[widx] >> shift) & 1) * np.uint32(g)
+    starts = np.flatnonzero(np.concatenate(([True], rank[1:] != rank[:-1])))
+    return starts, rank[starts].astype(np.int64)
+
+
+def _bin_segments(catalogs, cards, F: int, fvals, out: Dict) -> None:
+    """Bin the elementary segments of the columns' ``catalogs`` (each a
+    partition of ``[0, F)``) into ``out``'s row-major cube cells."""
+    # elementary segments: a boundary wherever any column changes rank
+    S = np.unique(np.concatenate([starts for starts, _ in catalogs]))
+    E = np.append(S[1:], F)
+    _trace.add("executor.group_segments", len(S))
+    cell = np.zeros(len(S), dtype=np.int64)
+    for (starts, ranks), card in zip(catalogs, cards):
+        cell = cell * card + ranks[np.searchsorted(starts, S,
+                                                   side="right") - 1]
+    size = int(np.prod(cards))
+    out["counts"] += np.bincount(cell, weights=(E - S),
+                                 minlength=size).astype(np.int64)
+    if fvals is not None:
+        pref = _ms.prefix_sums(fvals)
+        # np.add.at (not bincount) keeps int64 sums exact past 2^53
+        np.add.at(out["sums"], cell, pref[E] - pref[S])
+        mins, maxs = _ms.segmented_min_max(fvals, S, E)
+        np.minimum.at(out["mins"], cell, mins)
+        np.maximum.at(out["maxs"], cell, maxs)
 
 
 def execute_count(index, e: Optional[Expr] = None,

@@ -29,9 +29,12 @@ fetched back), ``kops.dirty_tile_bytes`` (4 bytes x ``block_cols`` per
 DIRTY operand tile flag handed to ``logical_reduce``, the operand bytes any
 implementation must read), and for grouped aggregates
 ``executor.group_aggs`` (one per shard-level aggregate run),
-``executor.group_intervals`` (group-bitmap run intervals mapped into the
-filter's coordinates) and ``executor.group_segments`` (elementary segments
-binned into cube cells).  ``summary()`` adds, per
+``executor.group_intervals`` (catalog entries in the filter's coordinates:
+a column's group-bitmap run intervals mapped there, or the rank changes
+along the filtered rows where the column is ranked at them),
+``executor.group_rows_ranked`` (filtered rows ranked by position, summed
+over the columns ranked so) and ``executor.group_segments`` (elementary
+segments binned into cube cells).  ``summary()`` adds, per
 span name, the count, total seconds, self seconds (a span's duration less
 the part of its interval that its child spans cover) and CPU seconds.
 """

@@ -7,16 +7,22 @@ the ``ewah`` and ``kernel`` backends (the kernels interpreted on the CPU),
 through each front door: ``QueryService.statement`` (JSON and SQL),
 ``Dataset.query().group_by`` and a ``ClusterService`` over a local worker
 fleet.  The one- and two-column answers are pinned to what the former
-one- and two-column branches of the executor returned.
+one- and two-column branches of the executor returned.  The sweep's two
+ways to build a column's catalog, mapping its bitmaps' intervals and
+ranking it at the filter's rows, are each held to the row scan on
+unsorted and lex-sorted tables, and the counters show which one ran.
 """
 import numpy as np
 import pytest
 
-from repro.core import ShardedIndex, col
+from repro.core import ShardedIndex, col, trace
 from repro.core.dataset import Dataset
-from repro.core.executor import execute_group_agg
-from repro.core.measures import (empty_group_agg, finalize_group,
-                                 merge_group_aggs)
+from repro.core.ewah import EWAH
+from repro.core.executor import (Executor, _bin_segments, _interval_catalog,
+                                 _rank_catalog, execute_group_agg)
+from repro.core.measures import (empty_group_agg, finalize_group, gather,
+                                 interval_positions, merge_group_aggs)
+from repro.core.planner import PGroupAgg, PPinned, Planner
 from repro.serve.query_api import QueryService, expr_to_json, parse_sql
 
 NAMES = ["a", "b", "c", "d", "e"]
@@ -283,3 +289,133 @@ def test_sweep_keeps_one_and_two_column_answers(by):
             finalize_group(op, g),
             np.asarray([np.nan if v is None else v for v in want[op + "s"]],
                        dtype=np.float64))
+
+
+# -- the sweep's two catalog builders ----------------------------------------
+
+BUILD_NAMES = ["x", "y", "z"]
+BUILD_CARDS = [6, 4, 5]
+BUILD_ROWS = 3000
+BUILD_MASKS = {
+    "nothing": lambda rng: np.zeros(BUILD_ROWS, dtype=bool),
+    "one_row": lambda rng: np.arange(BUILD_ROWS) == 1234,
+    "every_row": lambda rng: np.ones(BUILD_ROWS, dtype=bool),
+    "scattered_3pct": lambda rng: rng.random(BUILD_ROWS) < 0.03,
+}
+
+
+@pytest.fixture(scope="module")
+def build_tables():
+    """(rows, measure, index) per table order and k, built once: uniform
+    rows in drawing order, or sorted lexicographically, cut into 3
+    shards; int64 measure values whose sums need every bit."""
+    made = {}
+
+    def get(order, k):
+        if (order, k) not in made:
+            rng = np.random.default_rng(20261019)
+            rows = np.column_stack([rng.integers(0, c, BUILD_ROWS)
+                                    for c in BUILD_CARDS]).astype(np.int64)
+            if order == "lex":
+                rows = rows[np.lexsort(rows.T[::-1])]
+            m = rng.integers(-10**12, 10**12, BUILD_ROWS)
+            idx = ShardedIndex.build(rows, shard_rows=1024, k=k,
+                                     column_names=BUILD_NAMES,
+                                     measures={"m": m})
+            assert idx.n_shards == 3
+            made[order, k] = rows, m, idx
+        return made[order, k]
+    return get
+
+
+def _cube_by(builder, idx, by, mask):
+    """The cube of measure ``m`` by ``by`` over the rows ``mask`` selects,
+    every column's catalog built by ``builder`` ("interval", "rank" or
+    ``None`` for the executor's own choice), merged over the shards."""
+    parts, off = [], 0
+    for sh in idx.shards:
+        sel = mask[off:off + sh.n_rows]
+        off += sh.n_rows
+        node = Planner(sh).plan_group_agg("m", by, None)
+        ex = Executor(sh, backend="ewah")
+        if builder is None:
+            parts.append(ex.run_group_agg(PGroupAgg(
+                "m", node.cols, node.groups, PPinned(EWAH.from_bool(sel)))))
+            continue
+        cards = tuple(len(g) for g in node.groups)
+        out = empty_group_agg(node.cols, cards, "m", "<i8")
+        fs, fe = EWAH.from_bool(sel).set_intervals()
+        F = int((fe - fs).sum())
+        if F:
+            cols = [[ex._run(gn) for gn in g] for g in node.groups]
+            if builder == "rank":
+                pos = interval_positions(fs, fe)
+                cats = [_rank_catalog(bms, pos) for bms in cols]
+            else:
+                cats = [_interval_catalog(bms, fs, fe)[:2] for bms in cols]
+            _bin_segments(cats, cards, F, gather(sh.measure("m"), fs, fe),
+                          out)
+        parts.append(out)
+    return merge_group_aggs(parts)
+
+
+@pytest.mark.parametrize("mask", list(BUILD_MASKS))
+@pytest.mark.parametrize("by", [["y"], ["z", "x"], ["x", "y", "z"]],
+                         ids=["1col", "2cols", "3cols"])
+@pytest.mark.parametrize("k", [1, 2], ids=["k1", "k2"])
+@pytest.mark.parametrize("order", ["unsorted", "lex"])
+def test_catalog_builders_match_row_scan(build_tables, order, k, by, mask):
+    rows, m, idx = build_tables(order, k)
+    sel = BUILD_MASKS[mask](np.random.default_rng(7))
+    shape = [BUILD_CARDS[BUILD_NAMES.index(c)] for c in by]
+    size = int(np.prod(shape))
+    cell = np.ravel_multi_index(
+        tuple(rows[sel, BUILD_NAMES.index(c)] for c in by), shape)
+    counts = np.bincount(cell, minlength=size)
+    sums = np.zeros(size, dtype=np.int64)
+    np.add.at(sums, cell, m[sel])
+    mins, maxs = np.full(size, np.inf), np.full(size, -np.inf)
+    np.minimum.at(mins, cell, m[sel])
+    np.maximum.at(maxs, cell, m[sel])
+    mins[counts == 0] = maxs[counts == 0] = np.nan
+    for builder in ("interval", "rank", None):
+        got = _cube_by(builder, idx, by, sel)
+        assert got["shape"] == tuple(shape), builder
+        np.testing.assert_array_equal(got["counts"], counts, err_msg=builder)
+        assert got["sums"].dtype == np.int64
+        np.testing.assert_array_equal(got["sums"], sums, err_msg=builder)
+        np.testing.assert_array_equal(finalize_group("min", got), mins,
+                                      err_msg=builder)
+        np.testing.assert_array_equal(finalize_group("max", got), maxs,
+                                      err_msg=builder)
+
+
+@pytest.mark.parametrize("order", ["unsorted", "lex"])
+def test_the_builder_chosen_shows_in_the_counters(order):
+    """An unsorted table's literal-heavy columns are ranked at a scattered
+    filter's rows; a lex-sorted table's runs keep the interval path, and
+    so does every unfiltered column (ranking would touch every row).
+    Every aggregate that selects rows adds catalog entries."""
+    rng = np.random.default_rng(11)
+    n, cards = 20000, (8, 6, 5, 6, 6)
+    rows = np.column_stack([rng.integers(0, c, n) for c in cards])
+    if order == "lex":
+        rows = rows[np.lexsort(rows.T[::-1])]
+    idx = ShardedIndex.build(rows.astype(np.int64), shard_rows=10016, k=1,
+                             column_names=list("abcde"),
+                             measures={"m": rng.integers(0, 100, n)})
+    filt = (col("d") == 0) & (col("e") == 1)
+    names = ("executor.group_aggs", "executor.group_intervals",
+             "executor.group_rows_ranked")
+    for sh in idx.shards:
+        for expr in (filt, None):
+            node = Planner(sh).plan_group_agg("m", ["a", "b", "c"], expr)
+            c0 = trace.counters()
+            got = Executor(sh, backend="ewah").run_group_agg(node)
+            c1 = trace.counters()
+            aggs, intervals, ranked = (c1.get(k, 0) - c0.get(k, 0)
+                                       for k in names)
+            F = int(got["counts"].sum())
+            assert F > 0 and aggs == 1 and intervals > 0
+            ranks = order == "unsorted" and expr is not None
+            assert ranked == (3 * F if ranks else 0), (order, expr)

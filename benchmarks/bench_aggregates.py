@@ -31,7 +31,7 @@ import time
 import numpy as np
 
 from repro.core import Dataset, col, execute, synth
-from repro.core.executor import execute_group_count
+from repro.core import statement
 
 try:  # package-style and script-style execution both work
     from .common import emit
@@ -101,7 +101,7 @@ def run(n: int = 200_000, out_path: str = "BENCH_agg.json") -> dict:
         assert np.array_equal(sharded, oracle), cname
 
         t0 = time.perf_counter()
-        execute_group_count(ds.index, c, e)  # includes interval decodes
+        statement.run(ds.index, ("gcount", c, e))  # includes interval decodes
         cold_s = time.perf_counter() - t0
         comp_s = _median_time(
             lambda: ds.query().where(e).group_by(cname).count())

@@ -32,7 +32,7 @@ import time
 import numpy as np
 
 from repro.core import Dataset, col, execute
-from repro.core.executor import execute_agg, execute_group_agg
+from repro.core import statement
 from repro.core.measures import finalize_group
 
 try:  # package-style and script-style execution both work
@@ -110,7 +110,7 @@ def run(n: int = 200_000, out_path: str = "BENCH_olap.json") -> dict:
     assert baseline_sum(idx, vals, e) == oracle
     assert ds_sh.query().where(e).sum("sales") == oracle
 
-    comp_s = _median_time(lambda: execute_agg(idx, "sales", e))
+    comp_s = _median_time(lambda: statement.run(idx, ("agg", "sales", e)))
     base_s = _median_time(lambda: baseline_sum(idx, vals, e))
     sum_speedup = base_s / comp_s
     results["sum"] = {
@@ -140,7 +140,8 @@ def run(n: int = 200_000, out_path: str = "BENCH_olap.json") -> dict:
         np.asarray(ds_sh.query().where(e).group_by("day", "region")
                    .sum("sales")), g_oracle)
 
-    gcomp_s = _median_time(lambda: execute_group_agg(idx, "sales", [ca, cb], e))
+    gcomp_s = _median_time(lambda: statement.run(
+        idx, statement.group_task("sales", [ca, cb], idx.resolve_column, e)))
     gbase_s = _median_time(lambda: baseline_group_sum(idx, vals, ca, cb, e))
     g_speedup = gbase_s / gcomp_s
     results["group_sum_2col"] = {
@@ -153,7 +154,7 @@ def run(n: int = 200_000, out_path: str = "BENCH_olap.json") -> dict:
          f"{g_speedup:.1f}x_vs_decompress")
 
     # -- shard-pruned top-k agreement ----------------------------------------
-    agg = execute_group_agg(idx, "sales", [2], None)
+    agg = statement.run(idx, ("gagg", "sales", (2,), None))
     from repro.core.dataset import top_k_from_values
     expect = top_k_from_values(finalize_group("sum", agg),
                                np.asarray(agg["counts"]), 10)

@@ -26,8 +26,8 @@ from .store import (StoreCorruptError, StoreError, StoreVersionError,
 from .expr import (And, Col, Const, Eq, Expr, In, Not, Or, Range,
                    canonical_key, col, from_wire, to_wire)
 from .planner import explain, plan
-from .executor import (QueryBatch, execute, execute_count,
-                       execute_group_count, execute_rows)
+from .statement import QueryBatch, execute, execute_rows
+from . import statement
 from .shard import ForkSafetyError, ShardedIndex, ShardProcessPool
 from .wal import WAL, WALError, replay as wal_replay
 from .ingest import Compactor, DeltaIndex, LiveIndex
@@ -54,8 +54,7 @@ __all__ = [
     "manifest_meta",
     "Expr", "Col", "col", "Eq", "In", "Range", "And", "Or", "Not", "Const",
     "canonical_key", "from_wire", "to_wire",
-    "plan", "explain", "execute", "execute_rows", "execute_count",
-    "execute_group_count", "QueryBatch",
+    "plan", "explain", "execute", "execute_rows", "QueryBatch", "statement",
     "WAL", "WALError", "wal_replay",
     "LiveIndex", "DeltaIndex", "Compactor",
     "Dataset", "Query",

@@ -48,6 +48,9 @@ from .expr import Expr
 from .index import WORD_ROWS, BitmapIndex, IndexBuilder
 from .layout import LayoutDecision, LayoutStats
 from .shard import ShardedIndex
+from .statement import execute, group_task, run, top_k_of
+from .statement import (  # noqa: F401 - re-exported, their old home
+    top_k_from_counts, top_k_from_values)
 from .sorting import (SortStats, external_merge_sort_perm,
                       external_sorted_chunks, order_columns_freq_aware)
 
@@ -66,33 +69,6 @@ def _aligned_rows(n: int, parts: int) -> int:
 def _table_cards(table: np.ndarray) -> List[int]:
     n, d = table.shape
     return [int(table[:, c].max()) + 1 if n else 1 for c in range(d)]
-
-
-def top_k_from_counts(counts: np.ndarray, k: int) -> List[Tuple[int, int]]:
-    """The ``k`` largest entries of a group-count vector as
-    ``[(value_rank, count), ...]``: descending count, ties by ascending
-    rank, zero-count values never included.  Shared by ``Query.top_k`` and
-    the serving layer's top-k statement."""
-    counts = np.asarray(counts)
-    nz = np.flatnonzero(counts)
-    order = nz[np.lexsort((nz, -counts[nz]))][:max(int(k), 0)]
-    return [(int(v), int(counts[v])) for v in order]
-
-
-def top_k_from_values(values: np.ndarray, counts: np.ndarray,
-                      k: int) -> List[Tuple[int, Union[int, float]]]:
-    """The ``k`` largest entries of a per-group value vector (measure sums)
-    as ``[(value_rank, value), ...]``: descending value, ties by ascending
-    rank — the *same* deterministic tie-break as ``top_k_from_counts``, so
-    mono, sharded and cluster top-k orderings agree.  Groups with zero
-    rows (``counts == 0``) never appear, even when their value is 0."""
-    values = np.asarray(values)
-    counts = np.asarray(counts)
-    nz = np.flatnonzero(counts)
-    order = nz[np.lexsort((nz, -values[nz]))][:max(int(k), 0)]
-    if values.dtype.kind == "f":
-        return [(int(v), float(values[v])) for v in order]
-    return [(int(v), int(values[v])) for v in order]
 
 
 class Dataset:
@@ -801,9 +777,10 @@ class Query:
     def count(self) -> int:
         """COUNT(*): memoized compressed-domain popcount; per-shard partial
         counts are summed — no result bitmap, no row ids."""
-        from .executor import execute_count
-        return execute_count(self._index, self._where,
-                             backend=self._backend, pool=self._pool)
+        return self._run(("count", self._where))
+
+    def _run(self, task):
+        return run(self._index, task, self._backend, pool=self._pool)
 
     def group_by(self, col, *more) -> "GroupedQuery":
         """GROUP BY one or more columns; grouping by n columns aggregates
@@ -817,9 +794,7 @@ class Query:
         computed by slicing the measure sidecar with the filter's run
         intervals — no row ids, no row reconstruction.  ``min``/``max``
         are ``None`` when no row matches."""
-        from .executor import execute_agg
-        return execute_agg(self._index, measure, self._where,
-                           backend=self._backend, pool=self._pool)
+        return self._run(("agg", str(measure), self._where))
 
     def sum(self, measure):
         from .measures import finalize_scalar
@@ -847,16 +822,14 @@ class Query:
         rank; values with no matching rows never appear.  On a sharded
         index this runs the shard-pruned (TPUT-style) two-phase protocol;
         ordering is identical to the monolithic path by construction."""
-        from .executor import execute_group_agg
         idx = self._index
         if isinstance(idx, ShardedIndex):
             return idx.top_k(col, k, self._where, measure=measure,
                              backend=self._backend, pool=self._pool)
-        if measure is None:
-            return top_k_from_counts(self.group_by(col).count(), k)
-        agg = execute_group_agg(idx, measure, [col], self._where,
-                                backend=self._backend, pool=self._pool)
-        return top_k_from_values(agg["sums"], agg["counts"], k)
+        c = idx.resolve_column(col)
+        task = ("gcount", c, self._where) if measure is None \
+            else group_task(measure, [c], idx.resolve_column, self._where)
+        return top_k_of(measure, self._run(task), k)
 
     def rows(self, limit: Optional[int] = None) -> np.ndarray:
         """Matching row ids (sorted); the one terminal that decompresses.
@@ -864,7 +837,6 @@ class Query:
         With ``limit`` the decode itself is truncated: set-bit intervals
         are walked only until ``limit`` ids are covered, so a small preview
         of a huge result is O(limit), never O(result)."""
-        from .executor import execute
         from .expr import Const
         e = self._where if self._where is not None else Const(True)
         bm = execute(self._index, e, backend=self._backend, pool=self._pool)
@@ -883,7 +855,6 @@ class Query:
 
     def bitmap(self):
         """The filter's EWAH result bitmap (compressed)."""
-        from .executor import execute
         from .expr import Const
         e = self._where if self._where is not None else Const(True)
         return execute(self._index, e, backend=self._backend,
@@ -940,9 +911,8 @@ class GroupedQuery:
         per-shard partial vectors summed at the coordinator."""
         q = self._query
         if len(self._cols) == 1:
-            from .executor import execute_group_count
-            return execute_group_count(q._index, self._cols[0], q._where,
-                                       backend=q._backend, pool=q._pool)
+            return q._run(("gcount", q._index.resolve_column(self._cols[0]),
+                           q._where))
         agg = self.agg(None)
         return agg["counts"].reshape(self._shape(agg))
 
@@ -950,10 +920,9 @@ class GroupedQuery:
         """The raw mergeable partial: ``{"cols", "shape", "counts", and —
         with a measure — "sums", "mins", "maxs"}`` (flat arrays; reshape
         by ``shape``).  The building block behind the named terminals."""
-        from .executor import execute_group_agg
         q = self._query
-        return execute_group_agg(q._index, measure, list(self._cols),
-                                 q._where, backend=q._backend, pool=q._pool)
+        return q._run(group_task(measure, self._cols,
+                                 q._index.resolve_column, q._where))
 
     def _finalized(self, op: str, measure) -> np.ndarray:
         from .measures import finalize_group

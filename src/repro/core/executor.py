@@ -18,16 +18,17 @@ bucket)`` entries), so one compiled Pallas program serves every operand
 shape in a bucket and the clean sideband is computed once per bitmap, not
 once per query (see ``repro.kernels.ops``).
 
-``QueryBatch`` evaluates many expressions in one pass over a shared operand
-cache: physical bitmaps (and their bucketed dense decompressions + flags,
-when the kernel path is taken) are loaded once and reused across all plans
-in the batch.  Constant plan nodes memoize their full-length bitmaps in the
-same cache.  Sharded execution forwards an optional worker pool for
-shard-parallel fan-out (``repro.core.shard``).
+One ``Executor`` evaluates plans over one ``BitmapIndex`` (a shard) against
+an operand cache: physical bitmaps (and their bucketed dense decompressions
++ flags, when the kernel path is taken) are loaded once and reused across
+every plan run against the same cache (``repro.core.statement.QueryBatch``).
+Constant plan nodes memoize their full-length bitmaps in the same cache.
+Statements, their fan-out over shards and the merge of per-shard partials
+live in ``repro.core.statement``.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Union
+from typing import Dict, Optional, Sequence
 
 import numpy as np
 
@@ -35,11 +36,9 @@ from . import cost_model as _cm
 from . import measures as _ms
 from . import trace as _trace
 from .ewah import EWAH, and_many, or_many
-from .expr import Expr
 from .index import BitmapIndex
 from .planner import (PAgg, PAnd, PBitmap, PConst, PCount, PDiff,
-                      PGroupAgg, PGroupCount, PNot, POr, PPinned, PlanNode,
-                      Planner, plan)
+                      PGroupAgg, PGroupCount, PNot, POr, PPinned, PlanNode)
 
 # the historical static threshold, kept as the uncalibrated fallback; the
 # live value comes from ``repro.core.cost_model`` (measured crossover when a
@@ -495,44 +494,6 @@ class Executor:
                                    like.n_bits)
 
 
-def _shard_caches(index, cache: Optional[Dict]) -> Optional[List[Dict]]:
-    """Per-shard operand sub-dicts inside one caller-supplied cache, so a
-    persistent cache keeps sharing operands across calls on every
-    statement path (one keying scheme, used by all dispatchers)."""
-    if cache is None:
-        return None
-    return [cache.setdefault(("shard", i), {})
-            for i in range(index.n_shards)]
-
-
-def execute(index, e: Union[Expr, PlanNode],
-            backend: Backend = "auto", optimize: bool = True,
-            cache: Optional[Dict] = None, pool=None) -> EWAH:
-    """Plan (unless given a plan) and evaluate one expression -> EWAH.
-
-    Accepts a monolithic ``BitmapIndex`` or a ``ShardedIndex``; the sharded
-    path plans and executes per shard — concurrently when ``pool`` (a
-    ``concurrent.futures`` executor) is given — then concatenates the EWAH
-    results.
-    """
-    from .shard import ShardedIndex  # local: shard imports this module
-    from .ingest import LiveIndex   # local: ingest imports this module
-    if isinstance(index, LiveIndex):
-        return index.execute(e, backend=backend, optimize=optimize,
-                             pool=pool)
-    if isinstance(index, ShardedIndex):
-        return index.execute(e, backend=backend, optimize=optimize,
-                             caches=_shard_caches(index, cache), pool=pool)
-    node = plan(index, e, optimize=optimize) if isinstance(e, Expr) else e
-    return Executor(index, backend=backend, cache=cache).run(node)
-
-
-def execute_rows(index, e: Union[Expr, PlanNode],
-                 backend: Backend = "auto", optimize: bool = True) -> np.ndarray:
-    """Evaluate and return matching row ids (sorted)."""
-    return execute(index, e, backend=backend, optimize=optimize).set_bits()
-
-
 def _interval_coverage(fs: np.ndarray, fe: np.ndarray,
                        xs: np.ndarray) -> np.ndarray:
     """Covered length below each ``x`` of the sorted disjoint intervals
@@ -611,117 +572,3 @@ def _bin_segments(catalogs, cards, F: int, fvals, out: Dict) -> None:
         mins, maxs = _ms.segmented_min_max(fvals, S, E)
         np.minimum.at(out["mins"], cell, mins)
         np.maximum.at(out["maxs"], cell, maxs)
-
-
-def execute_count(index, e: Optional[Expr] = None,
-                  backend: Backend = "auto", optimize: bool = True,
-                  cache: Optional[Dict] = None, pool=None) -> int:
-    """COUNT(*) of a filter (``e=None`` counts all rows), computed in the
-    compressed domain — on a ``ShardedIndex`` per-shard partial counts are
-    summed at the coordinator, never a concatenated result bitmap."""
-    from .shard import ShardedIndex
-    from .ingest import LiveIndex
-    if isinstance(index, LiveIndex):
-        return index.count(e, backend=backend, optimize=optimize, pool=pool)
-    if isinstance(index, ShardedIndex):
-        return index.count(e, backend=backend, optimize=optimize,
-                           caches=_shard_caches(index, cache), pool=pool)
-    node = Planner(index, optimize=optimize).plan_count(e)
-    return Executor(index, backend=backend, cache=cache).run_count(node)
-
-
-def execute_group_count(index, col, e: Optional[Expr] = None,
-                        backend: Backend = "auto", optimize: bool = True,
-                        cache: Optional[Dict] = None, pool=None) -> np.ndarray:
-    """GROUP BY ``col`` COUNT(*) under filter ``e`` -> int64 array of
-    length ``card(col)`` (a ``np.bincount``-shaped result).  Sharded
-    indexes merge per-shard partial count vectors by summation."""
-    from .shard import ShardedIndex
-    from .ingest import LiveIndex
-    if isinstance(index, LiveIndex):
-        return index.group_count(col, e, backend=backend, optimize=optimize,
-                                 pool=pool)
-    if isinstance(index, ShardedIndex):
-        return index.group_count(col, e, backend=backend, optimize=optimize,
-                                 caches=_shard_caches(index, cache),
-                                 pool=pool)
-    node = Planner(index, optimize=optimize).plan_group_count(col, e)
-    return Executor(index, backend=backend,
-                    cache=cache).run_group_count(node)
-
-
-def execute_agg(index, measure: str, e: Optional[Expr] = None,
-                backend: Backend = "auto", optimize: bool = True,
-                cache: Optional[Dict] = None, pool=None):
-    """Scalar ``(sum, count, min, max)`` of ``measure`` under filter ``e``
-    (``e=None`` aggregates all rows), computed by interval-slicing the
-    measure sidecar — sharded indexes merge per-shard partial tuples at
-    the coordinator (``repro.core.measures.merge_scalar_aggs``)."""
-    from .shard import ShardedIndex
-    from .ingest import LiveIndex
-    if isinstance(index, LiveIndex):
-        return index.agg(measure, e, backend=backend, optimize=optimize,
-                         pool=pool)
-    if isinstance(index, ShardedIndex):
-        return index.agg(measure, e, backend=backend, optimize=optimize,
-                         caches=_shard_caches(index, cache), pool=pool)
-    node = Planner(index, optimize=optimize).plan_agg(measure, e)
-    return Executor(index, backend=backend, cache=cache).run_agg(node)
-
-
-def execute_group_agg(index, measure: Optional[str], cols,
-                      e: Optional[Expr] = None,
-                      backend: Backend = "auto", optimize: bool = True,
-                      cache: Optional[Dict] = None, pool=None) -> Dict:
-    """GROUP BY one or more columns, aggregating ``measure`` (or counting
-    rows when ``measure`` is ``None``) under filter ``e``.  Returns the
-    partial-aggregate dict of ``Executor.run_group_agg``; project it onto
-    one op with ``repro.core.measures.finalize_group``.  Sharded indexes
-    merge per-shard partials elementwise."""
-    from .shard import ShardedIndex
-    from .ingest import LiveIndex
-    if isinstance(index, LiveIndex):
-        return index.group_agg(measure, cols, e, backend=backend,
-                               optimize=optimize, pool=pool)
-    if isinstance(index, ShardedIndex):
-        return index.group_agg(measure, cols, e, backend=backend,
-                               optimize=optimize,
-                               caches=_shard_caches(index, cache),
-                               pool=pool)
-    node = Planner(index, optimize=optimize).plan_group_agg(measure, cols, e)
-    return Executor(index, backend=backend,
-                    cache=cache).run_group_agg(node)
-
-
-class QueryBatch:
-    """Evaluate many expressions in one pass sharing loaded operands.
-
-    Plans are built up front, then all plans execute against one operand
-    cache, so a bitmap referenced by several queries (the common case for
-    dashboard-style workloads: same dimensions, different slices) is
-    concatenated from its partitions — and decompressed, on the kernel
-    path — exactly once.
-    """
-
-    def __init__(self, exprs: Sequence[Expr]):
-        self.exprs = list(exprs)
-
-    def execute(self, index, backend: Backend = "auto",
-                optimize: bool = True, pool=None) -> List[EWAH]:
-        from .shard import ShardedIndex
-        if isinstance(index, ShardedIndex):
-            # one operand cache per shard, shared across the whole batch
-            caches: List[Dict] = [{} for _ in index.shards]
-            return [index.execute(e, backend=backend, optimize=optimize,
-                                  caches=caches, pool=pool)
-                    for e in self.exprs]
-        plans = [plan(index, e, optimize=optimize) for e in self.exprs]
-        cache: Dict = {}
-        ex = Executor(index, backend=backend, cache=cache)
-        return [ex.run(p) for p in plans]
-
-    def execute_rows(self, index, backend: Backend = "auto",
-                     optimize: bool = True, pool=None) -> List[np.ndarray]:
-        return [bm.set_bits()
-                for bm in self.execute(index, backend=backend,
-                                       optimize=optimize, pool=pool)]

@@ -187,7 +187,7 @@ def to_wire(e: Expr) -> dict:
     """Expr tree -> JSON-serializable wire object (see ``from_wire``).
 
     The wire format mirrors the AST and is shared by the HTTP serving layer
-    (``repro.serve.query_api``) and the write-ahead log
+    (``serve/query_api.py``) and the write-ahead log
     (``repro.core.wal``), which persists delete predicates as expressions so
     crash replay re-evaluates them in original order.
     """

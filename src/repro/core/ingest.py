@@ -15,11 +15,11 @@ bitmap:
   (the predicate's result bitmap ORs into the tombstone); nothing is
   rewritten.
 * ``LiveIndex`` — the read view ``(base ⊔ delta) AND NOT tombstones``.
-  Count / group-by / top-k stay compressed-domain across the merge:
-  per-shard partial counts (vectors) come from base and delta
-  independently, with tombstone popcounts subtracted via the run-aligned
-  ``EWAH.and_count`` — no global result bitmap, mirroring how the base
-  executes.  Delta rows occupy the global id range starting at the base's
+  Every statement stays compressed-domain across the merge: each base
+  shard and the delta run its task (``repro.core.statement``) with the
+  layer's tombstone folded into the filter, and the partials merge — no
+  global result bitmap, mirroring how the base executes.  Delta rows
+  occupy the global id range starting at the base's
   next 32-bit word boundary, so layer results concatenate *exactly* (the
   phantom gap rows are never set).
 * write-ahead log — every mutation is durably framed (CRC-checked, see
@@ -43,12 +43,12 @@ import numpy as np
 
 from . import measures as _ms
 from . import wal as walmod
-from .ewah import EWAH, _empty_ewah
+from .ewah import EWAH
 from .expr import Expr, canonical_key
-from .index import (BitmapIndex, ColumnIndex, IndexBuilder, WORD_ROWS,
-                    concat_bitmaps)
-from .planner import PAgg, PGroupAgg, PGroupCount, Planner, PPinned
+from .index import BitmapIndex, ColumnIndex, IndexBuilder, WORD_ROWS
+from .planner import PPinned
 from .shard import ShardedIndex
+from .statement import group_task, merge, run_on_shard, task_measure
 
 DELTA_PARTITION_ROWS = 4096
 
@@ -418,7 +418,7 @@ class LiveIndex:
     def _apply_delete(self, e: Expr) -> int:
         removed = 0
         if self.base.n_rows:
-            for i, p in enumerate(self.base.execute_per_shard(e)):
+            for i, p in enumerate(self.base.run_per_shard(("expr", e))):
                 t = self._tombs[i]
                 if t is None:
                     if p.count():
@@ -428,8 +428,7 @@ class LiveIndex:
                     removed += p.count() - p.and_count(t)
                     self._tombs[i] = t | p
         if self.delta.n_rows:
-            from .executor import execute as _execute
-            dres = _execute(self.delta.index(), e)
+            dres = run_on_shard(self.delta.index(), ("expr", e))
             dt = self._dtomb.pad_to(self.delta.n_rows) \
                 if self._dtomb is not None else None
             if dt is None:
@@ -464,224 +463,90 @@ class LiveIndex:
         keying by the snapshotted version keeps a read racing an append
         from filing the old index's result under the new version.
         """
-        from .executor import execute as _execute
         didx, dver = dsnap
         key = (dver, backend, bool(optimize), canonical_key(e))
         hit = self._dcache.get(key)
         if hit is None:
-            hit = _execute(didx, e, backend=backend, optimize=optimize)
+            hit = run_on_shard(didx, ("expr", e), backend, optimize)
             if len(self._dcache) >= DELTA_CACHE_ENTRIES:
                 self._dcache.clear()
             self._dcache[key] = hit
         return hit
+
+    def run(self, task, backend: str = "auto", optimize: bool = True,
+            cache: Optional[Dict] = None, pool=None):
+        """One statement task (``repro.core.statement``) on the live view,
+        compressed-domain across the base+delta merge: every base shard
+        and the delta run it with that layer's tombstone folded into the
+        filter (pinned into the plan as an already-evaluated bitmap), and
+        the partials merge (``statement.merge``) — no global result bitmap
+        for an aggregate.  While no base shard has a tombstone, the base
+        runs the task itself through its shard LRUs.  Delta row ``i`` has
+        id ``align32(base rows) + i``, so row results concatenate across
+        the word-aligned gap.  ``cache`` is unused: each layer caches its
+        own results."""
+        kind, e = task[0], task[-1]
+        if e is not None and not isinstance(e, Expr):
+            raise TypeError("LiveIndex executes Expr trees (each layer "
+                            "plans independently); got a plan node")
+        name = task_measure(task)
+        if name is not None and name not in self.measure_spec:
+            raise KeyError(f"unknown measure {name!r}; this live index "
+                           f"declares {sorted(self.measure_spec)}")
+        base, tombs, dsnap, dn, dt = self._snapshot()
+        parts: List = []
+        if base.n_rows:
+            if all(t is None for t in tombs):
+                parts = base.run_per_shard(task, backend, optimize,
+                                           pool=pool)
+            else:
+                fparts = base.run_per_shard(("expr", e), backend, optimize,
+                                            pool=pool) \
+                    if e is not None else [None] * len(tombs)
+                parts = [_masked(sh, task, fp, t, backend, optimize)
+                         for sh, t, fp in zip(base.shards, tombs, fparts)]
+        if dsnap[0] is not None:
+            fp = self._delta_result(dsnap, e, backend, optimize) \
+                if e is not None else None
+            gap = _align32(base.n_rows) - base.n_rows
+            if kind == "expr" and parts and gap:
+                # pad the base's ragged tail so delta ids start word-aligned
+                parts[-1] = parts[-1].pad_to(parts[-1].n_bits + gap)
+            parts.append(_masked(dsnap[0], task, fp, dt, backend, optimize))
+        return merge(task, parts, base)
 
     def execute(self, e, backend: str = "auto", optimize: bool = True,
                 pool=None) -> EWAH:
         """The live result bitmap of ``e``: per-shard base results (cached
         in the shards' LRUs) minus their tombstones, concatenated with the
         delta result minus its tombstone across the word-aligned gap."""
-        if not isinstance(e, Expr):
-            raise TypeError("LiveIndex executes Expr trees (each layer "
-                            "plans independently); got a plan node")
-        base, tombs, dsnap, dn, dt = self._snapshot()
-        parts: List[EWAH] = []
-        if base.n_rows:
-            for p, t in zip(base.execute_per_shard(e, backend=backend,
-                                                   optimize=optimize,
-                                                   pool=pool), tombs):
-                parts.append(p.andnot(t) if t is not None else p)
-        if dsnap[0] is not None:
-            dres = self._delta_result(dsnap, e, backend, optimize)
-            if dt is not None:
-                dres = dres.andnot(dt)
-            gap = _align32(base.n_rows) - base.n_rows
-            if parts and gap:
-                # pad the base's ragged tail so delta ids start word-aligned
-                parts[-1] = parts[-1].pad_to(parts[-1].n_bits + gap)
-            parts.append(dres)
-        if not parts:
-            return _empty_ewah(0)
-        return parts[0] if len(parts) == 1 else concat_bitmaps(parts)
+        return self.run(("expr", e), backend, optimize, pool=pool)
 
     def count(self, e: Optional[Expr] = None, backend: str = "auto",
               optimize: bool = True, pool=None) -> int:
-        """COUNT(*) under ``e`` — per-layer compressed-domain popcounts with
-        tombstone overlaps subtracted (``count - and_count(tombstone)``);
-        no result bitmap ever exists."""
-        base, tombs, dsnap, dn, dt = self._snapshot()
-        if e is None:
-            dead = sum(t.count() for t in tombs if t is not None)
-            return base.n_rows - dead + dn - (dt.count() if dt else 0)
-        total = 0
-        if base.n_rows:
-            for p, t in zip(base.execute_per_shard(e, backend=backend,
-                                                   optimize=optimize,
-                                                   pool=pool), tombs):
-                total += p.count() - (p.and_count(t) if t is not None else 0)
-        if dsnap[0] is not None:
-            dres = self._delta_result(dsnap, e, backend, optimize)
-            total += dres.count() - (dres.and_count(dt) if dt is not None
-                                     else 0)
-        return total
+        """COUNT(*) under ``e`` over the live rows."""
+        return self.run(("count", e), backend, optimize, pool=pool)
 
     def group_count(self, col, e: Optional[Expr] = None,
                     backend: str = "auto", optimize: bool = True,
                     pool=None) -> np.ndarray:
-        """GROUP BY ``col`` COUNT(*) under ``e``, compressed-domain across
-        the base+delta merge: per-shard partial vectors from both layers
-        are summed, with tombstones folded into each shard's effective
-        filter (pinned into the plan as an already-evaluated bitmap)."""
-        from .executor import Executor, execute_group_count as _egc
-        base, tombs, dsnap, dn, dt = self._snapshot()
-        didx = dsnap[0]
-        c = base.resolve_column(col)
-        out = np.zeros(base.card(c), dtype=np.int64)
-        if base.n_rows:
-            if all(t is None for t in tombs):
-                out += base.group_count(c, e, backend=backend,
-                                        optimize=optimize, pool=pool)
-            else:
-                fparts = base.execute_per_shard(
-                    e, backend=backend, optimize=optimize, pool=pool) \
-                    if e is not None else [None] * len(tombs)
-                for sh, t, fp in zip(base.shards, tombs, fparts):
-                    if not sh.n_rows:
-                        continue
-                    planner = Planner(sh, optimize=optimize)
-                    if t is None and fp is None:
-                        node = planner.plan_group_count(c, None)
-                    else:
-                        if t is None:
-                            eff = fp
-                        elif fp is None:
-                            eff = ~t
-                        else:
-                            eff = fp.andnot(t)
-                        groups = planner.plan_group_count(c, None).groups
-                        node = PGroupCount(c, groups, PPinned(eff))
-                    out += Executor(sh, backend=backend) \
-                        .run_group_count(node)
-        if didx is not None:
-            if dt is None:
-                out += _egc(didx, c, e, backend=backend, optimize=optimize)
-            else:
-                if e is not None:
-                    eff = self._delta_result(dsnap, e, backend,
-                                             optimize).andnot(dt)
-                else:
-                    eff = ~dt
-                groups = Planner(didx, optimize=optimize) \
-                    .plan_group_count(c, None).groups
-                node = PGroupCount(c, groups, PPinned(eff))
-                out += Executor(didx, backend=backend).run_group_count(node)
-        return out
+        """GROUP BY ``col`` COUNT(*) under ``e`` over the live rows."""
+        return self.run(("gcount", self.resolve_column(col), e), backend,
+                        optimize, pool=pool)
 
     def agg(self, measure, e: Optional[Expr] = None, backend: str = "auto",
             optimize: bool = True, pool=None):
-        """Scalar ``(sum, count, min, max)`` of ``measure`` under ``e``,
-        compressed-domain across the base+delta merge: each layer slices
-        its own measure sidecar with its effective filter (tombstones
-        pinned into the plan as already-evaluated bitmaps) and the partial
-        tuples merge — no row reconstruction anywhere."""
-        from .executor import Executor
-        name = str(measure)
-        if name not in self.measure_spec:
-            raise KeyError(f"unknown measure {name!r}; this live index "
-                           f"declares {sorted(self.measure_spec)}")
-        base, tombs, dsnap, dn, dt = self._snapshot()
-        didx = dsnap[0]
-        parts = []
-        if base.n_rows:
-            if all(t is None for t in tombs):
-                parts.append(base.agg(name, e, backend=backend,
-                                      optimize=optimize, pool=pool))
-            else:
-                fparts = base.execute_per_shard(
-                    e, backend=backend, optimize=optimize, pool=pool) \
-                    if e is not None else [None] * len(tombs)
-                for sh, t, fp in zip(base.shards, tombs, fparts):
-                    if not sh.n_rows:
-                        continue
-                    planner = Planner(sh, optimize=optimize)
-                    if t is None and fp is None:
-                        node = planner.plan_agg(name, None)
-                    else:
-                        eff = fp if t is None else \
-                            (~t if fp is None else fp.andnot(t))
-                        planner._measure_check(name)
-                        node = PAgg(name, PPinned(eff))
-                    parts.append(Executor(sh, backend=backend).run_agg(node))
-        if didx is not None:
-            planner = Planner(didx, optimize=optimize)
-            if dt is None and e is None:
-                node = planner.plan_agg(name, None)
-            else:
-                if e is not None:
-                    eff = self._delta_result(dsnap, e, backend, optimize)
-                    if dt is not None:
-                        eff = eff.andnot(dt)
-                else:
-                    eff = ~dt
-                planner._measure_check(name)
-                node = PAgg(name, PPinned(eff))
-            parts.append(Executor(didx, backend=backend).run_agg(node))
-        return _ms.merge_scalar_aggs(parts)
+        """Scalar ``(sum, count, min, max)`` of ``measure`` under ``e``
+        over the live rows."""
+        return self.run(("agg", str(measure), e), backend, optimize,
+                        pool=pool)
 
     def group_agg(self, measure, cols, e: Optional[Expr] = None,
                   backend: str = "auto", optimize: bool = True, pool=None):
-        """Grouped aggregates over one or more columns across the base+delta
-        merge (``measure=None`` computes counts only) — same per-layer
-        partial shape as ``Executor.run_group_agg``, merged elementwise,
-        tombstones pinned exactly as in ``group_count``."""
-        from .executor import Executor
-        name = None if measure is None else str(measure)
-        if name is not None and name not in self.measure_spec:
-            raise KeyError(f"unknown measure {name!r}; this live index "
-                           f"declares {sorted(self.measure_spec)}")
-        base, tombs, dsnap, dn, dt = self._snapshot()
-        didx = dsnap[0]
-        if isinstance(cols, (int, np.integer, str)):
-            cols = [cols]
-        cs = tuple(base.resolve_column(c) for c in cols)
-        parts = []
-        if base.n_rows:
-            if all(t is None for t in tombs):
-                parts.append(base.group_agg(name, list(cs), e,
-                                            backend=backend,
-                                            optimize=optimize, pool=pool))
-            else:
-                fparts = base.execute_per_shard(
-                    e, backend=backend, optimize=optimize, pool=pool) \
-                    if e is not None else [None] * len(tombs)
-                for sh, t, fp in zip(base.shards, tombs, fparts):
-                    if not sh.n_rows:
-                        continue
-                    planner = Planner(sh, optimize=optimize)
-                    node = planner.plan_group_agg(name, list(cs), None)
-                    if not (t is None and fp is None):
-                        eff = fp if t is None else \
-                            (~t if fp is None else fp.andnot(t))
-                        node = PGroupAgg(name, node.cols, node.groups,
-                                         PPinned(eff))
-                    parts.append(
-                        Executor(sh, backend=backend).run_group_agg(node))
-        if didx is not None:
-            planner = Planner(didx, optimize=optimize)
-            if dt is None:
-                node = planner.plan_group_agg(name, list(cs), e)
-            else:
-                eff = self._delta_result(dsnap, e, backend,
-                                         optimize).andnot(dt) \
-                    if e is not None else ~dt
-                plain = planner.plan_group_agg(name, list(cs), None)
-                node = PGroupAgg(name, plain.cols, plain.groups, PPinned(eff))
-            parts.append(Executor(didx, backend=backend).run_group_agg(node))
-        if not parts:
-            shape = tuple(base.card(c) for c in cs)
-            return _ms.empty_group_agg(cs, shape, name,
-                                       self.measure_spec.get(name)
-                                       if name else None)
-        return _ms.merge_group_aggs(parts)
+        """Grouped aggregates over one or more columns (``measure=None``
+        computes counts only) over the live rows."""
+        return self.run(group_task(measure, cols, self.resolve_column, e),
+                        backend, optimize, pool=pool)
 
     # -- compaction ----------------------------------------------------------
     def compact(self, relayout: bool = False) -> Dict:
@@ -910,6 +775,17 @@ class LiveIndex:
         if not isinstance(idx, ShardedIndex):
             idx = ShardedIndex([idx], column_names=self.column_names)
         return idx
+
+
+def _masked(layer: BitmapIndex, task, fp: Optional[EWAH],
+            t: Optional[EWAH], backend: str, optimize: bool):
+    """``task`` on one layer whose filter evaluated to ``fp`` (``None``:
+    no filter), minus the layer's tombstone ``t``."""
+    if t is None and fp is None:
+        return run_on_shard(layer, task, backend, optimize)
+    eff = fp if t is None else (~t if fp is None else fp.andnot(t))
+    return run_on_shard(layer, task[:-1] + (PPinned(eff),), backend,
+                        optimize)
 
 
 class Compactor:

@@ -310,6 +310,8 @@ class Planner:
         return self._plan(e)
 
     def _plan(self, e: Expr) -> PlanNode:
+        if isinstance(e, PlanNode):
+            return e  # already lowered (a pinned filter, a hand-built plan)
         if self.optimize:
             e = flatten(push_not(e))
         return self._lower(e)
@@ -321,7 +323,7 @@ class Planner:
         node = PCount(child)
         node.est_words = 0
         node.est_rows = child.est_rows
-        node.ckey = ("count", child.ckey)
+        node.ckey = None if child.ckey is None else ("count", child.ckey)
         return node
 
     @_spanned
@@ -341,8 +343,10 @@ class Planner:
         node.est_words = 0
         node.est_rows = filt.est_rows if filt is not None else \
             self.index.n_rows
-        node.ckey = ("gcount", c,
-                     None if filt is None else filt.ckey)
+        if filt is not None and filt.ckey is None:
+            node.ckey = None  # pinned filter: no stable structural identity
+        else:
+            node.ckey = ("gcount", c, None if filt is None else filt.ckey)
         return node
 
     def _measure_check(self, name: str) -> None:

@@ -2,7 +2,7 @@
 
 Queries are composable ``Expr`` trees (see ``repro.core.expr``) built with
 operator overloading, planned by ``repro.core.planner`` and evaluated by
-``repro.core.executor``:
+``repro.core.executor`` (``repro.core.statement`` runs them on any index):
 
     from repro.core import col, query
     hits = query.execute(index, (col(0) == 3) & ~col(1).isin([1, 2]))
@@ -21,7 +21,7 @@ from typing import Dict, Optional, Sequence
 import numpy as np
 
 from .expr import And, Const, Eq, Expr, In, Not, Or, Range, col
-from .executor import QueryBatch, execute, execute_rows
+from .statement import QueryBatch, execute, execute_rows
 from .planner import explain, plan
 
 __all__ = [

@@ -38,10 +38,13 @@ import numpy as np
 
 from . import trace as _trace
 from .ewah import EWAH
-from .expr import Expr, canonical_key
+from .expr import Expr
 from .index import (BitmapIndex, ColumnIndex, IndexBuilder, WORD_ROWS,
                     concat_bitmaps, validate_partition_rows)
 from .lru import LRUCache, payload_kind, payload_nbytes
+from .statement import (TASK_ARGS, group_task, merge, run_on_shard,
+                        task_key, top_k_from_counts, top_k_from_values,
+                        top_k_of)
 
 # per-shard result-cache defaults (entries + byte budget per shard)
 SHARD_CACHE_ENTRIES = 64
@@ -361,15 +364,16 @@ class ShardedIndex:
     def cache_stats(self) -> List[Dict]:
         return [c.stats() for c in self._result_caches]
 
-    def _fan_out(self, key, run_shard, task, pool,
-                 backend: str, optimize: bool) -> List:
-        """Shared shard fan-out: per-shard LRU lookup, pool dispatch for the
-        misses, cache refill.  Returns one result per shard, in order.
+    def _fan_out(self, key, task, pool, backend: str, optimize: bool,
+                 caches: Optional[List[Dict]] = None) -> List:
+        """Shared shard fan-out: per-shard LRU lookup, ``run_on_shard`` on
+        the pool for the misses, cache refill.  Returns one partial per
+        shard, in order.
 
         ``key`` (or ``None`` to skip caching) addresses the shard-local
-        LRUs; ``task`` is the picklable statement shipped to a
-        ``ShardProcessPool``; ``run_shard(i, shard)`` is the in-process
-        fallback, handed the shard object from *this* snapshot.
+        LRUs; ``task`` is the statement task (``repro.core.statement``),
+        shipped as is to a ``ShardProcessPool``; ``caches`` holds one
+        operand cache per shard.
 
         Caches are snapshotted *before* shards — in here, so no caller can
         get the order wrong: ``replace_shard`` writes the shard first, then
@@ -390,6 +394,11 @@ class ShardedIndex:
             for i in range(n):
                 parts[i] = rcaches[i].get(key)
         missing = [i for i, p in enumerate(parts) if p is None]
+
+        def run_shard(i: int):
+            return run_on_shard(shards[i], task, backend, optimize,
+                                None if caches is None else caches[i])
+
         if isinstance(pool, ShardProcessPool) and len(missing) > 1:
             fresh = pool.run_shards(task, missing, backend=backend,
                                     optimize=optimize)
@@ -397,117 +406,81 @@ class ShardedIndex:
                 and len(missing) > 1:
             runs = [_trace.bind(run_shard, wait="shard.queue_wait",
                                 run="shard.run") for _ in missing]
-            fresh = list(pool.map(lambda run, i: run(i, shards[i]), runs,
-                                  missing))
+            fresh = list(pool.map(lambda run, i: run(i), runs, missing))
         else:
             fresh = []
             for i in missing:
                 with _trace.span("shard.run"):
-                    fresh.append(run_shard(i, shards[i]))
+                    fresh.append(run_shard(i))
         for i, res in zip(missing, fresh):
             parts[i] = res
             if key is not None:
                 rcaches[i].put(key, res)
         return parts
 
+    def run_per_shard(self, task, backend: str = "auto",
+                      optimize: bool = True,
+                      caches: Optional[List[Dict]] = None,
+                      pool=None) -> List:
+        """Per-shard partials of one task, in shard order, memoized in the
+        shard-local LRUs under the task's key (``statement.task_key``).
+        The live-ingest layer pairs each shard's partial with that shard's
+        tombstone, so the LRU entries (keyed by the task alone) stay valid
+        across tombstone changes."""
+        key = task_key(task)
+        if key is not None:
+            key += (backend, bool(optimize))
+        return self._fan_out(key, task, pool, backend, optimize, caches)
+
+    def run(self, task, backend: str = "auto", optimize: bool = True,
+            cache: Optional[Dict] = None, pool=None,
+            caches: Optional[List[Dict]] = None):
+        """Plan and run ``task`` per shard, merge the partials
+        (``statement.merge``): counts sum, count vectors add, measure
+        partials merge, EWAH results concatenate — an aggregate never
+        builds the global result bitmap.
+
+        ``pool`` (any ``concurrent.futures`` executor, or a
+        ``ShardProcessPool``) runs shards concurrently; shard tasks submit
+        no further work, so a dedicated pool is deadlock-free by
+        construction.  ``caches`` (one operand dict per shard), or
+        ``cache`` (one dict holding them), lets a batch share loaded
+        bitmaps across statements, exactly like ``Executor``'s cache does
+        for a monolithic index.  Per-shard results are memoized in the
+        shard-local LRUs keyed by the filter's ``canonical_key`` — a repeat
+        (or commutatively reordered) statement only re-executes shards
+        whose cache was invalidated."""
+        e = task[-1]
+        if task[0] != "expr" and e is not None and not isinstance(e, Expr):
+            raise TypeError(f"{task[0]} takes an Expr or None, got {e!r}")
+        if caches is None and cache is not None:
+            caches = [cache.setdefault(("shard", i), {})
+                      for i in range(self.n_shards)]
+        return merge(task, self.run_per_shard(task, backend, optimize,
+                                              caches, pool), self)
+
     def execute(self, e, backend: str = "auto", optimize: bool = True,
                 caches: Optional[List[Dict]] = None, pool=None) -> EWAH:
-        """Plan per shard, execute per shard, concatenate the EWAH results.
-
-        ``caches`` (one operand dict per shard) lets a batch share loaded
-        bitmaps across queries, exactly like ``Executor``'s cache does for a
-        monolithic index.  ``pool`` (any ``concurrent.futures`` executor)
-        runs shards concurrently; shard tasks submit no further work, so a
-        dedicated pool is deadlock-free by construction.  Per-shard results
-        of ``Expr`` queries are memoized in the shard-local LRU keyed by
-        ``canonical_key`` — a repeat (or commutatively reordered) query only
-        re-executes shards whose cache was invalidated.
-        """
-        return concat_bitmaps(self.execute_per_shard(
-            e, backend=backend, optimize=optimize, caches=caches, pool=pool))
-
-    def execute_per_shard(self, e, backend: str = "auto",
-                          optimize: bool = True,
-                          caches: Optional[List[Dict]] = None,
-                          pool=None) -> List[EWAH]:
-        """Per-shard EWAH results of one expression, in shard order.
-
-        The fan-out behind ``execute``, exposed separately for callers that
-        need the un-concatenated slices — the live-ingest layer pairs each
-        shard's result with that shard's tombstone before merging, so the
-        shard-local LRU entries (keyed by the expression alone) stay valid
-        across tombstone changes.
-        """
-        from .executor import Executor  # local: executor also dispatches here
-        from .planner import plan
-        key = (("expr", backend, bool(optimize), canonical_key(e))
-               if isinstance(e, Expr) else None)
-
-        def run_shard(i: int, sh: BitmapIndex) -> EWAH:
-            node = plan(sh, e, optimize=optimize) if isinstance(e, Expr) else e
-            cache = caches[i] if caches is not None else None
-            return Executor(sh, backend=backend, cache=cache).run(node)
-
-        return self._fan_out(key, run_shard, ("expr", e), pool,
-                             backend, optimize)
+        """The EWAH result of one expression (or plan): per-shard results
+        concatenated."""
+        return self.run(("expr", e), backend, optimize, pool=pool,
+                        caches=caches)
 
     def count(self, e=None, backend: str = "auto", optimize: bool = True,
               caches: Optional[List[Dict]] = None, pool=None) -> int:
-        """COUNT(*) under filter ``e`` (``None`` counts every row).
-
-        Each shard plans and popcounts its own slice in the compressed
-        domain; the coordinator *sums the integers* — no per-shard result
-        bitmap is ever concatenated for an aggregate.
-        """
-        from .executor import Executor
-        from .planner import Planner
-        if e is not None and not isinstance(e, Expr):
-            raise TypeError(f"count() takes an Expr or None, got {e!r}")
-        key = ("count", backend, bool(optimize),
-               canonical_key(e) if e is not None else None)
-
-        def run_shard(i: int, sh: BitmapIndex) -> int:
-            node = Planner(sh, optimize=optimize).plan_count(e)
-            cache = caches[i] if caches is not None else None
-            return Executor(sh, backend=backend, cache=cache).run_count(node)
-
-        parts = self._fan_out(key, run_shard, ("count", e), pool,
-                              backend, optimize)
-        return int(sum(parts))
+        """COUNT(*) under filter ``e`` (``None`` counts every row)."""
+        return self.run(("count", e), backend, optimize, pool=pool,
+                        caches=caches)
 
     def group_count(self, col, e=None, backend: str = "auto",
                     optimize: bool = True,
                     caches: Optional[List[Dict]] = None,
                     pool=None) -> np.ndarray:
         """GROUP BY ``col`` COUNT(*) under filter ``e`` -> int64 vector of
-        length ``card(col)``.
-
-        The shards share one set of encoders, so every shard produces a
-        count vector in the same value-rank space; the coordinator merges
-        by *summing the partial vectors* (scatter/gather aggregation — the
-        global result bitmap that ``execute`` would concatenate never
-        exists here).
-        """
-        from .executor import Executor
-        from .planner import Planner
-        if e is not None and not isinstance(e, Expr):
-            raise TypeError(f"group_count() takes an Expr or None, got {e!r}")
-        c = self.resolve_column(col)
-        key = ("gcount", c, backend, bool(optimize),
-               canonical_key(e) if e is not None else None)
-
-        def run_shard(i: int, sh: BitmapIndex) -> np.ndarray:
-            node = Planner(sh, optimize=optimize).plan_group_count(c, e)
-            cache = caches[i] if caches is not None else None
-            return Executor(sh, backend=backend,
-                            cache=cache).run_group_count(node)
-
-        parts = self._fan_out(key, run_shard, ("gcount", c, e), pool,
-                              backend, optimize)
-        out = np.zeros(self.card(c), dtype=np.int64)
-        for p in parts:
-            out += p
-        return out
+        length ``card(col)``: the shards share one set of encoders, so
+        their partial vectors add."""
+        return self.run(("gcount", self.resolve_column(col), e), backend,
+                        optimize, pool=pool, caches=caches)
 
     # -- measure aggregates (compressed-domain OLAP) ------------------------
     @property
@@ -519,56 +492,18 @@ class ShardedIndex:
             pool=None):
         """Scalar ``(sum, count, min, max)`` of ``measure`` under filter
         ``e``: each shard slices its own measure sidecar by its filter
-        intervals, the coordinator merges the four-number partials —
-        bitmaps and measure values never leave their shard."""
-        from .executor import Executor
-        from .planner import Planner
-        from .measures import merge_scalar_aggs
-        if e is not None and not isinstance(e, Expr):
-            raise TypeError(f"agg() takes an Expr or None, got {e!r}")
-        name = str(measure)
-        key = ("agg", name, backend, bool(optimize),
-               canonical_key(e) if e is not None else None)
-
-        def run_shard(i: int, sh: BitmapIndex):
-            node = Planner(sh, optimize=optimize).plan_agg(name, e)
-            cache = caches[i] if caches is not None else None
-            return Executor(sh, backend=backend, cache=cache).run_agg(node)
-
-        parts = self._fan_out(key, run_shard, ("agg", name, e), pool,
-                              backend, optimize)
-        return merge_scalar_aggs(parts)
+        intervals — bitmaps and measure values never leave their shard."""
+        return self.run(("agg", str(measure), e), backend, optimize,
+                        pool=pool, caches=caches)
 
     def group_agg(self, measure, cols, e=None, backend: str = "auto",
                   optimize: bool = True,
                   caches: Optional[List[Dict]] = None, pool=None) -> Dict:
         """GROUP BY one or more columns aggregating ``measure`` (or
         counting rows when ``None``) into flat partials of the dense
-        row-major cube ``[card(c0), ..., card(cn-1)]`` (``shape``);
-        per-shard partial dicts merge elementwise (sums/counts add,
-        mins/maxs combine against their identities)."""
-        from .executor import Executor
-        from .planner import Planner
-        from .measures import merge_group_aggs
-        if e is not None and not isinstance(e, Expr):
-            raise TypeError(f"group_agg() takes an Expr or None, got {e!r}")
-        name = None if measure is None else str(measure)
-        if not isinstance(cols, (list, tuple)):
-            cols = [cols]
-        cs = tuple(self.resolve_column(c) for c in cols)
-        key = ("gagg", name, cs, backend, bool(optimize),
-               canonical_key(e) if e is not None else None)
-
-        def run_shard(i: int, sh: BitmapIndex) -> Dict:
-            node = Planner(sh, optimize=optimize).plan_group_agg(
-                name, list(cs), e)
-            cache = caches[i] if caches is not None else None
-            return Executor(sh, backend=backend,
-                            cache=cache).run_group_agg(node)
-
-        parts = self._fan_out(key, run_shard, ("gagg", name, cs, e), pool,
-                              backend, optimize)
-        return merge_group_aggs(parts)
+        row-major cube ``[card(c0), ..., card(cn-1)]`` (``shape``)."""
+        return self.run(group_task(measure, cols, self.resolve_column, e),
+                        backend, optimize, pool=pool, caches=caches)
 
     def top_k(self, col, k: int, e=None, measure=None,
               backend: str = "auto", optimize: bool = True,
@@ -589,7 +524,6 @@ class ShardedIndex:
         vector merge.  Ties break by (value desc, rank asc) — identical to
         the monolithic ``top_k_from_counts`` path.
         """
-        from .dataset import top_k_from_counts, top_k_from_values
         c = self.resolve_column(col)
         k = int(k)
         if k <= 0:
@@ -598,25 +532,15 @@ class ShardedIndex:
         card = self.card(c)
 
         def full_merge() -> List:
-            agg = self.group_agg(name, [c], e, backend=backend,
-                                 optimize=optimize, caches=caches, pool=pool)
-            if name is None:
-                return top_k_from_counts(agg["counts"], k)
-            return top_k_from_values(agg["sums"], agg["counts"], k)
+            task = ("gcount", c, e) if name is None \
+                else ("gagg", name, (c,), e)
+            return top_k_of(name, self.run(task, backend, optimize,
+                                           pool=pool, caches=caches), k)
 
         if card <= k or self.n_shards == 1:
             return full_merge()
-        key = ("gtop", c, name, k, backend, bool(optimize),
-               canonical_key(e) if e is not None else None)
-
-        def run_gtop(i: int, sh: BitmapIndex) -> Dict:
-            cache = caches[i] if caches is not None else None
-            return run_shard_task(sh, ("gtop", c, e, k, name),
-                                  backend=backend, optimize=optimize,
-                                  cache=cache)
-
-        parts = self._fan_out(key, run_gtop, ("gtop", c, e, k, name), pool,
-                              backend, optimize)
+        parts = self.run_per_shard(("gtop", name, c, k, e), backend,
+                                   optimize, caches, pool)
         if not all(p["prunable"] for p in parts):
             return full_merge()
         vdt = parts[0]["vals"].dtype
@@ -629,16 +553,9 @@ class ShardedIndex:
         kth_lb = np.partition(lb, card - k)[card - k]
         candidates = np.flatnonzero(ub >= kth_lb)
         ids = tuple(int(g) for g in candidates)
-
-        def run_gvals(i: int, sh: BitmapIndex) -> Dict:
-            cache = caches[i] if caches is not None else None
-            return run_shard_task(sh, ("gvals", c, e, ids, name),
-                                  backend=backend, optimize=optimize,
-                                  cache=cache)
-
         # candidate sets are query-dependent; phase 2 skips the result LRU
-        parts2 = self._fan_out(None, run_gvals, ("gvals", c, e, ids, name),
-                               pool, backend, optimize)
+        parts2 = self._fan_out(None, ("gvals", name, c, ids, e), pool,
+                               backend, optimize, caches)
         vals = np.zeros(card, dtype=vdt)
         counts = np.zeros(card, dtype=np.int64)
         for p in parts2:
@@ -723,75 +640,6 @@ def _fork_index(pool_key: int) -> "ShardedIndex":
     return idx
 
 
-def run_shard_task(sh: BitmapIndex, task, backend: str = "auto",
-                   optimize: bool = True, cache: Optional[Dict] = None):
-    """Execute one shard *statement task* against one shard.
-
-    ``task`` mirrors the coordinator's statement kinds: ``("expr", e)``
-    returns the shard's EWAH result, ``("count", e)`` its partial count and
-    ``("gcount", col, e)`` its partial per-value count vector — aggregates
-    ship a few integers across a process or network boundary instead of a
-    bitmap.  Measure statements follow the same shape: ``("agg", measure,
-    e)`` returns the shard's ``(sum, count, min, max)`` partial,
-    ``("gagg", measure, cols, e)`` its grouped partial dict, ``("gtop",
-    col, e, m, measure)`` its pruned top-m report (ids/vals/counts plus the
-    ``tau`` threshold and a ``prunable`` flag) and ``("gvals", col, e, ids,
-    measure)`` exact partials at the given candidate ids.  This is the
-    single shard-side execution path shared by the fork-based
-    ``ShardProcessPool`` and the RPC worker tier
-    (``repro.serve.worker_api``), so a remote worker computes exactly what
-    the single-process ``ShardedIndex`` fan-out would.
-    """
-    from .executor import Executor
-    from .planner import Planner, plan
-    kind = task[0]
-    ex = Executor(sh, backend=backend, cache=cache)
-    if kind == "expr":
-        e = task[1]
-        node = plan(sh, e, optimize=optimize) if isinstance(e, Expr) else e
-        return ex.run(node)
-    if kind == "count":
-        return ex.run_count(Planner(sh, optimize=optimize).plan_count(task[1]))
-    if kind == "gcount":
-        return ex.run_group_count(
-            Planner(sh, optimize=optimize).plan_group_count(task[1], task[2]))
-    if kind == "agg":
-        return ex.run_agg(
-            Planner(sh, optimize=optimize).plan_agg(task[1], task[2]))
-    if kind == "gagg":
-        return ex.run_group_agg(
-            Planner(sh, optimize=optimize).plan_group_agg(
-                task[1], list(task[2]), task[3]))
-    if kind == "gtop":
-        col, e, m, measure = task[1], task[2], int(task[3]), task[4]
-        agg = ex.run_group_agg(
-            Planner(sh, optimize=optimize).plan_group_agg(measure, [col], e))
-        counts = agg["counts"]
-        vals = counts if measure is None else agg["sums"]
-        nz = np.flatnonzero(counts)
-        # sum-pruning needs non-negative partials everywhere: one negative
-        # value and "unreported <= tau" no longer bounds anything
-        prunable = (measure is None or not len(nz)
-                    or not bool(vals[nz].min() < 0))
-        order = nz[np.lexsort((nz, -vals[nz]))][:m]
-        if len(nz) > m:
-            tau = vals[order[-1]]
-            tau = float(tau) if vals.dtype.kind == "f" else int(tau)
-        else:
-            tau = 0.0 if vals.dtype.kind == "f" else 0
-        return {"ids": order, "vals": vals[order], "counts": counts[order],
-                "tau": tau, "prunable": prunable}
-    if kind == "gvals":
-        col, e, ids, measure = task[1], task[2], task[3], task[4]
-        ids = np.asarray(ids, dtype=np.int64)
-        agg = ex.run_group_agg(
-            Planner(sh, optimize=optimize).plan_group_agg(measure, [col], e))
-        counts = agg["counts"]
-        vals = counts if measure is None else agg["sums"]
-        return {"vals": vals[ids], "counts": counts[ids]}
-    raise ValueError(f"unknown shard task {kind!r}")
-
-
 def _forked_run(args):
     """Worker-side shard statement execution (operand caches per worker)."""
     pool_key, shard_i, task, backend, optimize = args
@@ -801,8 +649,7 @@ def _forked_run(args):
                 "backend": backend}
     sh = _fork_index(pool_key).shards[shard_i]
     cache = _FORK_CACHES.setdefault((pool_key, shard_i), {})
-    return run_shard_task(sh, task, backend=backend, optimize=optimize,
-                          cache=cache)
+    return run_on_shard(sh, task, backend, optimize, cache)
 
 
 class ShardProcessPool:
@@ -868,17 +715,12 @@ class ShardProcessPool:
 
     def run_shards(self, task, shard_ids: Sequence[int],
                    backend: str = "auto", optimize: bool = True) -> List:
-        """Run one statement task over the given shards in the workers.
-
-        ``task`` is a ``("expr", e)`` / ``("count", e)`` / ``("gcount",
-        col, e)`` / ``("agg", measure, e)`` / ``("gagg", measure, cols,
-        e)`` / ``("gtop", col, e, m, measure)`` / ``("gvals", col, e, ids,
-        measure)`` tuple (see ``_forked_run``); a bare expression/plan is
-        accepted for backward compatibility and treated as ``("expr", e)``.
-        """
+        """Run one statement task (``repro.core.statement``) over the
+        given shards in the workers; ``("probe",)`` reports each worker
+        instead.  A bare expression or plan is accepted for backward
+        compatibility and treated as ``("expr", e)``."""
         if not (isinstance(task, tuple) and task
-                and task[0] in ("expr", "count", "gcount", "agg", "gagg",
-                                "gtop", "gvals", "probe")):
+                and (task[0] in TASK_ARGS or task[0] == "probe")):
             task = ("expr", task)
         args = [(self._key, i, task, backend, optimize) for i in shard_ids]
         # a concurrent generation bump can shut this executor down between

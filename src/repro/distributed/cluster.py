@@ -1,11 +1,11 @@
 """Fault-tolerant scatter/gather coordinator over RPC shard workers.
 
-``ClusterService`` is the cluster-sized sibling of
-``repro.serve.query_api.QueryService``: the same statements (count /
+``ClusterService`` is the cluster-sized sibling of ``QueryService``
+(``serve/query_api.py``): the same statements (count /
 group-by / top-k / sum/avg/min/max / grouped measure aggregates / row
 queries, plus the SQL-ish front door), the same wire expressions, the same
 HTTP front end (``make_server`` accepts either service) — but execution
-fans out over TCP to ``repro.serve.worker_api`` workers, each mmap-serving
+fans out over TCP to shard workers (``serve/worker_api.py``), each serving
 a subset of the shard store files.  Aggregates are the ideal first
 distributed workload: a shard's contribution is an integer, a small count
 vector, or a ``(sum, count, min, max)`` measure partial (the same
@@ -24,7 +24,10 @@ Every fan-out runs under a **robustness policy** (``Policy``):
   adaptive latency percentile (``hedge_pctl`` over a rolling window,
   ``hedge_after_s`` until the window fills), the same task is speculatively
   sent to a backup replica and the first answer wins.  Tail latency from a
-  slow worker costs one duplicate RPC instead of a deadline.
+  slow worker costs one duplicate RPC instead of a deadline.  Hedges stay
+  within a budget (``HEDGE_BUDGET``, 5% of tasks): a statement much heavier
+  than the window's recent ones would otherwise hedge every shard task and
+  double the workers' load at the moment they are slowest.
 * **health probes + eviction + re-placement** — a monitor probes workers;
   ``fail_threshold`` consecutive failures evict a worker, and its shards
   are re-assigned to healthy peers (an ``assign`` op — the peer mmap-opens
@@ -58,7 +61,6 @@ Run a coordinator over already-running workers::
 from __future__ import annotations
 
 import argparse
-import os
 import queue
 import random
 import socket
@@ -70,12 +72,19 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
-from repro.core import store as index_store
-from repro.core.ewah import EWAH
-from repro.core.expr import Expr, canonical_key, to_wire
+from repro.core import statement
+from repro.core.expr import Expr, from_wire
 from repro.core.lru import LRUCache, payload_kind, payload_nbytes
 from repro.core.shard import ShardedIndex
 from . import wire
+
+
+# hedged requests stay under this share of shard tasks (plus one): the
+# extra load "The Tail at Scale" (Dean, Barroso, CACM 2013) bounds hedging
+# to by waiting for the 95th-percentile latency, enforced here because a
+# statement far heavier than the window's recent ones outlasts that
+# percentile on every shard
+HEDGE_BUDGET = 0.05
 
 
 @dataclass
@@ -190,7 +199,7 @@ class ClusterService:
 
     Statement-compatible with ``QueryService`` (``count`` / ``group_count``
     / ``top_k`` / ``query`` / ``query_batch`` / ``statement`` / ``stats``),
-    so ``repro.serve.query_api.make_server`` mounts it unchanged.  The
+    so the HTTP front end's ``make_server`` mounts it unchanged.  The
     coordinator holds only *metadata* of the index (a zero-copy mmap open:
     shard offsets, cardinalities, column names — no bitmap word is ever
     read locally); all bitmap work happens on the workers.
@@ -454,15 +463,18 @@ class ClusterService:
             try:
                 w, hedged, res, exc, dt = out_q.get(timeout=wait)
             except queue.Empty:
-                if not backup_launched:
+                if backup_launched:
+                    break  # deadline
+                if self._take_hedge():
                     # primary silent past the latency percentile: hedge
                     launch(backup, True)
                     pending += 1
                     backup_launched = True
-                    with self._lock:
-                        self._counters["hedges"] += 1
-                    continue
-                break  # deadline
+                else:
+                    # over budget: the primary alone, up to the deadline
+                    # (the backup still takes over if it fails)
+                    hedge_delay = float("inf")
+                continue
             pending -= 1
             if exc is None:
                 self._record_latency(dt)
@@ -481,6 +493,16 @@ class ClusterService:
                 with self._lock:
                     self._counters["failovers"] += 1
         return None
+
+    def _take_hedge(self) -> bool:
+        """Count one more hedge if it fits the budget: ``1 + HEDGE_BUDGET
+        x tasks`` in all."""
+        with self._lock:
+            c = self._counters
+            if c["hedges"] >= 1 + HEDGE_BUDGET * c["tasks"]:
+                return False
+            c["hedges"] += 1
+            return True
 
     def _shard_task(self, sid: int, obj: Dict, extract: Callable):
         """Full robustness policy for one shard: deadline, hedged replica
@@ -509,51 +531,17 @@ class ClusterService:
         return None
 
     # -- scatter/gather ------------------------------------------------------
-    def _scatter(self, op: str, e: Optional[Expr], col: Optional[int] = None,
-                 measure: Optional[str] = None,
-                 cols: Optional[Tuple[int, ...]] = None
-                 ) -> Tuple[Dict[int, object], List[int]]:
-        w = to_wire(e) if e is not None else None
-
-        def mk(sid: int) -> Dict:
-            obj = {"op": op, "shards": [sid]}
-            if w is not None:
-                obj["where"] = w
-            if col is not None:
-                obj["col"] = col
-            if op in ("agg", "gagg"):
-                obj["measure"] = measure
-            if cols is not None:
-                obj["cols"] = list(cols)
-            return obj
+    def _scatter(self, task) -> Tuple[Dict[int, object], List[int]]:
+        """One statement task to every shard under the robustness policy:
+        ``({shard: decoded partial or None}, missing shards)``."""
+        req = statement.encode_task(task)
 
         def extract(sid: int) -> Callable:
-            if op == "count":
-                return lambda out, arrs: int(out["counts"][str(sid)])
-            if op == "gcount":
-                return lambda out, arrs: np.asarray(arrs[f"g{sid}"],
-                                                    dtype=np.int64)
-            if op == "agg":
-                # the scalar (sum, count, min, max) partial, JSON-shipped
-                return lambda out, arrs: tuple(out["aggs"][str(sid)])
-            if op == "gagg":
-                def ex(out, arrs):
-                    part = {"cols": tuple(out["cols"]),
-                            "shape": tuple(out["shapes"][str(sid)]),
-                            "measure": out["measure"],
-                            "dtype": out["dtype"],
-                            "counts": np.asarray(arrs[f"gc{sid}"],
-                                                 dtype=np.int64)}
-                    if out["measure"] is not None:
-                        part["sums"] = np.asarray(arrs[f"gs{sid}"])
-                        part["mins"] = np.asarray(arrs[f"gm{sid}"])
-                        part["maxs"] = np.asarray(arrs[f"gx{sid}"])
-                    return part
-                return ex
-            return lambda out, arrs: (
-                np.asarray(arrs[f"w{sid}"]), int(out["n_bits"][str(sid)]))
+            return lambda out, arrs: statement.decode_partial(
+                out["parts"][str(sid)], arrs)
 
-        futs = {sid: self._pool.submit(self._shard_task, sid, mk(sid),
+        futs = {sid: self._pool.submit(self._shard_task, sid,
+                                       {**req, "shards": [sid]},
                                        extract(sid))
                 for sid in range(self.n_shards)}
         results = {sid: f.result() for sid, f in futs.items()}
@@ -568,76 +556,45 @@ class ClusterService:
         return int(self.meta.n_rows - sum(int(rows[s]) for s in missing))
 
     # -- statements (QueryService-compatible) --------------------------------
-    def _snapshot_key(self, kind: str, col, e: Optional[Expr]) -> tuple:
-        return (self._generation, self.backend, kind, col,
-                canonical_key(e) if e is not None else None)
+    def _key(self, task) -> tuple:
+        return (self._generation, self.backend) + statement.task_key(task)
+
+    def _statement(self, st: Dict) -> Dict:
+        """One statement descriptor: the result cache (filled only by exact
+        answers), else scatter, merge and answer, with the coverage of a
+        degraded one."""
+        if st["measure"] is not None:
+            self._check_measure(st["measure"])
+        task = statement.task_of(st, self.meta.resolve_column)
+        key = self._key(task)
+        merged = self.cache.get(key)
+        missing: List[int] = []
+        cached = merged is not None
+        if not cached:
+            results, missing = self._scatter(task)
+            merged = statement.merge(task, list(results.values()),
+                                     self.meta)
+            if not missing:
+                self.cache.put(key, merged)
+        return statement.answer(st, merged, cached, exact=not missing,
+                                missing_shards=missing,
+                                covered_rows=self._coverage(missing))
+
+    def _ask(self, kind: str, where, **fields) -> Dict:
+        return self._statement(statement.make(kind, self._as_expr(where),
+                                              **fields))
 
     def count(self, where=None) -> Dict:
-        e = self._as_expr(where)
-        key = self._snapshot_key("count", None, e)
-        hit = self.cache.get(key)
-        if hit is not None:
-            return {"select": "count", "count": int(hit), "exact": True,
-                    "missing_shards": [], "covered_rows": self.meta.n_rows,
-                    "cached": True}
-        results, missing = self._scatter("count", e)
-        total = sum(int(v) for v in results.values() if v is not None)
-        if not missing:
-            self.cache.put(key, total)
-        return {"select": "count", "count": total, "exact": not missing,
-                "missing_shards": missing,
-                "covered_rows": self._coverage(missing), "cached": False}
+        return self._ask("count", where)
 
     def group_count(self, col, where=None) -> Dict:
-        e = self._as_expr(where)
-        c = self.meta.resolve_column(col)
-        key = self._snapshot_key("gcount", c, e)
-        hit = self.cache.get(key)
-        if hit is not None:
-            return {"select": "group_count", "col": col,
-                    "counts": [int(x) for x in hit], "exact": True,
-                    "missing_shards": [], "covered_rows": self.meta.n_rows,
-                    "cached": True}
-        results, missing = self._scatter("gcount", e, col=c)
-        out = np.zeros(self.meta.card(c), dtype=np.int64)
-        for v in results.values():
-            if v is not None:
-                out += v
-        if not missing:
-            self.cache.put(key, out)
-        return {"select": "group_count", "col": col,
-                "counts": [int(x) for x in out], "exact": not missing,
-                "missing_shards": missing,
-                "covered_rows": self._coverage(missing), "cached": False}
+        return self._ask("group_count", where, col=col)
 
     def top_k(self, col, k: int, where=None, measure=None) -> Dict:
-        from repro.core.dataset import top_k_from_counts, top_k_from_values
-        if measure is None:
-            out = self.group_count(col, where)
-            top = top_k_from_counts(np.asarray(out["counts"]), int(k))
-            return {"select": "top_k", "col": col, "k": int(k),
-                    "measure": None,
-                    "top": [[v, c] for v, c in top], "exact": out["exact"],
-                    "missing_shards": out["missing_shards"],
-                    "covered_rows": out["covered_rows"],
-                    "cached": out["cached"]}
-        # rank by SUM(measure): gather per-shard grouped-sum partials and
-        # merge — each partial is one card(col)-long vector, so the wire
-        # cost matches group_count, not a TPUT round trip per shard
-        from repro.core import measures as measures_mod
-        self._check_measure(measure)
-        e = self._as_expr(where)
-        c = self.meta.resolve_column(col)
-        agg, missing, cached = self._group_agg_raw(measure, (c,), e)
-        vals = measures_mod.finalize_group("sum", agg)
-        top = top_k_from_values(np.asarray(vals),
-                                np.asarray(agg["counts"]), int(k))
-        return {"select": "top_k", "col": col, "k": int(k),
-                "measure": measure,
-                "top": [[int(r), (int(v) if isinstance(v, (int, np.integer))
-                                  else float(v))] for r, v in top],
-                "exact": not missing, "missing_shards": missing,
-                "covered_rows": self._coverage(missing), "cached": cached}
+        """Top-k by count, or by SUM(measure): each shard ships its whole
+        grouped partial (one ``card(col)``-long vector, the wire cost of a
+        group count), merged here — no TPUT round trip per shard."""
+        return self._ask("top_k", where, col=col, k=int(k), measure=measure)
 
     # -- measure statements (compressed-domain OLAP) -------------------------
     def _check_measure(self, name) -> None:
@@ -649,77 +606,15 @@ class ClusterService:
     def agg(self, op: str, measure: str, where=None) -> Dict:
         """Scalar sum/avg/min/max of a measure: each worker ships one
         ``(sum, count, min, max)`` partial per shard, merged here."""
-        from repro.core import measures as measures_mod
-        self._check_measure(measure)
-        e = self._as_expr(where)
-        key = self._snapshot_key(f"agg:{measure}", None, e)
-        agg = self.cache.get(key)
-        missing: List[int] = []
-        cached = agg is not None
-        if agg is None:
-            results, missing = self._scatter("agg", e, measure=measure)
-            parts = [v for v in results.values() if v is not None]
-            agg = measures_mod.merge_scalar_aggs(parts)
-            if not missing:
-                self.cache.put(key, agg)
-        val = measures_mod.finalize_scalar(op, agg)
-        return {"select": op, "measure": measure, "value": val,
-                "count": int(agg[1]), "exact": not missing,
-                "missing_shards": missing,
-                "covered_rows": self._coverage(missing), "cached": cached}
-
-    def _group_agg_raw(self, measure: Optional[str],
-                       cs: Tuple[int, ...], e: Optional[Expr]):
-        """Scatter the grouped aggregate, merge the per-shard partial
-        matrices.  Returns ``(merged_partial, missing, cached)``; partial
-        results (missing shards skipped in the merge) are never cached."""
-        from repro.core import measures as measures_mod
-        key = self._snapshot_key(f"gagg:{measure}", cs, e)
-        hit = self.cache.get(key)
-        if hit is not None:
-            return hit, [], True
-        results, missing = self._scatter("gagg", e, measure=measure,
-                                         cols=cs)
-        parts = [v for v in results.values() if v is not None]
-        if parts:
-            agg = measures_mod.merge_group_aggs(parts)
-        else:
-            shape = tuple(self.meta.card(c) for c in cs)
-            dt = None
-            if measure is not None:
-                arr = self.meta.shards[0].measure(measure)
-                dt = measures_mod.measure_dtype_str(arr)
-            agg = measures_mod.empty_group_agg(cs, shape, measure, dt)
-        if not missing:
-            self.cache.put(key, agg)
-        return agg, missing, False
+        return self._ask("agg", where, op=op, measure=measure)
 
     def group_agg(self, op: str, measure: Optional[str], by,
                   where=None) -> Dict:
         """Grouped sum/avg/min/max (or multi-column count when ``measure``
         is None) over one or more columns: the dense row-major cube
         ``[card(c0), ..., card(cn-1)]`` as nested lists."""
-        from repro.core import measures as measures_mod
-        if measure is not None:
-            self._check_measure(measure)
-        e = self._as_expr(where)
-        cs = tuple(self.meta.resolve_column(c) for c in by)
-        agg, missing, cached = self._group_agg_raw(measure, cs, e)
-        shape = list(agg["shape"])
-
-        def nest(flat):
-            return np.asarray(flat).reshape(shape).tolist()
-
-        out = {"select": "group_agg", "op": op, "measure": measure,
-               "by": list(by), "shape": shape,
-               "counts": nest(agg["counts"]), "exact": not missing,
-               "missing_shards": missing,
-               "covered_rows": self._coverage(missing), "cached": cached}
-        if op != "count":
-            from repro.serve.query_api import nan_to_none
-            out["values"] = nan_to_none(
-                nest(measures_mod.finalize_group(op, agg)))
-        return out
+        return self._ask("group_agg", where, op=op, measure=measure,
+                         by=list(by))
 
     def query(self, expr, explain_plan: bool = False) -> Dict:
         """Row query: per-shard EWAH results gathered and offset into
@@ -728,24 +623,18 @@ class ClusterService:
         e = self._as_expr(expr)
         if e is None:
             raise ValueError("query needs an expression")
-        key = self._snapshot_key("rows", None, e)
-        hit = self.cache.get(key)
+        task = ("expr", e)
+        hit = self.cache.get(self._key(task))
         if hit is not None:
             return self._rows_result(hit, [], cached=True)
-        results, missing = self._scatter("execute", e)
+        results, missing = self._scatter(task)
         offsets = self.meta.offsets
-        parts = []
-        for sid in range(self.n_shards):
-            v = results.get(sid)
-            if v is None:
-                continue
-            words, n_bits = v
-            bits = EWAH(np.ascontiguousarray(words), n_bits).set_bits()
-            parts.append(bits.astype(np.int64) + int(offsets[sid]))
+        parts = [bm.set_bits().astype(np.int64) + int(offsets[sid])
+                 for sid, bm in results.items() if bm is not None]
         rows = np.concatenate(parts) if parts \
             else np.empty(0, dtype=np.int64)
         if not missing:
-            self.cache.put(key, rows)
+            self.cache.put(self._key(task), rows)
         return self._rows_result(rows, missing, cached=False)
 
     def _rows_result(self, rows: np.ndarray, missing: List[int],
@@ -764,29 +653,16 @@ class ClusterService:
         return [self.query(e) for e in exprs]
 
     def statement(self, obj: Dict) -> Dict:
-        from repro.serve.query_api import parse_statement
-        st = parse_statement(obj)
-        kind, e = st["kind"], st["where"]
-        if kind == "count":
-            return self.count(e)
-        if kind == "group_count":
-            return self.group_count(st["col"], e)
-        if kind == "agg":
-            return self.agg(st["op"], st["measure"], e)
-        if kind == "group_agg":
-            return self.group_agg(st["op"], st["measure"], st["by"], e)
-        return self.top_k(st["col"], st["k"], e, measure=st["measure"])
+        return self._statement(statement.parse_statement(obj))
 
     def sql(self, text: str) -> Dict:
-        """Execute one SQL-ish statement (see ``query_api.parse_sql``)."""
-        from repro.serve.query_api import parse_sql
-        return self.statement(parse_sql(text))
+        """Execute one SQL-ish statement (see ``statement.parse_sql``)."""
+        return self.statement(statement.parse_sql(text))
 
     @staticmethod
     def _as_expr(where) -> Optional[Expr]:
         if where is None or isinstance(where, Expr):
             return where
-        from repro.core.expr import from_wire
         return from_wire(where)
 
     # -- ops surface (HTTP admin endpoints) ----------------------------------

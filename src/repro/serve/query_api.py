@@ -37,7 +37,9 @@ Production-shaped serving on a dependency-free stack (stdlib ``http.server``
   compressed domain* (memoized popcounts + interval intersection; sharded
   indexes merge per-shard partial counts at the coordinator, never a global
   result bitmap) and are cached like row queries, keyed by the statement
-  kind plus the filter's canonical key.
+  kind plus the filter's canonical key.  What a statement is — its
+  grammar, its per-shard run, the merge and the JSON answer — lives in
+  ``repro.core.statement``; this module is its HTTP front end.
 * **Live ingest** — ``/ingest`` and ``/delete`` mutate the served dataset
   through the WAL-backed LSM layer (``repro.core.ingest.LiveIndex``):
   appends land in an in-memory delta index, deletes in compressed per-shard
@@ -113,313 +115,17 @@ import numpy as np
 
 from repro.core import BitmapIndex, ShardedIndex, lex_sort, synth
 from repro.core import cost_model
-from repro.core import measures as measures_mod
+from repro.core import statement as stmt
 from repro.core import store as index_store
 from repro.core import trace
-from repro.core.dataset import top_k_from_counts, top_k_from_values
-from repro.core.expr import Expr, canonical_key, from_wire, to_wire
-from repro.core.executor import (execute, execute_agg, execute_count,
-                                 execute_group_agg, execute_group_count)
+from repro.core.expr import Expr
 from repro.core.lru import LRUCache, payload_kind, payload_nbytes
 from repro.core.planner import explain, plan
+# the statement grammar lives in repro.core.statement; re-exported here
+from repro.core.statement import (  # noqa: F401
+    expr_to_json, nan_to_none, parse_expr, parse_sql, parse_statement)
 
 DEFAULT_CACHE_BYTES = 64 << 20  # total EWAH payload budget for the result LRU
-
-
-def parse_expr(obj: Dict) -> Expr:
-    """JSON wire format -> Expr tree (raises ValueError on malformed input).
-
-    Alias of ``repro.core.expr.from_wire`` — one wire codec shared by the
-    HTTP layer and the write-ahead log's delete frames."""
-    return from_wire(obj)
-
-
-def expr_to_json(e: Expr) -> Dict:
-    """Inverse of ``parse_expr`` (alias of ``repro.core.expr.to_wire``)."""
-    return to_wire(e)
-
-
-_AGG_OPS = ("sum", "avg", "min", "max")
-
-
-def parse_statement(obj: Dict) -> Dict:
-    """``{"select": ..., "where": ..., "limit": ...}`` -> statement
-    descriptor.
-
-    Returns a dict with keys ``kind`` (``"count"`` / ``"group_count"`` /
-    ``"agg"`` / ``"group_agg"`` / ``"top_k"``), ``op`` (``sum`` / ``avg``
-    / ``min`` / ``max`` / ``count`` for measure statements), ``measure``,
-    ``col``, ``by`` (grouping column list), ``k`` and ``where`` (parsed
-    ``Expr``) — None where not applicable.  A top-level ``limit`` rewrites
-    a single-column count/sum group-by into the equivalent top-k (the
-    shard-prunable ranking ops).  Raises ValueError on malformed
-    statements (mapped to HTTP 400).
-    """
-    sel = obj.get("select")
-    if not isinstance(sel, dict):
-        raise ValueError(
-            f"'select' must be an object naming one of count / group_count "
-            f"/ top_k / sum / avg / min / max: {sel!r}")
-    where = obj.get("where")
-    e = parse_expr(where) if where is not None else None
-    by = sel.get("by")
-    keys = [k for k in sel if k != "by"]
-    if len(keys) != 1:
-        raise ValueError(
-            f"'select' must name exactly one of count / group_count / "
-            f"top_k / sum / avg / min / max (plus an optional 'by'): "
-            f"{sel!r}")
-    kind, arg = keys[0], sel[keys[0]]
-    if by is not None:
-        if isinstance(by, (str, int)) and not isinstance(by, bool):
-            by = [by]
-        if (not isinstance(by, list) or not by
-                or any(isinstance(c, bool) or not isinstance(c, (str, int))
-                       for c in by)):
-            raise ValueError(
-                f"'by' must list one or more grouping columns, got {by!r}")
-    out = {"kind": None, "op": None, "measure": None, "col": None,
-           "by": None, "k": None, "where": e}
-    if kind == "count":
-        if arg is not True:
-            raise ValueError('use {"count": true}')
-        if by is None:
-            out["kind"] = "count"
-        else:
-            out.update(kind="group_agg", op="count", by=by)
-    elif kind in _AGG_OPS:
-        if not isinstance(arg, str) or not arg:
-            raise ValueError(f"{kind} needs a measure name, got {arg!r}")
-        out.update(op=kind, measure=arg)
-        if by is None:
-            out["kind"] = "agg"
-        else:
-            out.update(kind="group_agg", by=by)
-    elif by is not None:
-        raise ValueError(f"'by' does not combine with {kind!r}")
-    elif kind == "group_count":
-        _check_col(arg, "group_count")
-        out.update(kind="group_count", col=arg)
-    elif kind == "top_k":
-        if not (isinstance(arg, dict) and "col" in arg and "k" in arg):
-            raise ValueError(
-                f'top_k needs {{"col": ..., "k": ...}}, got {arg!r}')
-        _check_col(arg["col"], "top_k")
-        m = arg.get("measure")
-        if m is not None and (not isinstance(m, str) or not m):
-            raise ValueError(f"top_k 'measure' must be a name, got {m!r}")
-        out.update(kind="top_k", col=arg["col"], k=int(arg["k"]), measure=m)
-    else:
-        raise ValueError(f"unknown select {kind!r}")
-    return _apply_limit(out, obj.get("limit"))
-
-
-def _apply_limit(st: Dict, limit) -> Dict:
-    """Rewrite ``limit`` on a single-column group statement into the
-    equivalent top-k (count and sum rankings — the ops shard pruning can
-    bound; an avg/min/max ranking has no monotone partial)."""
-    if limit is None:
-        return st
-    if isinstance(limit, bool) or not isinstance(limit, int) or limit < 1:
-        raise ValueError(f"'limit' must be a positive integer, got {limit!r}")
-    if st["kind"] == "group_count":
-        return {**st, "kind": "top_k", "k": int(limit), "measure": None}
-    if (st["kind"] == "group_agg" and st["by"] is not None
-            and len(st["by"]) == 1 and st["op"] in ("count", "sum")):
-        return {**st, "kind": "top_k", "col": st["by"][0], "by": None,
-                "k": int(limit), "measure": st["measure"]}
-    if st["kind"] == "top_k":
-        return {**st, "k": min(st["k"], int(limit))}
-    raise ValueError(
-        "'limit' ranks a single-column count or sum group-by (top-k); it "
-        "cannot truncate a scalar, a multi-column cube, or an avg/min/max "
-        "ranking")
-
-
-def _check_col(arg, kind: str) -> None:
-    # bool is a subclass of int: {"group_count": true} (a typo'd copy of
-    # the count shape) must be a 400, not a query against column 1
-    if isinstance(arg, bool) or not isinstance(arg, (str, int)):
-        raise ValueError(f"{kind} needs a column name or position, "
-                         f"got {arg!r}")
-
-
-def nan_to_none(x):
-    """Recursively replace NaN (empty avg/min/max cells) with None so
-    grouped results serialize as strict JSON ``null``."""
-    if isinstance(x, list):
-        return [nan_to_none(v) for v in x]
-    if isinstance(x, float) and x != x:
-        return None
-    return x
-
-
-# -- SQL-ish front door ------------------------------------------------------
-
-def _sql_tokens(sql: str) -> List[tuple]:
-    import re
-    out: List[tuple] = []
-    i, n = 0, len(sql)
-    while i < n:
-        ch = sql[i]
-        if ch.isspace():
-            i += 1
-            continue
-        if ch in "(),=*":
-            out.append((ch, ch))
-            i += 1
-            continue
-        m = re.match(r"[A-Za-z_][A-Za-z0-9_]*", sql[i:])
-        if m:
-            out.append(("ident", m.group(0)))
-            i += len(m.group(0))
-            continue
-        m = re.match(r"-?\d+", sql[i:])
-        if m:
-            out.append(("int", int(m.group(0))))
-            i += len(m.group(0))
-            continue
-        raise ValueError(f"SQL: unexpected character {ch!r} at offset {i}")
-    out.append(("end", None))
-    return out
-
-
-class _SqlParser:
-    """Recursive-descent parser for the SQL-ish statement subset::
-
-        SELECT count(*) | sum(m) | avg(m) | min(m) | max(m)
-        FROM <table>                      -- single-table engine: name ignored
-        [WHERE <pred>]                    -- =, IN (...), BETWEEN a AND b,
-                                          --   AND / OR / NOT, parentheses
-        [GROUP BY a[, b, ...]]
-        [LIMIT k]
-
-    Values are integer *ranks* (the dictionary-encoded domain the bitmap
-    index stores).  Produces the JSON statement object ``parse_statement``
-    accepts, so SQL and JSON front doors share one semantics."""
-
-    def __init__(self, sql: str):
-        self.toks = _sql_tokens(sql)
-        self.pos = 0
-
-    def peek(self) -> tuple:
-        return self.toks[self.pos]
-
-    def next(self) -> tuple:
-        t = self.toks[self.pos]
-        self.pos += 1
-        return t
-
-    def at_kw(self, word: str) -> bool:
-        t, v = self.peek()
-        return t == "ident" and v.upper() == word
-
-    def expect_kw(self, word: str) -> None:
-        if not self.at_kw(word):
-            raise ValueError(f"SQL: expected {word}, got {self.peek()[1]!r}")
-        self.next()
-
-    def expect(self, typ: str):
-        t, v = self.next()
-        if t != typ:
-            raise ValueError(f"SQL: expected {typ!r}, got {v!r}")
-        return v
-
-    # predicate grammar: OR < AND < NOT < primary
-    def pred_or(self) -> Dict:
-        args = [self.pred_and()]
-        while self.at_kw("OR"):
-            self.next()
-            args.append(self.pred_and())
-        return args[0] if len(args) == 1 else {"op": "or", "args": args}
-
-    def pred_and(self) -> Dict:
-        args = [self.pred_not()]
-        while self.at_kw("AND"):
-            self.next()
-            args.append(self.pred_not())
-        return args[0] if len(args) == 1 else {"op": "and", "args": args}
-
-    def pred_not(self) -> Dict:
-        if self.at_kw("NOT"):
-            self.next()
-            return {"op": "not", "arg": self.pred_not()}
-        return self.primary()
-
-    def primary(self) -> Dict:
-        t, v = self.peek()
-        if t == "(":
-            self.next()
-            e = self.pred_or()
-            self.expect(")")
-            return e
-        if t != "ident":
-            raise ValueError(f"SQL: expected a column name, got {v!r}")
-        self.next()
-        col = v
-        t2, v2 = self.next()
-        if t2 == "=":
-            return {"op": "eq", "col": col, "value": self.expect("int")}
-        if t2 == "ident" and v2.upper() == "IN":
-            self.expect("(")
-            vals = [self.expect("int")]
-            while self.peek()[0] == ",":
-                self.next()
-                vals.append(self.expect("int"))
-            self.expect(")")
-            return {"op": "in", "col": col, "values": vals}
-        if t2 == "ident" and v2.upper() == "BETWEEN":
-            lo = self.expect("int")
-            self.expect_kw("AND")
-            hi = self.expect("int")
-            return {"op": "range", "col": col, "lo": lo, "hi": hi}
-        raise ValueError(f"SQL: expected =, IN or BETWEEN after "
-                         f"{col!r}, got {v2!r}")
-
-    def parse(self) -> Dict:
-        self.expect_kw("SELECT")
-        t, fn = self.next()
-        if t != "ident" or fn.upper() not in ("COUNT", "SUM", "AVG",
-                                              "MIN", "MAX"):
-            raise ValueError(f"SQL: expected count(*)/sum(m)/avg(m)/min(m)"
-                             f"/max(m), got {fn!r}")
-        fn = fn.upper()
-        self.expect("(")
-        if fn == "COUNT":
-            self.expect("*")
-            sel: Dict = {"count": True}
-        else:
-            sel = {fn.lower(): self.expect("ident")}
-        self.expect(")")
-        self.expect_kw("FROM")
-        self.expect("ident")  # table name: single-table engine, ignored
-        out: Dict = {}
-        if self.at_kw("WHERE"):
-            self.next()
-            out["where"] = self.pred_or()
-        if self.at_kw("GROUP"):
-            self.next()
-            self.expect_kw("BY")
-            by = [self.expect("ident")]
-            while self.peek()[0] == ",":
-                self.next()
-                by.append(self.expect("ident"))
-            sel["by"] = by
-        if self.at_kw("LIMIT"):
-            self.next()
-            out["limit"] = self.expect("int")
-        if self.peek()[0] != "end":
-            raise ValueError(f"SQL: trailing input at {self.peek()[1]!r}")
-        out["select"] = sel
-        return out
-
-
-def parse_sql(sql: str) -> Dict:
-    """SQL-ish text -> the JSON statement object ``parse_statement``
-    accepts (and ``POST /query`` executes).  See ``_SqlParser``."""
-    if not isinstance(sql, str) or not sql.strip():
-        raise ValueError("'sql' must be a non-empty statement string")
-    return _SqlParser(sql).parse()
 
 
 class QueryService:
@@ -837,21 +543,32 @@ class QueryService:
         gen = self._generation
         return gen, self.index
 
-    def _execute_cached(self, e: Expr, op_cache: Optional[Dict],
-                        snapshot=None):
+    def _run_cached(self, task, snapshot=None, op_cache=None,
+                    top_k: Optional[int] = None):
+        """``(merged result, cached)`` of one statement task
+        (``repro.core.statement``), through the result cache.
+
+        The key holds the generation, the index's own mutation generation
+        (a live index: every append/delete/compaction retires all cached
+        results without a cache clear), the backend and the task's key —
+        its kind, resolved columns and the filter's canonical key.  Both
+        generations are read before executing.  ``top_k`` ranks a grouped
+        sum on a ``ShardedIndex`` by the shard-pruned two-phase protocol
+        instead of the full merge."""
         gen, idx = snapshot if snapshot is not None else self._snapshot()
-        # a live index's own mutation generation joins the key (read before
-        # executing, like ``gen``): every append/delete/compaction retires
-        # all cached results without a cache clear
-        key = (gen, getattr(idx, "generation", None), self.backend,
-               canonical_key(e))
-        bm = self.cache.get(key)
-        if bm is not None:
-            return bm, True
+        key = (gen, getattr(idx, "generation", None), self.backend) \
+            + stmt.task_key(task) + (() if top_k is None else ("tput", top_k))
+        val = self.cache.get(key)
+        if val is not None:
+            return val, True
         pool = None if isinstance(idx, BitmapIndex) else self._shard_pool
-        bm = execute(idx, e, backend=self.backend, cache=op_cache, pool=pool)
-        self.cache.put(key, bm)
-        return bm, False
+        if top_k is None:
+            val = stmt.run(idx, task, self.backend, cache=op_cache, pool=pool)
+        else:
+            val = idx.top_k(task[2][0], top_k, task[-1], measure=task[1],
+                            backend=self.backend, pool=pool)
+        self.cache.put(key, val)
+        return val, False
 
     def _result(self, bm, cached: bool) -> Dict:
         rows = bm.set_bits()  # pad bits already masked, so len == popcount
@@ -865,7 +582,7 @@ class QueryService:
 
     def _query_one(self, e: Expr, explain_plan: bool = False,
                    op_cache: Optional[Dict] = None, snapshot=None) -> Dict:
-        bm, cached = self._execute_cached(e, op_cache, snapshot)
+        bm, cached = self._run_cached(("expr", e), snapshot, op_cache)
         out = self._result(bm, cached)
         if explain_plan:
             out["plan"] = self.explain(e)
@@ -918,133 +635,44 @@ class QueryService:
             return [f.result() for f in futs]
 
     # -- aggregation statements (compressed domain) -------------------------
-    def _agg_cached(self, kind: str, col, e: Optional[Expr], compute):
-        """Cache wrapper shared by the aggregate statements: keyed by the
-        statement kind + resolved column + the filter's canonical key (and
-        the index generation, like row results).
-
-        The column resolves against the *snapshotted* index — resolving
-        against ``self.index`` outside the snapshot would let a concurrent
-        ``set_index`` cache another column's counts under a live key.
-        ``col`` may also be a list of grouping columns (group_agg)."""
-        gen, idx = self._snapshot()
-        if isinstance(col, (list, tuple)):
-            c = tuple(idx.resolve_column(x) for x in col)
-        else:
-            c = idx.resolve_column(col) if col is not None else None
-        key = (gen, getattr(idx, "generation", None), self.backend, kind, c,
-               canonical_key(e) if e is not None else None)
-        val = self.cache.get(key)
-        if val is not None:
-            return val, True
-        pool = None if isinstance(idx, BitmapIndex) else self._shard_pool
-        val = compute(idx, pool, c)
-        self.cache.put(key, val)
-        return val, False
-
-    def _count_one(self, e: Optional[Expr]) -> Dict:
-        cnt, cached = self._agg_cached(
-            "count", None, e,
-            lambda idx, pool, _c: execute_count(idx, e, backend=self.backend,
-                                                pool=pool))
-        return {"select": "count", "count": int(cnt), "cached": cached}
-
-    def _group_count_one(self, col, e: Optional[Expr]) -> Dict:
-        counts, cached = self._agg_cached(
-            "group_count", col, e,
-            lambda idx, pool, c: execute_group_count(
-                idx, c, e, backend=self.backend, pool=pool))
-        return {"select": "group_count", "col": col,
-                "counts": [int(x) for x in counts], "cached": cached}
-
-    def _agg_one(self, op: str, measure: str, e: Optional[Expr]) -> Dict:
-        """Scalar sum/avg/min/max over the measure sidecar, evaluated by
-        slicing mmap'd measure arrays with the filter's intervals."""
-        agg, cached = self._agg_cached(
-            f"agg:{measure}", None, e,
-            lambda idx, pool, _c: execute_agg(
-                idx, measure, e, backend=self.backend, pool=pool))
-        val = measures_mod.finalize_scalar(op, agg)
-        return {"select": op, "measure": measure, "value": val,
-                "count": int(agg[1]), "cached": cached}
-
-    def _group_agg_one(self, op: str, measure: Optional[str], by,
-                       e: Optional[Expr]) -> Dict:
-        """Grouped aggregate over one or more columns; ``measure=None`` is
-        the multi-column count.  Counts and values are the dense row-major
-        cube as nested lists, of shape ``[card(c0), ..., card(cn-1)]``;
-        empty cells count 0 and sum 0, and their avg/min/max serialize as
-        null."""
-        agg, cached = self._agg_cached(
-            f"gagg:{op}:{measure}", list(by), e,
-            lambda idx, pool, cs: execute_group_agg(
-                idx, measure, list(cs), e, backend=self.backend, pool=pool))
-        shape = list(agg["shape"])
-
-        def nest(flat):
-            a = np.asarray(flat).reshape(shape)
-            return a.tolist()
-
-        out = {"select": "group_agg", "op": op, "measure": measure,
-               "by": list(by), "shape": shape,
-               "counts": nest(agg["counts"]), "cached": cached}
-        if op != "count":
-            out["values"] = nan_to_none(
-                nest(measures_mod.finalize_group(op, agg)))
-        return out
-
-    def _top_k_one(self, col, k: int, e: Optional[Expr],
-                   measure: Optional[str] = None) -> Dict:
-        if measure is None:
-            out = self._group_count_one(col, e)
-            top = top_k_from_counts(np.asarray(out["counts"]), k)
-            return {"select": "top_k", "col": col, "k": int(k),
-                    "measure": None, "top": [[v, c] for v, c in top],
-                    "cached": out["cached"]}
-
+    def _statement_one(self, st: Dict) -> Dict:
+        """One statement descriptor, answered.  Its columns resolve against
+        the *snapshotted* index — resolving against ``self.index`` outside
+        the snapshot would let a concurrent ``set_index`` cache another
+        column's counts under a live key."""
+        snapshot = self._snapshot()
+        idx = snapshot[1]
+        task = stmt.task_of(st, idx.resolve_column)
         # rank by SUM(measure): sharded indexes run the shard-pruned
-        # two-phase protocol; monolithic/live fall back to the full
-        # grouped sum (one vector — nothing to prune)
-        def compute(idx, pool, c):
-            if isinstance(idx, ShardedIndex):
-                return idx.top_k(c, k, e, measure=measure,
-                                 backend=self.backend, pool=pool)
-            agg = execute_group_agg(idx, measure, [c], e,
-                                    backend=self.backend, pool=pool)
-            vals = measures_mod.finalize_group("sum", agg)
-            return top_k_from_values(np.asarray(vals),
-                                     np.asarray(agg["counts"]), k)
+        # two-phase protocol; monolithic/live rank the full grouped sum
+        tput = (st["kind"] == "top_k" and st["measure"] is not None
+                and isinstance(idx, ShardedIndex))
+        val, cached = self._run_cached(task, snapshot,
+                                       top_k=int(st["k"]) if tput else None)
+        return stmt.answer(st, val, cached)
 
-        top, cached = self._agg_cached(
-            f"topk:{measure}:{int(k)}", col, e, compute)
-        return {"select": "top_k", "col": col, "k": int(k),
-                "measure": measure,
-                "top": [[int(r), (int(v) if isinstance(v, (int, np.integer))
-                                  else float(v))] for r, v in top],
-                "cached": cached}
+    def _ask(self, kind: str, where, **fields) -> Dict:
+        e = parse_expr(where) if isinstance(where, dict) else where
+        return self._call(self._statement_one, stmt.make(kind, e, **fields))
 
     def count(self, where=None) -> Dict:
-        e = parse_expr(where) if isinstance(where, dict) else where
-        return self._call(self._count_one, e)
+        return self._ask("count", where)
 
     def group_count(self, col, where=None) -> Dict:
-        e = parse_expr(where) if isinstance(where, dict) else where
-        return self._call(self._group_count_one, col, e)
+        return self._ask("group_count", where, col=col)
 
     def top_k(self, col, k: int, where=None, measure=None) -> Dict:
-        e = parse_expr(where) if isinstance(where, dict) else where
-        return self._call(self._top_k_one, col, k, e, measure)
+        return self._ask("top_k", where, col=col, k=int(k), measure=measure)
 
     def agg(self, op: str, measure: str, where=None) -> Dict:
         """Scalar sum/avg/min/max of a measure under an optional filter."""
-        e = parse_expr(where) if isinstance(where, dict) else where
-        return self._call(self._agg_one, op, measure, e)
+        return self._ask("agg", where, op=op, measure=measure)
 
     def group_agg(self, op: str, measure: Optional[str], by,
                   where=None) -> Dict:
         """Grouped sum/avg/min/max/count over one or more columns."""
-        e = parse_expr(where) if isinstance(where, dict) else where
-        return self._call(self._group_agg_one, op, measure, list(by), e)
+        return self._ask("group_agg", where, op=op, measure=measure,
+                         by=list(by))
 
     def sql(self, text: str) -> Dict:
         """Execute one SQL-ish statement (see ``parse_sql``)."""
@@ -1055,18 +683,7 @@ class QueryService:
         with trace.statement():
             with trace.span("service.parse"):
                 st = parse_statement(obj)
-            kind, e = st["kind"], st["where"]
-            if kind == "count":
-                return self._call(self._count_one, e)
-            if kind == "group_count":
-                return self._call(self._group_count_one, st["col"], e)
-            if kind == "agg":
-                return self._call(self._agg_one, st["op"], st["measure"], e)
-            if kind == "group_agg":
-                return self._call(self._group_agg_one, st["op"],
-                                  st["measure"], st["by"], e)
-            return self._call(self._top_k_one, st["col"], st["k"], e,
-                              st["measure"])
+            return self._call(self._statement_one, st)
 
     def stats(self) -> Dict:
         from repro.core.ingest import LiveIndex

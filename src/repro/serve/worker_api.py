@@ -6,26 +6,18 @@ The scatter/gather tier's data plane.  A ``ShardWorker`` mmap-opens an
 single-process service warm-starts from — and serves shard statement tasks
 over the length-prefixed, CRC-framed wire protocol
 (``repro.distributed.wire``).  Execution goes through
-``repro.core.shard.run_shard_task``, the *same* per-shard path the
-in-process fan-out uses, so a worker's partial counts, count vectors and
-EWAH slices are bit-identical to what the mono ``ShardedIndex`` would have
-computed for that shard.
+``repro.core.statement.run_on_shard``, the *same* per-shard path the
+in-process fan-out uses, so a worker's partials are bit-identical to what
+the mono ``ShardedIndex`` would have computed for that shard.
 
 Operations (request ``{"op": ...}``, one response frame per request):
 
-* ``count``    — ``{"shards": [...], "where": wire-expr|null}`` ->
-  per-shard row counts.
-* ``gcount``   — ``+ {"col": int}`` -> per-shard int64 count vectors
-  (binary section).
-* ``agg``      — ``+ {"measure": str}`` -> per-shard ``[sum, count, min,
-  max]`` scalar measure partials (JSON; ``min``/``max`` null when the
-  shard's filtered slice is empty).
-* ``gagg``     — ``+ {"measure": str|null, "cols": [int, ...]}`` -> per-
-  shard grouped-aggregate partials: a ``gc<i>`` counts array per shard
-  (binary section) plus ``gs<i>``/``gm<i>``/``gx<i>`` sum/min/max arrays
-  when a measure is named, with the group ``shape`` in the JSON object.
-  ``measure=null`` computes multi-column counts only.
-* ``execute``  — per-shard EWAH result words (binary section) + bit widths.
+* a statement task — ``op`` is its kind (``expr``, ``count``, ``gcount``,
+  ``agg``, ``gagg``, ``gtop``, ``gvals``), with ``args``, ``where``
+  (wire-expr or null) and ``shards`` (``statement.encode_task``) ->
+  ``parts``, one ``statement.encode_partial`` per held shard (arrays in
+  the binary section), and ``missing``, the shards this worker does not
+  hold.
 * ``health``   — liveness probe: pid, held shards, generation.
 * ``assign``   — mmap-open additional shards (coordinator re-placement
   after a peer eviction; cheap — metadata-only open).
@@ -39,10 +31,10 @@ Operations (request ``{"op": ...}``, one response frame per request):
   response path (chaos tests and the chaos benchmark drive this remotely).
 * ``stats``    — per-shard cache stats + fault counters.
 
-Faults apply only to data-plane responses (``count``/``gcount``/
-``execute``): admin ops stay reliable so the harness can always steer the
-chaos, and health probes report the truth — a probe failure means the
-worker is actually gone, not that the injector ate the frame.
+Faults apply only to statement responses: admin ops stay reliable so the
+harness can always steer the chaos, and health probes report the truth — a
+probe failure means the worker is actually gone, not that the injector ate
+the frame.
 
 Run standalone::
 
@@ -60,16 +52,13 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.core import store as index_store
-from repro.core.ewah import WORD_DTYPE
-from repro.core.expr import canonical_key, from_wire
 from repro.core.lru import LRUCache, payload_kind, payload_nbytes
-from repro.core.shard import run_shard_task
+from repro.core.statement import (TASK_ARGS, decode_task, encode_partial,
+                                  run_on_shard, task_key)
 from repro.distributed import wire
 
 WORKER_CACHE_ENTRIES = 64
 WORKER_CACHE_BYTES = 16 << 20
-
-_DATA_OPS = ("count", "gcount", "agg", "gagg", "execute")
 
 
 class ShardWorker:
@@ -178,19 +167,19 @@ class ShardWorker:
                                           for r in reports)}
 
     # -- statement execution -------------------------------------------------
-    def _run(self, i: int, task, ckey) -> object:
+    def _run(self, i: int, task, key) -> object:
+        """Shard ``i``'s partial of ``task`` through its result cache;
+        ``None`` when this worker does not hold the shard."""
         with self._lock:
             sh = self.shards.get(i)
             cache = self._caches.get(i)
         if sh is None:
-            raise KeyError(i)
-        if ckey is not None and cache is not None:
-            hit = cache.get(ckey)
-            if hit is not None:
-                return hit
-        out = run_shard_task(sh, task, backend=self.backend)
-        if ckey is not None and cache is not None:
-            cache.put(ckey, out)
+            return None
+        hit = cache.get(key)
+        if hit is not None:
+            return hit
+        out = run_on_shard(sh, task, backend=self.backend)
+        cache.put(key, out)
         return out
 
     def handle(self, obj: Dict, arrays: Dict) -> tuple:
@@ -224,97 +213,21 @@ class ShardWorker:
                                 for i, c in sorted(self._caches.items())},
                      "fault": (self.fault.counts
                                if self.fault is not None else None)}, {})
-        if op not in _DATA_OPS:
+        if op not in TASK_ARGS:
             raise ValueError(f"unknown worker op {op!r}")
-
-        sids = [int(s) for s in (obj.get("shards") or [])]
-        w = obj.get("where")
-        e = from_wire(w) if w is not None else None
-        ck = canonical_key(e) if e is not None else None
-        missing: List[int] = []
-        out: Dict = {"ok": True, "op": op}
+        task = decode_task(obj)
+        key = (self.backend,) + task_key(task)
+        parts: Dict[str, object] = {}
         arrs: Dict[str, np.ndarray] = {}
-        if op == "count":
-            counts = {}
-            for i in sids:
-                try:
-                    counts[str(i)] = int(self._run(
-                        i, ("count", e), ("count", self.backend, ck)))
-                except KeyError:
-                    missing.append(i)
-            out["counts"] = counts
-        elif op == "gcount":
-            col = obj.get("col")
-            if not isinstance(col, int):
-                raise ValueError(f"gcount needs an integer 'col', got {col!r}")
-            for i in sids:
-                try:
-                    vec = self._run(i, ("gcount", col, e),
-                                    ("gcount", col, self.backend, ck))
-                except KeyError:
-                    missing.append(i)
-                    continue
-                arrs[f"g{i}"] = np.asarray(vec, dtype=np.int64)
-        elif op == "agg":
-            name = obj.get("measure")
-            if not isinstance(name, str):
-                raise ValueError(f"agg needs a 'measure' name, got {name!r}")
-            aggs = {}
-            for i in sids:
-                try:
-                    part = self._run(i, ("agg", name, e),
-                                     ("agg", name, self.backend, ck))
-                except KeyError:
-                    missing.append(i)
-                    continue
-                s, cnt, mn, mx = part
-                aggs[str(i)] = [s, cnt, mn, mx]
-            out["aggs"] = aggs
-        elif op == "gagg":
-            name = obj.get("measure")
-            if name is not None and not isinstance(name, str):
-                raise ValueError(f"gagg 'measure' must be a name or null, "
-                                 f"got {name!r}")
-            cols = obj.get("cols")
-            if (not isinstance(cols, list) or not cols
-                    or not all(isinstance(c, int) for c in cols)):
-                raise ValueError(f"gagg needs 'cols' as a list of integer "
-                                 f"columns, got {cols!r}")
-            cols = tuple(cols)
-            shapes = {}
-            dtype = None
-            for i in sids:
-                try:
-                    g = self._run(i, ("gagg", name, cols, e),
-                                  ("gagg", name, cols, self.backend, ck))
-                except KeyError:
-                    missing.append(i)
-                    continue
-                shapes[str(i)] = list(g["shape"])
-                dtype = g["dtype"]
-                arrs[f"gc{i}"] = np.asarray(g["counts"], dtype=np.int64)
-                if name is not None:
-                    arrs[f"gs{i}"] = np.asarray(g["sums"])
-                    arrs[f"gm{i}"] = np.asarray(g["mins"])
-                    arrs[f"gx{i}"] = np.asarray(g["maxs"])
-            out["shapes"] = shapes
-            out["cols"] = list(cols)
-            out["measure"] = name
-            out["dtype"] = dtype
-        else:  # execute
-            n_bits = {}
-            for i in sids:
-                try:
-                    bm = self._run(i, ("expr", e),
-                                   ("expr", self.backend, ck))
-                except KeyError:
-                    missing.append(i)
-                    continue
-                arrs[f"w{i}"] = np.asarray(bm.words, dtype=WORD_DTYPE)
-                n_bits[str(i)] = int(bm.n_bits)
-            out["n_bits"] = n_bits
-        out["missing"] = missing
-        return out, arrs
+        missing: List[int] = []
+        for i in (int(s) for s in (obj.get("shards") or [])):
+            part = self._run(i, task, key)
+            if part is None:
+                missing.append(i)
+            else:
+                parts[str(i)] = encode_partial(part, f"p{i}", arrs)
+        return {"ok": True, "op": op, "parts": parts,
+                "missing": missing}, arrs
 
 
 class WorkerServer:
@@ -380,7 +293,7 @@ class WorkerServer:
                 injector = None
                 try:
                     obj, arrays = wire.decode_msg(payload)
-                    if obj.get("op") in _DATA_OPS:
+                    if obj.get("op") in TASK_ARGS:
                         injector = self.worker.fault
                     out, arrs = self.worker.handle(obj, arrays)
                     frame = (wire.KIND_RESP, wire.encode_msg(out, arrs))

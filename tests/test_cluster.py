@@ -411,7 +411,7 @@ def test_worker_assign_retire_missing(store):
     w = ShardWorker(d, [0, 1], backend=BACKEND)
     out, _arrs = w.handle({"op": "count", "shards": [0, 1, 2],
                            "where": None}, {})
-    assert sorted(map(int, out["counts"])) == [0, 1]
+    assert sorted(map(int, out["parts"])) == [0, 1]
     assert out["missing"] == [2]  # unheld shard reported, not fabricated
     assert w.assign([2])["opened"] == [2]
     out, _arrs = w.handle({"op": "count", "shards": [2], "where": None}, {})

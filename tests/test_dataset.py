@@ -9,7 +9,8 @@ import numpy as np
 import pytest
 
 from repro.core import (BitmapIndex, Dataset, QueryBatch, ShardedIndex, col,
-                        execute_count, execute_group_count, lex_sort, synth)
+                        lex_sort, synth)
+from repro.core.statement import run
 from repro.core.executor import Executor
 from repro.core.planner import Planner, plan
 from repro.serve.query_api import (expr_to_json, parse_statement,
@@ -165,29 +166,30 @@ def test_sharded_counts_equal_single_index(tables):
     mono = BitmapIndex.build(table, k=2, column_names=NAMES)
     sh = ShardedIndex.build(table, shard_rows=2016, k=2, column_names=NAMES)
     e = (col("region") == int(table[5, 0])) | (col("day") == int(table[3, 1]))
-    assert execute_count(sh, e) == execute_count(mono, e)
-    assert execute_count(sh, None) == execute_count(mono, None) == len(table)
+    assert run(sh, ("count", e)) == run(mono, ("count", e))
+    assert run(sh, ("count", None)) == run(mono, ("count", None)) \
+        == len(table)
     for c in range(3):
-        assert np.array_equal(execute_group_count(sh, c, e),
-                              execute_group_count(mono, c, e))
-        assert np.array_equal(execute_group_count(sh, c, None),
-                              execute_group_count(mono, c, None))
+        assert np.array_equal(run(sh, ("gcount", c, e)),
+                              run(mono, ("gcount", c, e)))
+        assert np.array_equal(run(sh, ("gcount", c, None)),
+                              run(mono, ("gcount", c, None)))
     # second round is served from the shard-local LRUs, same answers
-    assert execute_count(sh, e) == execute_count(mono, e)
+    assert run(sh, ("count", e)) == run(mono, ("count", e))
     assert any(c["hits"] > 0 for c in sh.cache_stats())
 
 
 def test_sharded_aggregates_never_concat_bitmaps(tables, monkeypatch):
     """Aggregates merge per-shard partial counts; the global result bitmap
     that ``execute`` concatenates must never exist."""
-    import repro.core.shard as shard_mod
+    import repro.core.statement as statement_mod  # where partials merge
     table = tables["sorted"]
     sh = ShardedIndex.build(table, shard_rows=2016, k=1, column_names=NAMES)
 
     def boom(parts):
         raise AssertionError("aggregate concatenated a global bitmap")
 
-    monkeypatch.setattr(shard_mod, "concat_bitmaps", boom)
+    monkeypatch.setattr(statement_mod, "concat_bitmaps", boom)
     e = col("region") == int(table[5, 0])
     mask = table[:, 0] == table[5, 0]
     assert sh.count(e) == int(mask.sum())

@@ -7,7 +7,8 @@ import pytest
 
 from repro.core import BitmapIndex, ShardedIndex, lex_sort, synth
 from repro.core import cost_model as cm
-from repro.core.executor import Executor, QueryBatch, execute
+from repro.core.executor import Executor
+from repro.core.statement import QueryBatch, execute
 from repro.core.expr import col
 from repro.core.lru import LRUCache
 from repro.core.planner import explain, plan

@@ -19,10 +19,11 @@ from repro.core import ShardedIndex, col, trace
 from repro.core.dataset import Dataset
 from repro.core.ewah import EWAH
 from repro.core.executor import (Executor, _bin_segments, _interval_catalog,
-                                 _rank_catalog, execute_group_agg)
+                                 _rank_catalog)
 from repro.core.measures import (empty_group_agg, finalize_group, gather,
                                  interval_positions, merge_group_aggs)
 from repro.core.planner import PGroupAgg, PPinned, Planner
+from repro.core.statement import group_task, run
 from repro.serve.query_api import QueryService, expr_to_json, parse_sql
 
 NAMES = ["a", "b", "c", "d", "e"]
@@ -280,7 +281,8 @@ def test_sweep_keeps_one_and_two_column_answers(by):
     idx = ShardedIndex.build(rows.astype(np.int64), shard_rows=352, k=1,
                              column_names=["a", "b"], measures={"m": m})
     assert idx.n_shards == 3
-    g = execute_group_agg(idx, "m", list(by), col("b") <= 1, backend="ewah")
+    g = run(idx, group_task("m", list(by), idx.resolve_column,
+                            col("b") <= 1), backend="ewah")
     want = PINNED[by]
     assert g["counts"].tolist() == want["counts"]
     assert g["sums"].tolist() == want["sums"]

@@ -75,12 +75,12 @@ def test_delta_index_incremental_matches_batch():
     assert np.array_equal(d.rows(), rows)
     idx = d.index()
     # sealed partitions + recompiled tail answer like a one-shot build
-    from repro.core.executor import execute, execute_group_count
+    from repro.core.statement import execute, run
     e = (col(0) == 3) | (col(1) == 1)
     want = (rows[:, 0] == 3) | (rows[:, 1] == 1)
     assert execute(idx, e).count() == int(want.sum())
     assert np.array_equal(
-        execute_group_count(idx, 2, e),
+        run(idx, ("gcount", 2, e)),
         np.bincount(rows[want][:, 2], minlength=CARDS[2]))
     # the compiled view is memoized per version, invalidated by append
     assert d.index() is idx

@@ -436,10 +436,8 @@ class Executor:
             pw, pf = zip(*[self._dense_operand(n, bm) for n, bm in pos])
             nw, nf = zip(*[self._dense_operand(n, bm) for n, bm in neg])
             with _trace.span("executor.kernel"):
-                a = kops.logical_reduce(np.stack(pw), op="and",
-                                        row_flags=np.stack(pf))
-                b = kops.logical_reduce(np.stack(nw), op="or",
-                                        row_flags=np.stack(nf))
+                a = kops.logical_reduce(pw, op="and", row_flags=np.stack(pf))
+                b = kops.logical_reduce(nw, op="or", row_flags=np.stack(nf))
                 out = self._fetch(kops.word_logical(a[None, :], b[None, :],
                                                     "andnot"))[0]
             return self._reencode(out, pos[0][1])
@@ -467,7 +465,7 @@ class Executor:
         self._count_dispatch()
         ws, fs = zip(*[self._dense_operand(node, bm) for node, bm in children])
         with _trace.span("executor.kernel"):
-            out = self._fetch(kops.logical_reduce(np.stack(ws), op=op,
+            out = self._fetch(kops.logical_reduce(ws, op=op,
                                                   row_flags=np.stack(fs)))
         return self._reencode(out, children[0][1])
 

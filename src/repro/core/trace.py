@@ -24,10 +24,13 @@ turned off in them.
 
 Counters (``add``) are always on: integer sums under one lock, read with
 ``counters()``.  The served path keeps ``kops.h2d_bytes`` (operand words
-and tile flags copied to the device), ``kops.d2h_bytes`` (kernel results
-fetched back), ``kops.dirty_tile_bytes`` (4 bytes x ``block_cols`` per
-DIRTY operand tile flag handed to ``logical_reduce``, the operand bytes any
-implementation must read), and for grouped aggregates
+and their flags copied to the device), ``kops.programs`` (device programs
+launched by the bitmap wrappers of ``repro.kernels.ops``: one per
+``logical_reduce``, ``word_logical``, ``popcount_*`` or ``bitpack`` call),
+``kops.d2h_bytes`` (kernel results fetched back), ``kops.dirty_tile_bytes``
+(4 bytes x ``block_cols`` per DIRTY operand tile flag handed to
+``logical_reduce``, the operand bytes any implementation must read), and
+for grouped aggregates
 ``executor.group_aggs`` (one per shard-level aggregate run),
 ``executor.group_intervals`` (catalog entries in the filter's coordinates:
 a column's group-bitmap run intervals mapped there, or the rank changes

@@ -17,12 +17,12 @@ Compilation contract: ``word_logical`` is jit-compiled once per *input
 shape* (plus static block/op params).  Callers must therefore keep the
 shape universe small — ``repro.kernels.ops`` pads the word dimension to
 power-of-two multiples of ``block_cols`` and operand stacks to power-of-two
-row counts, so one compiled program here serves every operand count and
-word count in a bucket, across shards, queries, and index rebuilds.  The
-``tile_flags`` sideband can equally be produced host-side per row
-(``ops.np_row_flags``) and cached by the executor; a conservative merge of
-row flags into tile flags is valid because DIRTY only means "read the
-words".
+row counts, and calls this kernel from inside its own programs (a whole
+reduction tree is one), so one compiled program serves every operand count
+and word count in a bucket, across shards, queries, and index rebuilds.
+The tile flags come from per-row flags (``ops.np_row_flags``, cached by the
+executor, or made on the device); a conservative merge of row flags into
+tile flags is valid because DIRTY only means "read the words".
 """
 from __future__ import annotations
 
@@ -106,13 +106,3 @@ def word_logical(
         interpret=interpret,
     )(flags_a.reshape(-1), flags_b.reshape(-1), a, b)
 
-
-def tile_flags(words: jax.Array, block_rows: int = BLOCK_ROWS,
-               block_cols: int = BLOCK_COLS) -> jax.Array:
-    """Compute the clean-tile sideband (DIRTY/CLEAN0/CLEAN1) for a word array."""
-    R, C = words.shape
-    gr, gc = R // block_rows, C // block_cols
-    t = words.reshape(gr, block_rows, gc, block_cols)
-    all0 = jnp.all(t == 0, axis=(1, 3))
-    all1 = jnp.all(t == jnp.uint32(0xFFFFFFFF), axis=(1, 3))
-    return jnp.where(all0, CLEAN0, jnp.where(all1, CLEAN1, DIRTY)).astype(jnp.int32)

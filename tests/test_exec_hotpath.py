@@ -66,6 +66,37 @@ def test_logical_reduce_with_cached_row_flags():
             assert np.array_equal(plain, flagged), (L, op)
 
 
+def test_logical_reduce_is_one_program_per_row_bucket():
+    """Operand counts that pad to one power of two share one compiled
+    program, and a call launches exactly one."""
+    import jax
+    from repro.core import trace
+    from repro.kernels import ops as kops
+    rng = np.random.default_rng(4)
+    # a block width no other test uses, so the first call is the compile
+    bc = 512
+    compiles = []
+
+    def on_duration(event, duration, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(duration)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        for L in (5, 7):  # both pad to 8 rows
+            mat = rng.integers(0, 2**32, (L, 4 * bc), dtype=np.uint32)
+            mat[0, :bc] = 0
+            rf = kops.np_row_flags(mat, bc)
+            p0 = trace.counters().get("kops.programs", 0)
+            got = np.asarray(kops.logical_reduce(mat, op="or", block_cols=bc,
+                                                 row_flags=rf))
+            assert trace.counters()["kops.programs"] - p0 == 1
+            assert np.array_equal(got, np.bitwise_or.reduce(mat, axis=0))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert len(compiles) == 1
+
+
 def test_np_row_flags_values():
     from repro.kernels import ops as kops
     from repro.kernels.word_logical import CLEAN0, CLEAN1, DIRTY

@@ -31,12 +31,28 @@ def test_word_logical_all_clean_tiles():
     assert np.asarray(ops.word_logical(a, b, "and")).max() == 0
 
 
-@pytest.mark.parametrize("L", [1, 2, 3, 7, 8, 16])
+@pytest.mark.parametrize("flags", [False, True])
+@pytest.mark.parametrize("L", [1, 2, 3, 7, 8, 9, 16, 17, 25, 33])
 @pytest.mark.parametrize("op", ["and", "or", "xor"])
-def test_logical_reduce_matches_numpy(L, op):
-    mat = RNG.integers(0, 2**32, size=(L, 700), dtype=np.uint32)
-    mat[0, :300] = 0
-    got = np.asarray(ops.logical_reduce(mat, op=op))
+def test_logical_reduce_matches_numpy(L, op, flags):
+    # 3000 words pad to a 4096-word bucket: four 1024-word blocks a row,
+    # each random, all zeros or all ones (CLEAN0 and CLEAN1 tiles); block 1
+    # is all ones in every row, so AND keeps it CLEAN1 through every round
+    # and OR/XOR see only clean operands there
+    rng = np.random.default_rng(L)
+    cols, width = 3000, ops.bucket_cols(3000)
+    mat = rng.integers(0, 2**32, size=(L, width), dtype=np.uint32)
+    kind = rng.choice(3, size=(L, width // 1024), p=[0.4, 0.3, 0.3])
+    kind[:, 1] = 2
+    blocks = mat.reshape(L, -1, 1024)
+    blocks[kind == 1] = 0
+    blocks[kind == 2] = 0xFFFFFFFF
+    mat = mat[:, :cols]
+    rf = None
+    if flags:
+        rf = ops.np_row_flags(np.pad(mat, ((0, 0), (0, width - cols))))
+        assert {int(f) for f in np.unique(rf)} == {0, 1, 2}
+    got = np.asarray(ops.logical_reduce(mat, op=op, row_flags=rf))
     npop = {"and": np.bitwise_and, "or": np.bitwise_or,
             "xor": np.bitwise_xor}[op]
     want = npop.reduce(mat, axis=0)

@@ -78,6 +78,35 @@ def test_word_logical_compiles(one_chip, op, cols):
                                          interpret=False))
 
 
+# the lex cell's shard width: 1.5M rows, 46,875 words, in a 65,536 bucket
+LEX_COLS = kops.bucket_cols(1_500_000 // 32)
+
+
+@pytest.mark.parametrize("rows", [2, 32])
+@pytest.mark.parametrize("op", ["and", "or"])
+def test_logical_reduce_tree_compiles(one_chip, op, rows):
+    # the whole reduction tree, as the executor sends it: one (1, cols) row
+    # per operand and int8 row flags; one kernel per round
+    assert LEX_COLS == 65536
+    parts = tuple(_spec((1, LEX_COLS), jnp.uint32, one_chip)
+                  for _ in range(rows))
+    flags = _spec((rows, LEX_COLS // wl.BLOCK_COLS), jnp.int8, one_chip)
+    lowered = kops._reduce_program.lower(
+        parts, flags, op=op, cols=LEX_COLS,
+        block_rows=wl.BLOCK_ROWS, block_cols=wl.BLOCK_COLS, interpret=False)
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == rows.bit_length() - 1
+
+
+def test_word_logical_program_compiles(one_chip):
+    # the executor's ANDNOT of two reductions: pad, flags, kernel, slice
+    row = _spec((1, LEX_COLS), jnp.uint32, one_chip)
+    lowered = kops._logical_program.lower(
+        row, row, op="andnot", width=LEX_COLS, cols=LEX_COLS,
+        block_rows=wl.BLOCK_ROWS, block_cols=wl.BLOCK_COLS, interpret=False)
+    assert lowered.compile().as_text().count("tpu_custom_call") == 1
+
+
 def test_popcount_total_compiles(one_chip):
     words = _spec((8, SHARD_COLS), jnp.uint32, one_chip)
     _assert_kernel(pc.popcount_total.lower(words, interpret=False))
